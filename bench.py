@@ -1751,9 +1751,9 @@ def run_ingest(conf_path: str) -> int:
     r = g.get("routed")
     if r:
         import jax as _jax
-        if len(_jax.devices()) < 8:
-            print("INGEST ROUTED SKIP: <8 devices, replicated routed "
-                  "arm needs the 8-shard mesh", flush=True)
+        if len(_jax.devices()) <= r.get("kill_shard", 2):
+            print("INGEST ROUTED SKIP: the replicated routed arm needs "
+                  "more devices than the killed shard's index", flush=True)
         else:
             _flight.clear()
             rlines = bench_dist_ingest(
@@ -3177,17 +3177,10 @@ def run_conf(conf_path: str) -> None:
 
 
 def _setup_jax_cache() -> None:
-    # persistent compile cache: the remote TPU AOT compile dominates one-shot
-    # build wall-clock (measured ~170s compile vs ~7s execute for a 100k
-    # extend); caching amortizes it across bench invocations
-    import os
-
-    import jax
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                                     "/tmp/raft_tpu_jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # persistent compile cache: compiles dominate one-shot build
+    # wall-clock; caching amortizes them across bench invocations
+    from raft_tpu.core.platform import setup_compile_cache
+    setup_compile_cache()
 
 
 def main() -> None:

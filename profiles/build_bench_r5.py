@@ -5,18 +5,17 @@ pack), plus search QPS spot-check — the VERDICT r5 item-1 gate
 import json
 import sys
 import time
+from pathlib import Path
 
 import numpy as np
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 
 def main():
     import jax
-    jax.config.update("jax_compilation_cache_dir",
-                      "/tmp/raft_tpu_jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    from raft_tpu.core.platform import setup_compile_cache
+    setup_compile_cache()
     import jax.numpy as jnp
     from raft_tpu import DeviceResources
     from raft_tpu.neighbors import brute_force, cagra

@@ -7,15 +7,16 @@ import functools
 import json
 import sys
 import time
+from pathlib import Path
 
 import numpy as np
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 import jax  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/raft_tpu_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+from raft_tpu.core.platform import setup_compile_cache  # noqa: E402
+setup_compile_cache()
 
 import jax.numpy as jnp  # noqa: E402
 from jax.experimental import pallas as pl  # noqa: E402

@@ -1,50 +1,16 @@
-"""JAX version compatibility shims.
+"""JAX spellings the library standardizes on (JAX >= 0.9).
 
-One module owns every version probe so algorithm code stays on the
-modern spelling.  Currently: ``jax.shard_map`` graduated from
-``jax.experimental.shard_map`` (and renamed ``check_rep`` →
-``check_vma``) — older runtimes get the experimental one adapted.
+``shard_map`` defaults ``check_vma`` to False: the library's SPMD bodies
+mix replicated and per-shard values through explicit collectives, which
+the varying-manual-axes check would reject.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f=None, *, mesh, in_specs, out_specs, check_vma=False):
-        if f is None:
-            return lambda fn: jax.shard_map(fn, mesh=mesh,
-                                            in_specs=in_specs,
-                                            out_specs=out_specs,
-                                            check_vma=check_vma)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:  # pre-graduation releases
-    from jax.experimental.shard_map import shard_map as _experimental
-
-    def shard_map(f=None, *, mesh, in_specs, out_specs, check_vma=False):
-        if f is None:
-            return lambda fn: _experimental(fn, mesh=mesh,
-                                            in_specs=in_specs,
-                                            out_specs=out_specs,
-                                            check_rep=check_vma)
-        return _experimental(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check_vma)
-
-
-if hasattr(jax, "enable_x64"):
-    enable_x64 = jax.enable_x64
-else:  # pre-graduation releases keep it under jax.experimental
-    from jax.experimental import enable_x64  # noqa: F401
-
-
-if hasattr(jax.lax, "axis_size"):
-    def axis_size(axis_name):
-        """Static extent of a mesh axis inside a traced context."""
-        return jax.lax.axis_size(axis_name)
-else:
-    def axis_size(axis_name):
-        """Static extent of a mesh axis inside a traced context (older
-        releases expose it as the axis frame itself)."""
-        from jax.core import axis_frame
-        return axis_frame(axis_name)
+shard_map = functools.partial(jax.shard_map, check_vma=False)
+enable_x64 = jax.enable_x64
+axis_size = jax.lax.axis_size

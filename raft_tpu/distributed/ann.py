@@ -60,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from raft_tpu.core import serialize as ser
+from raft_tpu.core import platform as _platform
 from raft_tpu.core.compat import shard_map
 from raft_tpu.core.error import expects
 from raft_tpu.core.mdarray import ensure_array
@@ -196,7 +197,7 @@ def _resolve_scan_mode(params, index, nq: int, n_probes: int,
     mode = getattr(params, "scan_mode", "auto")
     expects(mode in ivf_pq._SCAN_MODES,
             f"distributed.ann.search: unknown scan_mode {mode!r}")
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = _platform.on_tpu()
     kt_req = int(getattr(params, "per_probe_topk", 0) or 0)
     routed = isinstance(index, RoutedIndex)
     want_fused = mode == "fused" or (mode == "auto" and on_tpu)

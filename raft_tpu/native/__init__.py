@@ -26,14 +26,27 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
+_SRC = _HERE / "agglomerative.cpp"
+
+
+def _stale() -> bool:
+    """True when the library is missing or older than its source."""
+    return (not _SO.exists()
+            or _SO.stat().st_mtime < _SRC.stat().st_mtime)
+
+
 def _compile() -> bool:
-    src = _HERE / "agglomerative.cpp"
+    # build beside the target, then rename: a process that loads the
+    # library concurrently never sees a half-written file
+    tmp = _SO.with_name(f"{_SO.name}.{os.getpid()}.tmp")
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", str(src), "-o", str(_SO)],
+            ["g++", "-O3", "-shared", "-fPIC", str(_SRC), "-o", str(tmp)],
             check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO)
         return True
     except (OSError, subprocess.SubprocessError):
+        tmp.unlink(missing_ok=True)
         return False
 
 
@@ -45,7 +58,7 @@ def _load() -> Optional[ctypes.CDLL]:
         _tried = True
         if os.environ.get("RAFT_TPU_DISABLE_NATIVE"):
             return None
-        if not _SO.exists() and not _compile():
+        if _stale() and not _compile():
             return None
         try:
             lib = ctypes.CDLL(str(_SO))

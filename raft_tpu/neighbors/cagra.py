@@ -64,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from raft_tpu.cluster import kmeans_balanced
+from raft_tpu.core import platform as _platform
 from raft_tpu.core import serialize as ser
 from raft_tpu.core.error import expects
 from raft_tpu.core.mdarray import ensure_array
@@ -403,17 +404,23 @@ _REV_HOST_EDGES = 200_000_000
 _DEEP_SCALE_ROWS = 4_000_000
 
 
+# memory budget of the deep-scale walk on a non-TPU backend (the CPU
+# twin reports no device memory); the size of one v5e chip's HBM
+_NON_TPU_MEMORY_BYTES = 16 << 30
+
+
 def _hbm_bytes() -> int:
-    """Default-device HBM, from the runtime when it reports it (a v5e
-    constant otherwise — the one chip this repo is tuned on)."""
-    try:
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = int(stats.get("bytes_limit", 0))
-        if limit > 0:
-            return limit
-    except Exception:
-        pass
-    return 16 << 30
+    """Default-device HBM from the runtime's ``bytes_limit``.  A TPU that
+    reports none is an error: the deep-scale walk sizes its table from
+    this number, and a guess could run the chip out of memory."""
+    if not _platform.on_tpu():
+        return _NON_TPU_MEMORY_BYTES
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = int(stats.get("bytes_limit", 0))
+    if limit <= 0:
+        raise RuntimeError(
+            f"cagra: the TPU runtime reports no bytes_limit ({stats!r})")
+    return limit
 
 
 def _deep_walk_round(dataset, knn, kg, metric, pdim, iters, vecs=None):
@@ -2012,7 +2019,7 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
             mw = chp.hop_merge_window(queries.shape[0], itopk, wd,
                                       min(pdim, index.dim),
                                       requested=mw_req)
-            fused = (jax.default_backend() == "tpu"
+            fused = (_platform.on_tpu()
                      and index.size < (1 << 24)
                      and mw > 0)
             stage = ("cagra.search.fused_walk" if fused

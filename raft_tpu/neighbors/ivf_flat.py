@@ -32,6 +32,7 @@ import numpy as np
 
 from raft_tpu.cluster import kmeans_balanced
 from raft_tpu.cluster.kmeans_types import KMeansBalancedParams
+from raft_tpu.core import platform as _platform
 from raft_tpu.core import serialize as ser
 from raft_tpu.core.error import expects
 from raft_tpu.core.mdarray import ensure_array
@@ -764,7 +765,7 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
         # the fused kernel's one-hot id contraction is f32 — require
         # every actual candidate id (incl. user-supplied extend ids)
         # to be f32-exact, not just the row count
-        use_pallas = (jax.default_backend() == "tpu"
+        use_pallas = (_platform.on_tpu()
                       and grouped.ids_f32_exact(index, index.list_indices))
         if use_pallas and index.list_data_sq is None:
             # lazily attach the row-norm cache (stays on the index);

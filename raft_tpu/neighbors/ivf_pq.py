@@ -44,6 +44,7 @@ import numpy as np
 
 from raft_tpu.cluster import kmeans_balanced
 from raft_tpu.cluster.kmeans_types import KMeansBalancedParams
+from raft_tpu.core import platform as _platform
 from raft_tpu.core import serialize as ser
 from raft_tpu.core.error import expects
 from raft_tpu.core.interruptible import interruptible
@@ -411,44 +412,45 @@ def _train_books_per_subspace(resid_sub, keys, book_size, n_iters):
     return jax.lax.map(one, (resid_sub, keys))
 
 
+@functools.partial(jax.jit, static_argnames=("codebook_kind",))
+def _encode_chunk(codebooks, r, lab, codebook_kind):
+    # d[c, j, k] = ||r[c,j,:] - cb[j,k,:]||^2, summed elementwise over the
+    # pq_len axis.  The expanded ||cb||^2 - 2 r.cb form, fused with the
+    # argmin, was miscompiled by XLA's TPU backend on a v5e — inside a
+    # lax.map, and in a jit that also cast the codes to uint8 (~13% of
+    # codes matched a host argmin); codes leave this jit as int32.
+    if codebook_kind == CodebookKind.PER_SUBSPACE:
+        diff = r[:, :, None, :] - codebooks[None, :, :, :]
+    else:
+        cb = codebooks[lab]                              # (c, book, pq_len)
+        diff = r[:, :, None, :] - cb[:, None, :, :]
+    d = jnp.sum(diff * diff, axis=-1)                    # (c, j, k)
+    return jnp.argmin(d, axis=-1)
+
+
 def _encode(codebooks, resid, codebook_kind, labels=None):
     """PQ-encode residuals (n, pq_dim, pq_len) -> (n, pq_dim) uint8.
 
     Reference: process_and_fill_codes_kernel (ivf_pq_build.cuh:944) — the
-    per-subspace argmin over the codebook.  Chunked over rows with
-    ``lax.map``: the full (n, pq_dim, book) distance tensor is 32 GB at
+    per-subspace argmin over the codebook.  Chunked over rows, one call
+    per chunk: the full (n, pq_dim, book) distance tensor is 32 GB at
     SIFT-1M scale.
     """
     n = resid.shape[0]
     chunk = 65_536
-
-    def enc(args):
-        r, lab = args
-        if codebook_kind == CodebookKind.PER_SUBSPACE:
-            # d[c, j, k] = ||r[c,j,:] - cb[j,k,:]||^2; argmin over k
-            ip = jnp.einsum("njl,jkl->njk", r, codebooks,
-                            precision=get_matmul_precision())
-            cb_sq = jnp.sum(codebooks * codebooks, axis=-1)  # (j, k)
-            d = cb_sq[None, :, :] - 2.0 * ip
-        else:
-            cb = codebooks[lab]                          # (c, book, pq_len)
-            ip = jnp.einsum("njl,nkl->njk", r, cb,
-                            precision=get_matmul_precision())
-            cb_sq = jnp.sum(cb * cb, axis=-1)            # (c, k)
-            d = cb_sq[:, None, :] - 2.0 * ip
-        return jnp.argmin(d, axis=-1).astype(jnp.uint8)
-
     if labels is None:
         labels = jnp.zeros(n, jnp.int32)
     if n <= chunk:
-        return enc((resid, labels))
-    n_pad = -(-n // chunk) * chunk
-    rp = jnp.pad(resid, ((0, n_pad - n), (0, 0), (0, 0)))
-    lp = jnp.pad(labels, (0, n_pad - n))
-    rp = rp.reshape(n_pad // chunk, chunk, *resid.shape[1:])
-    lp = lp.reshape(n_pad // chunk, chunk)
-    out = jax.lax.map(enc, (rp, lp))
-    return out.reshape(n_pad, -1)[:n]
+        codes = _encode_chunk(codebooks, resid, labels, codebook_kind)
+    else:
+        n_pad = -(-n // chunk) * chunk
+        rp = jnp.pad(resid, ((0, n_pad - n), (0, 0), (0, 0)))
+        lp = jnp.pad(labels, (0, n_pad - n))
+        codes = jnp.concatenate([
+            _encode_chunk(codebooks, rp[s:s + chunk], lp[s:s + chunk],
+                          codebook_kind)
+            for s in range(0, n_pad, chunk)])[:n]
+    return codes.astype(jnp.uint8)
 
 
 def build(res, params: IndexParams, dataset, *,
@@ -1763,7 +1765,7 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
         mw_req = vb.merge_window_request(
             getattr(params, "merge_window", "auto"))
         G = grouped.GROUP
-        on_tpu = jax.default_backend() == "tpu"
+        on_tpu = _platform.on_tpu()
         # the fused kernels' one-hot id contraction is f32 — require
         # every actual candidate id (incl. user-supplied extend ids)
         # to be f32-exact, not just the row count

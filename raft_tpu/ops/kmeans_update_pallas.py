@@ -186,8 +186,8 @@ def fused_tile(n: int, dim: int, k: int) -> int:
     this pass (kmeans.fit and kmeans_balanced share it; each checks
     its own metric family first).  dim < 32 is unprofitable — lane
     padding makes the bf16 tiles mostly zeros."""
-    import jax
+    from raft_tpu.core.platform import on_tpu
 
-    if jax.default_backend() != "tpu" or dim < 32:
+    if not on_tpu() or dim < 32:
         return 0
     return best_tile(n, dim, k, True)

@@ -57,7 +57,8 @@ from raft_tpu.ops.pq_group_scan_pallas import (_KT_MAX, _KT_UNROLL,
                                                _gather_queries,
                                                _gather_queries_masked,
                                                _scratch_shapes,
-                                               _unpack_admission)
+                                               _unpack_admission,
+                                               query_table)
 from raft_tpu.ops.pq_group_scan_pallas import _ACC_WORST  # noqa: F401 (re-export)
 
 _VMEM_BUDGET = 10 << 20
@@ -202,7 +203,7 @@ def _kernel_codes(gl_ref, slot_ref, qrot_ref, cf_ref, codes_ref, cb_ref,
     sub_sq = jnp.sum(sub * sub, axis=1)                  # (G,)
     cap = codes_ref.shape[2]
     reconT = _decode_reconT(codes_ref, cb_ref, pq_dim, pq_bits,
-                            qrot_ref.shape[1], cap)      # (rot_pad, cap)
+                            qrot_ref.shape[2], cap)      # (rot_pad, cap)
     ip = jax.lax.dot_general(sub.astype(jnp.bfloat16), reconT,
                              (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -260,7 +261,7 @@ def _kernel_codes_fused(gl_ref, slot_ref, qrot_ref, cf_ref, codes_ref,
     sub_sq = jnp.sum(sub * sub, axis=1)                  # (G,)
     cap = codes_ref.shape[2]
     reconT = _decode_reconT(codes_ref, cb_ref, pq_dim, pq_bits,
-                            qrot_ref.shape[1], cap)      # (rot_pad, cap)
+                            qrot_ref.shape[2], cap)      # (rot_pad, cap)
     ip = jax.lax.dot_general(sub.astype(jnp.bfloat16), reconT,
                              (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -301,15 +302,14 @@ def grouped_code_scan_fused(group_list, slot_pairs, qrot, centers_f32,
     rot_pad = _round_up(rot, 128)
 
     nq_pad = _round_up(nq + 1, 128)
-    qrot_pad = jnp.zeros((nq_pad, rot_pad), jnp.float32)
-    qrot_pad = qrot_pad.at[:nq, :rot].set(qrot.astype(jnp.float32))
+    qrot_pad = query_table(qrot, nq_pad, rot_pad)
     cf_pad = _pad_lanes(centers_f32, rot_pad)
     cbT = jnp.swapaxes(codebooks.astype(jnp.float32), 1, 2)
 
     has_adm = adm_words is not None
     in_specs = [
         pl.BlockSpec((1, 1, GROUP), lambda g, gl: (g, 0, 0)),
-        pl.BlockSpec((nq_pad, rot_pad), lambda g, gl: (0, 0)),
+        pl.BlockSpec((3, nq_pad, rot_pad), lambda g, gl: (0, 0, 0)),
         pl.BlockSpec((1, 1, rot_pad), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, Wi, cap), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((pq_dim, pq_len, book), lambda g, gl: (0, 0, 0)),
@@ -386,8 +386,7 @@ def grouped_code_scan(group_list, slot_pairs, qrot, centers_f32,
     rot_pad = _round_up(rot, 128)
 
     nq_pad = _round_up(nq + 1, 128)
-    qrot_pad = jnp.zeros((nq_pad, rot_pad), jnp.float32)
-    qrot_pad = qrot_pad.at[:nq, :rot].set(qrot.astype(jnp.float32))
+    qrot_pad = query_table(qrot, nq_pad, rot_pad)
     cf_pad = _pad_lanes(centers_f32, rot_pad)
     # (pq_dim, pq_len, book): books on lanes — the (.., book, pq_len)
     # orientation would lane-pad pq_len (2 at bench shape) to 128
@@ -396,7 +395,7 @@ def grouped_code_scan(group_list, slot_pairs, qrot, centers_f32,
     has_adm = adm_words is not None
     in_specs = [
         pl.BlockSpec((1, 1, GROUP), lambda g, gl: (g, 0, 0)),
-        pl.BlockSpec((nq_pad, rot_pad), lambda g, gl: (0, 0)),
+        pl.BlockSpec((3, nq_pad, rot_pad), lambda g, gl: (0, 0, 0)),
         pl.BlockSpec((1, 1, rot_pad), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, Wi, cap), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((pq_dim, pq_len, book), lambda g, gl: (0, 0, 0)),
@@ -455,14 +454,13 @@ def grouped_recon8_scan(group_list, slot_pairs, qrot, centers_f32,
     P = nq * n_probes
 
     nq_pad = _round_up(nq + 1, 128)
-    qrot_pad = jnp.zeros((nq_pad, rot_pad), jnp.float32)
-    qrot_pad = qrot_pad.at[:nq, :rot].set(qrot.astype(jnp.float32))
+    qrot_pad = query_table(qrot, nq_pad, rot_pad)
     cf_pad = _pad_lanes(centers_f32, rot_pad)
 
     has_adm = adm_words is not None
     in_specs = [
         pl.BlockSpec((1, 1, GROUP), lambda g, gl: (g, 0, 0)),
-        pl.BlockSpec((nq_pad, rot_pad), lambda g, gl: (0, 0)),
+        pl.BlockSpec((3, nq_pad, rot_pad), lambda g, gl: (0, 0, 0)),
         pl.BlockSpec((1, 1, rot_pad), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, cap, rot_pad), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, 1, 1), lambda g, gl: (gl[g], 0, 0)),

@@ -56,6 +56,69 @@ _KT_MAX = 128
 # +inf / id -1 contract.
 _ACC_WORST = 3.0e38
 
+# The one-hot contractions move f32 values and f32-encoded ids that must
+# arrive bit-exact, and Mosaic's default contraction precision rounds f32
+# operands to bf16 (ids past 256 come back as other ids).  So every f32
+# operand of a one-hot contraction is split into three bf16 parts that
+# together hold its 24-bit significand: a bf16 one-hot times a bf16 part
+# is exact and every output takes at most one non-zero product, so three
+# single bf16 passes sum back exactly — half the passes of a
+# ``Precision.HIGHEST`` contraction.
+
+
+def _split3(x):
+    """Three bf16 parts of f32 ``x`` whose f32 sum is ``x`` exactly.
+
+    Each part keeps the top 8 significant bits of what is left (the low
+    16 bits masked off), so no part ever rounds and the split holds
+    under any compiler that folds an f32 -> bf16 -> f32 round trip."""
+    def top(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.int32)
+        return jax.lax.bitcast_convert_type(bits & jnp.int32(-65536),
+                                            jnp.float32)
+    hi = top(x)
+    rest = x - hi
+    mid = top(rest)
+    lo = rest - mid
+    return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, lo))
+
+
+def _parts_dot(parts, oh, dims, onehot_lhs=False):
+    """Exact f32 ``dot_general(x, oh)`` (``dot_general(oh, x)`` with
+    ``onehot_lhs``) from the :func:`_split3` parts of ``x`` against a
+    0/1 one-hot ``oh``."""
+    ohb = oh.astype(jnp.bfloat16)
+    out = None
+    for part in parts:
+        lhs, rhs = (ohb, part) if onehot_lhs else (part, ohb)
+        p = jax.lax.dot_general(lhs, rhs, dims,
+                                preferred_element_type=jnp.float32)
+        out = p if out is None else out + p
+    return out
+
+
+def _onehot_dot(x, oh, dims):
+    """Exact f32 ``dot_general(x, oh)`` against a 0/1 one-hot ``oh``."""
+    return _parts_dot(_split3(x), oh, dims)
+
+
+def query_table(q, nq_pad: int, width: int):
+    """The scan kernels' VMEM-resident query table: ``q`` zero-padded to
+    (nq_pad, width) f32 and stored as its (3, nq_pad, width) bf16
+    :func:`_split3` parts, split once per search instead of once per
+    grid step.  Padded rows are the zero row empty slots gather."""
+    nq, d = q.shape
+    qp = jnp.zeros((nq_pad, width), jnp.float32)
+    qp = qp.at[:nq, :d].set(q.astype(jnp.float32))
+    return jnp.stack(_split3(qp))
+
+
+def _gather_rows(onehot, q_ref):
+    """(G, nq_pad) one-hot x the (3, nq_pad, d) split table -> exact
+    (G, d) f32 rows."""
+    return _parts_dot([q_ref[i] for i in range(q_ref.shape[0])], onehot,
+                      (((1,), (0,)), ((), ())), onehot_lhs=True)
+
 
 def _scratch_shapes(kt):
     if kt <= _KT_UNROLL:
@@ -67,18 +130,16 @@ def _scratch_shapes(kt):
 
 def _gather_queries(slot_ref, q_ref, n_probes, P):
     """One-hot MXU row gather of the group's queries from the
-    VMEM-resident table.  f32 one-hot x f32 table is EXACT (one product
-    per output) — a bf16 table would round |q| before any center
+    VMEM-resident :func:`query_table`, exact in f32 (one product per
+    output) — a plain bf16 table would round |q| before any center
     subtraction, which can exceed the residual magnitude on
     well-clustered data.  Sentinel slots gather the zero row."""
-    nq_pad = q_ref.shape[0]
+    nq_pad = q_ref.shape[1]
     slot = slot_ref[0, 0]                              # (G,) int32 pair ids
     qid = jnp.where(slot < P, slot // n_probes, nq_pad - 1)
     cols = jax.lax.broadcasted_iota(jnp.int32, (GROUP, nq_pad), 1)
-    onehot = (cols == qid[:, None]).astype(jnp.float32)
-    return jax.lax.dot_general(onehot, q_ref[:],
-                               (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)  # (G, d)
+    onehot = (cols == qid[:, None]).astype(jnp.bfloat16)
+    return _gather_rows(onehot, q_ref)                 # (G, d)
 
 
 def _unpack_admission(adm_ref, cap):
@@ -198,15 +259,15 @@ def _gather_queries_masked(slot_ref, q_ref, n_probes, P):
     to address the fused accumulator.  Sentinel slots have an all-zero
     one-hot row: they gather the zero query row AND are excluded from
     the accumulator write-back (their merged columns are discarded)."""
-    nq_pad = q_ref.shape[0]
+    nq_pad = q_ref.shape[1]
     slot = slot_ref[0, 0]                              # (G,) int32 pair ids
-    valid = slot < P
-    qid = jnp.where(valid, slot // n_probes, 0)
+    # sentinel slots map to column -1, which no iota column matches —
+    # validity rides the int32 id, because Mosaic cannot lay out the
+    # (G,) -> (G, 1) reshape of a bool vector
+    qid = jnp.where(slot < P, slot // n_probes, -1)
     cols = jax.lax.broadcasted_iota(jnp.int32, (GROUP, nq_pad), 1)
-    oh = ((cols == qid[:, None]) & valid[:, None]).astype(jnp.float32)
-    qv = jax.lax.dot_general(oh, q_ref[:], (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    return qv, oh
+    oh = (cols == qid[:, None]).astype(jnp.float32)
+    return _gather_rows(oh, q_ref), oh
 
 
 def _topk_rows(d, ids_row, kt, adm=None):
@@ -267,20 +328,16 @@ def _fused_accumulate(oh, d, ids_row, acc_v, acc_i, kt, adm=None):
     most one slot; sentinel slots have all-zero one-hot rows)."""
     k = acc_v.shape[0]
     new_v, new_i = _topk_rows(d, ids_row, kt, adm=adm)  # (kt, G)
-    old_v = jax.lax.dot_general(acc_v[:], oh, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    old_i = jax.lax.dot_general(acc_i[:], oh, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+    old_v = _onehot_dot(acc_v[:], oh, (((1,), (1,)), ((), ())))
+    old_i = _onehot_dot(acc_i[:], oh, (((1,), (1,)), ((), ())))
     mer_v, mer_i = _merge_topk(jnp.concatenate([old_v, new_v], 0),
                                jnp.concatenate([old_i, new_i], 0), k)
     cover = jnp.sum(oh, axis=0)                        # (nq_pad,) 0/1
     keep = (1.0 - cover)[None, :]
-    acc_v[:] = acc_v[:] * keep + jax.lax.dot_general(
-        mer_v, oh, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    acc_i[:] = acc_i[:] * keep + jax.lax.dot_general(
-        mer_i, oh, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_v[:] = acc_v[:] * keep + _onehot_dot(
+        mer_v, oh, (((1,), (0,)), ((), ())))
+    acc_i[:] = acc_i[:] * keep + _onehot_dot(
+        mer_i, oh, (((1,), (0,)), ((), ())))
 
 
 def _merge_cols(acc_v, acc_i, stg_v, stg_i, k):
@@ -352,13 +409,11 @@ def _fused_step(g, oh, d, ids_row, acc_v, acc_i, stg, *, kt,
     new_v, new_i = _topk_rows(d, ids_row, kt, adm=adm)  # (kt, G), finite
     cover = jnp.sum(oh, axis=0)                        # (nq_pad,) 0/1
     fill = (1.0 - cover)[None, :]
-    row0 = (g % merge_window) * kt
-    stg_v[pl.ds(row0, kt), :] = jax.lax.dot_general(
-        new_v, oh, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) + _ACC_WORST * fill
-    stg_i[pl.ds(row0, kt), :] = jax.lax.dot_general(
-        new_i, oh, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) - fill
+    row0 = (g % merge_window) * vb.stage_stride(kt)
+    stg_v[pl.ds(row0, kt), :] = _onehot_dot(
+        new_v, oh, (((1,), (0,)), ((), ()))) + _ACC_WORST * fill
+    stg_i[pl.ds(row0, kt), :] = _onehot_dot(
+        new_i, oh, (((1,), (0,)), ((), ()))) - fill
 
     @pl.when(((g + 1) % merge_window == 0) | (g == n_groups - 1))
     def _merge():
@@ -437,14 +492,13 @@ def grouped_l2_scan_fused(group_list, slot_pairs, qrot, centers_f32,
     _, cap, _ = list_recon.shape
     P = nq * n_probes
 
-    nq_pad = -(-(nq + 1) // 128) * 128
-    qrot_pad = jnp.zeros((nq_pad, rot), jnp.float32)
-    qrot_pad = qrot_pad.at[:nq].set(qrot.astype(jnp.float32))
+    nq_pad = vb.nq_padded(nq)
+    qrot_pad = query_table(qrot, nq_pad, rot)
 
     has_adm = adm_words is not None
     in_specs = [
         pl.BlockSpec((1, 1, GROUP), lambda g, gl: (g, 0, 0)),
-        pl.BlockSpec((nq_pad, rot), lambda g, gl: (0, 0)),
+        pl.BlockSpec((3, nq_pad, rot), lambda g, gl: (0, 0, 0)),
         pl.BlockSpec((1, 1, rot), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, cap, rot), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, 1, cap), lambda g, gl: (gl[g], 0, 0)),
@@ -588,14 +642,13 @@ def grouped_l2_scan(group_list, slot_pairs, qrot, centers_f32, list_recon,
 
     # pad the query table to a lane-friendly height; the sentinel row
     # (all zeros, index nq_pad-1) is what empty slots gather
-    nq_pad = -(-(nq + 1) // 128) * 128
-    qrot_pad = jnp.zeros((nq_pad, rot), jnp.float32)
-    qrot_pad = qrot_pad.at[:nq].set(qrot.astype(jnp.float32))
+    nq_pad = vb.nq_padded(nq)
+    qrot_pad = query_table(qrot, nq_pad, rot)
 
     has_adm = adm_words is not None
     in_specs = [
         pl.BlockSpec((1, 1, GROUP), lambda g, gl: (g, 0, 0)),
-        pl.BlockSpec((nq_pad, rot), lambda g, gl: (0, 0)),
+        pl.BlockSpec((3, nq_pad, rot), lambda g, gl: (0, 0, 0)),
         pl.BlockSpec((1, 1, rot), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, cap, rot), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, 1, cap), lambda g, gl: (gl[g], 0, 0)),
@@ -646,14 +699,13 @@ def grouped_flat_l2_scan(group_list, slot_pairs, queries_f32, list_data,
     _, cap, _ = list_data.shape
     P = nq * n_probes
 
-    nq_pad = -(-(nq + 1) // 128) * 128
-    q_pad = jnp.zeros((nq_pad, dim), jnp.float32)
-    q_pad = q_pad.at[:nq].set(queries_f32.astype(jnp.float32))
+    nq_pad = vb.nq_padded(nq)
+    q_pad = query_table(queries_f32, nq_pad, dim)
 
     has_adm = adm_words is not None
     in_specs = [
         pl.BlockSpec((1, 1, GROUP), lambda g, gl: (g, 0, 0)),
-        pl.BlockSpec((nq_pad, dim), lambda g, gl: (0, 0)),
+        pl.BlockSpec((3, nq_pad, dim), lambda g, gl: (0, 0, 0)),
         pl.BlockSpec((1, cap, dim), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, 1, cap), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, 1, cap), lambda g, gl: (gl[g], 0, 0)),

@@ -4,8 +4,9 @@ The three fused kernels (:mod:`raft_tpu.ops.pq_group_scan_pallas`,
 :mod:`raft_tpu.ops.pq_code_scan_pallas`,
 :mod:`raft_tpu.ops.cagra_hop_pallas`) amortize their per-step top-k merge
 through a VMEM **staging ring**: each grid step appends its kt candidates
-into a (kt*W, nq_pad) scratch pair with a cheap one-hot scatter +
-sentinel fill, and only every W-th step (and at flush) pays the full
+(in a slot of :func:`stage_stride` rows) into a (stride*W, nq_pad)
+scratch pair with a cheap one-hot scatter + sentinel fill, and only
+every W-th step (and at flush) pays the full
 merge into the (k, nq_pad) accumulator.  ``W`` is host-static: it is
 chosen here, from shapes only, by one budget model all three kernels
 share — staging + accumulator + merge working set must fit the kernel's
@@ -67,12 +68,20 @@ def accumulator_bytes(k: int, nq_pad: int) -> int:
     return 2 * k * nq_pad * 4
 
 
+def stage_stride(kt: int) -> int:
+    """Rows one grid step owns in the staging ring: kt rounded up to
+    the 8-row sublane tile, because Mosaic only stores a multi-row block
+    at a dynamic row offset it can prove is a multiple of 8.  The pad
+    rows keep the sentinel fill and merge as no-ops."""
+    return round_up(kt, 8)
+
+
 def staging_bytes(kt: int, merge_window: int, nq_pad: int) -> int:
-    """The (kt*W, nq_pad) f32 staging-ring pair; W <= 1 stages nothing
-    (the per-step merge never materializes a window)."""
+    """The (stage_stride(kt)*W, nq_pad) f32 staging-ring pair; W <= 1
+    stages nothing (the per-step merge never materializes a window)."""
     if merge_window <= 1:
         return 0
-    return 2 * kt * merge_window * nq_pad * 4
+    return 2 * stage_stride(kt) * merge_window * nq_pad * 4
 
 
 def merge_temps_bytes(k: int, kt: int, merge_window: int, nq_pad: int,
@@ -86,7 +95,7 @@ def merge_temps_bytes(k: int, kt: int, merge_window: int, nq_pad: int,
     """
     if merge_window <= 1:
         return 4 * (k + kt) * group * 4
-    return 2 * (k + kt * merge_window) * nq_pad * 4
+    return 2 * (k + stage_stride(kt) * merge_window) * nq_pad * 4
 
 
 def select_merge_window(requested: int, *, kt: int, k: int, nq_pad: int,
@@ -124,14 +133,16 @@ def select_merge_window(requested: int, *, kt: int, k: int, nq_pad: int,
 
 def fused_scan_scratch(k: int, kt: int, merge_window: int, nq_pad: int):
     """Scratch list for the fused scan kernels: the (k, nq_pad)
-    accumulator pair, plus the (kt*W, nq_pad) staging-ring pair when a
-    window is in play.  The fused kernels MUST allocate through this
-    helper (graftlint-enforced) so scratch and the budget model agree."""
+    accumulator pair, plus the (stage_stride(kt)*W, nq_pad) staging-ring
+    pair when a window is in play.  The fused kernels MUST allocate
+    through this helper (graftlint-enforced) so scratch and the budget
+    model agree."""
     scratch = [pltpu.VMEM((k, nq_pad), jnp.float32),
                pltpu.VMEM((k, nq_pad), jnp.float32)]
     if merge_window > 1:
-        scratch += [pltpu.VMEM((kt * merge_window, nq_pad), jnp.float32),
-                    pltpu.VMEM((kt * merge_window, nq_pad), jnp.float32)]
+        rows = stage_stride(kt) * merge_window
+        scratch += [pltpu.VMEM((rows, nq_pad), jnp.float32),
+                    pltpu.VMEM((rows, nq_pad), jnp.float32)]
     return scratch
 
 

@@ -207,7 +207,7 @@ class Server:
         """Durably ingest one upsert/delete batch; returns the record's
         LSN once it is fsync-durable AND searchable (see
         :meth:`serving.IngestServer.write` for the ack contract and the
-        :class:`Overloaded` shed taxonomy)."""
+        :class:`Overloaded` shed classes)."""
         expects(self.ingest is not None,
                 "serving: no ingest tier attached — Server.write needs "
                 "attach_ingest before start()")

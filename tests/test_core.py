@@ -273,3 +273,31 @@ class TestAot:
             index, q, 5)
         same = np.mean(np.asarray(i1) == np.asarray(i2))
         assert same == 1.0, same
+
+
+class TestPlatform:
+    @pytest.mark.parametrize("env_dir", [None, "custom"])
+    def test_compile_cache_dir(self, monkeypatch, tmp_path, env_dir):
+        """$JAX_COMPILATION_CACHE_DIR wins when set; otherwise the cache
+        sits at the fixed <repo>/.jax_cache."""
+        import pathlib
+
+        from raft_tpu.core import platform
+
+        repo = pathlib.Path(__file__).resolve().parents[1]
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = str(repo / ".jax_cache")
+        else:
+            want = str(tmp_path / env_dir)
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+        prev = jax.config.jax_compilation_cache_dir
+        try:
+            assert platform.setup_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+        finally:
+            jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_on_tpu_follows_default_backend(self):
+        from raft_tpu.core.platform import on_tpu
+        assert on_tpu() == (jax.default_backend() == "tpu")

@@ -400,7 +400,7 @@ class TestKillMatrix:
 class TestTornTail:
     def test_torn_tail_repair_at_every_record_boundary(
             self, rhandle, built, data, tmp_path):
-        """Per-shard WALs inherit the PR 13 torn-tail taxonomy: cut one
+        """Per-shard WALs inherit the PR 13 torn-tail classes: cut one
         shard's log mid-record at EVERY record boundary — recover()
         repairs the tail, replays the intact prefix, and the memtable
         matches an independent replay of the same prefix."""

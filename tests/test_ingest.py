@@ -1,4 +1,4 @@
-"""Durable streaming ingest: WAL framing/corruption taxonomy, memtable
+"""Durable streaming ingest: WAL framing/corruption classes, memtable
 semantics (incl. the same-id churn regression), the fsync-before-ack
 write path, the kill-at-every-boundary recovery matrix (bit-identical
 replay, no acked write lost), write-path backpressure/quota/brownout
@@ -88,7 +88,7 @@ def _rows(rng, n):
 
 
 # ---------------------------------------------------------------------------
-# WAL framing + the corruption taxonomy
+# WAL framing + the corruption classes
 
 
 class TestWalFraming:

@@ -917,3 +917,19 @@ class TestGroupCapacity:
         buf.seek(0)
         back = ivf_pq.deserialize(res, buf)
         assert back.group_est == index.group_est
+
+
+def test_encode_chunks_match_rowwise_argmin():
+    """_encode splits rows into 65,536-row chunks (one call each); the
+    codes of every chunk, the padded tail included, equal the per-row
+    argmin over each subspace's codebook."""
+    rng = np.random.default_rng(5)
+    n, pq_dim, book, pq_len = 70_000, 4, 256, 2
+    books = rng.standard_normal((pq_dim, book, pq_len)).astype(np.float32)
+    resid = rng.standard_normal((n, pq_dim, pq_len)).astype(np.float32)
+    codes = np.asarray(ivf_pq._encode(jnp.asarray(books), jnp.asarray(resid),
+                                      ivf_pq.CodebookKind.PER_SUBSPACE))
+    assert codes.shape == (n, pq_dim) and codes.dtype == np.uint8
+    for rows in (slice(0, 500), slice(65_400, 66_000), slice(n - 500, n)):
+        d = ((resid[rows, :, None, :] - books[None]) ** 2).sum(-1)
+        np.testing.assert_array_equal(codes[rows], d.argmin(-1))

@@ -59,6 +59,28 @@ class TestBuildDendrogram:
         assert n_comp == 4      # {0,1}, {2,3}, {4}, {5}
 
 
+@requires_native
+def test_stale_library_is_rebuilt(monkeypatch, tmp_path):
+    """A library older than its source is rebuilt before it is loaded."""
+    import shutil
+
+    src = tmp_path / "agglomerative.cpp"
+    shutil.copy(native._SRC, src)
+    so = tmp_path / "libagglomerative.so"
+    so.write_bytes(b"not a library")
+    os.utime(so, (0, 0))
+    monkeypatch.setattr(native, "_SRC", src)
+    monkeypatch.setattr(native, "_SO", so)
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    assert native._stale()
+    assert native._load() is not None
+    assert not native._stale()
+    labels, n_comp = native.connected_components(
+        np.asarray([0], np.int32), np.asarray([1], np.int32), 3)
+    assert n_comp == 2
+
+
 def test_single_linkage_end_to_end_uses_whatever_is_available(res):
     """single_linkage must give identical results whichever backend the
     union-find runs on."""

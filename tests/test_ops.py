@@ -31,14 +31,16 @@ class TestFusedL2NNPallas:
 
     def test_dispatch_via_fused_l2_nn(self):
         """fused_l2_nn(use_pallas=True) must agree with the XLA path —
-        off-TPU the dispatch auto-selects the Pallas interpreter, on a TPU
+        off-TPU the test asks for the Pallas interpreter, on a TPU
         backend these same assertions check the compiled kernel."""
+        from raft_tpu.core.platform import on_tpu
         from raft_tpu.distance import fused_l2_nn
         rng = np.random.default_rng(1)
         x = rng.random((128, 32)).astype(np.float32)
         y = rng.random((256, 32)).astype(np.float32)
         d_x, i_x = fused_l2_nn(x, y)
-        d_p, i_p = fused_l2_nn(x, y, use_pallas=True)
+        d_p, i_p = fused_l2_nn(x, y, use_pallas=True,
+                               pallas_interpret=not on_tpu())
         np.testing.assert_allclose(np.asarray(d_x), np.asarray(d_p),
                                    rtol=1e-4, atol=1e-4)
         np.testing.assert_array_equal(np.asarray(i_x), np.asarray(i_p))
@@ -145,3 +147,100 @@ class TestFusedL2NNPallas:
         with matmul_precision("highest"):
             d3, _ = fused_l2_nn_pallas(x, y, interpret=True)
         np.testing.assert_allclose(np.asarray(d1), np.asarray(d3))
+
+
+class TestExactOneHot:
+    """The bf16 three-part split behind every one-hot contraction of the
+    scan kernels must hold f32 values and f32-encoded ids exactly."""
+
+    @pytest.mark.parametrize("case", ["wide", "ids", "sentinel"])
+    def test_split3_sums_back_exactly(self, case):
+        import jax.numpy as jnp
+
+        from raft_tpu.ops import pq_group_scan_pallas as pqp
+
+        rng = np.random.default_rng(5)
+        x = {"wide": rng.standard_normal(4096) * 10.0 ** rng.integers(
+                 -20, 20, 4096),
+             "ids": rng.integers(0, 1 << 24, 4096),
+             "sentinel": np.array([pqp._ACC_WORST, -1.0, 0.0, 255.0,
+                                   257.0, (1 << 24) - 1])}[case]
+        x = jnp.asarray(x.astype(np.float32))
+        parts = pqp._split3(x)
+        assert all(p.dtype == jnp.bfloat16 for p in parts)
+        back = sum(p.astype(jnp.float32) for p in parts)
+        np.testing.assert_array_equal(np.asarray(back), np.asarray(x))
+
+    def test_query_table_gather_is_exact(self):
+        """A one-hot gather of the split query table returns the f32
+        rows bit-exactly (the rows a bf16 table would round)."""
+        import jax.numpy as jnp
+
+        from raft_tpu.ops import pq_group_scan_pallas as pqp
+
+        rng = np.random.default_rng(6)
+        q = rng.standard_normal((300, 128)).astype(np.float32) * 1e3
+        table = pqp.query_table(jnp.asarray(q), 384, 128)
+        assert table.shape == (3, 384, 128)
+        rows = rng.integers(0, 300, 128)
+        onehot = jnp.asarray(np.eye(384, dtype=np.float32)[rows])
+        got = pqp._gather_rows(onehot, table)
+        np.testing.assert_array_equal(np.asarray(got), q[rows])
+        assert not np.asarray(table[:, 300:]).any()
+
+
+class TestFusedScanMatchesXlaTwin:
+    """The fused in-kernel top-k scans (interpret mode) against the XLA
+    grouped twin at full probe.  k = kt = 10 is not a multiple of the
+    8-row sublane tile, so every staging-ring slot carries pad rows, and
+    eight queries x eight lists leave sentinel slots in every group."""
+
+    @pytest.fixture(scope="class")
+    def case(self):
+        import jax.numpy as jnp
+
+        from raft_tpu import DeviceResources
+        from raft_tpu.filters import SampleFilter, query_filter_words
+        from raft_tpu.neighbors import grouped, ivf_pq
+
+        rng = np.random.default_rng(3)
+        n, nq = 1024, 8
+        data = rng.standard_normal((n, 16)).astype(np.float32)
+        q = jnp.asarray(rng.standard_normal((nq, 16)).astype(np.float32))
+        idx = ivf_pq._with_code_lanes(ivf_pq.build(
+            DeviceResources(seed=0), ivf_pq.IndexParams(n_lists=8, pq_dim=8),
+            data))
+        probes = ivf_pq._select_clusters(idx.centers, idx.rotation, q, 8,
+                                         idx.metric, exact=True)
+        ng, _ = grouped.group_capacity(nq, 8, idx.n_lists)
+        fw = query_filter_words(
+            SampleFilter.from_mask(rng.random((nq, n)) < 0.4), nq, "t")
+        return idx, q, probes, ng, fw
+
+    @pytest.mark.parametrize("kernel", ["recon", "codes"])
+    @pytest.mark.parametrize("filtered", [False, True])
+    def test_fused_equals_xla_twin(self, case, kernel, filtered):
+        from raft_tpu.neighbors import ivf_pq
+
+        idx, q, probes, ng, fw = case
+        fw = fw if filtered else None
+        k = 10
+        ref_d, ref_i = ivf_pq._search_impl_recon_grouped(
+            idx.centers, idx.list_recon, idx.list_recon_sq,
+            idx.list_indices, idx.rotation, q, probes, k, idx.metric, ng,
+            64, use_pallas=False, filter_words=fw)
+        if kernel == "recon":
+            d, i = ivf_pq._search_impl_fused_recon_grouped(
+                idx.centers, idx.list_recon, idx.list_recon_sq,
+                idx.list_indices, idx.rotation, q, probes, k, k,
+                idx.metric, ng, merge_window=3, pallas_interpret=True,
+                filter_words=fw)
+        else:
+            d, i = ivf_pq._search_impl_fused_codes_grouped(
+                idx.centers, idx.codebooks, idx.list_code_lanes,
+                idx.list_code_rsq, idx.list_indices, idx.rotation, q,
+                probes, k, k, idx.metric, ng, idx.pq_bits, merge_window=3,
+                pallas_interpret=True, filter_words=fw)
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(ref_i))
+        np.testing.assert_allclose(np.asarray(d), np.asarray(ref_d),
+                                   rtol=1e-5, atol=1e-5)

@@ -1,0 +1,174 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e.
+
+Interpret-mode tests cannot see what Mosaic refuses (layouts, unaligned
+dynamic stores, VMEM overruns); the TPU compiler is installed here and
+compiles for a chip that is described, not attached.  Shapes are the
+flagship's (SIFT-like 1M x 128, IVF-PQ 4096 lists x cap 256, pq_dim 64
+at 8 bits, nq 5000 x 72 probes, k = kt = 10).
+
+The topology is described inside a module fixture, never at import: only
+one process may load libtpu, and every xdist worker imports this file.
+"""
+
+import functools
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from raft_tpu.neighbors import grouped
+from raft_tpu.ops import cagra_hop_pallas as chp
+from raft_tpu.ops.fused_l2_nn_pallas import fused_l2_nn_pallas
+from raft_tpu.ops import kmeans_update_pallas as kup
+from raft_tpu.ops import pq_code_scan_pallas as pcs
+from raft_tpu.ops import pq_group_scan_pallas as pgs
+
+NQ, N_PROBES, N_LISTS, CAP, ROT, K, KT = 5000, 72, 4096, 256, 128, 10, 10
+PQ_DIM, PQ_BITS, BOOK = 64, 8, 256
+N_DB, DIM, N_CLUSTERS = 1_000_000, 128, 1024
+HOP_NQ, HOP_WD, HOP_PDIM = 64, 32, 32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """Only a missing TPU compiler skips: any other failure to describe
+    the chip (a broken libtpu, a JAX upgrade) fails every test here."""
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("libtpu is not installed: no TPU compiler to run")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    """Build ShapeDtypeStructs on one described chip; the persistent
+    compilation cache is off meanwhile (entries compiled for a described
+    chip cannot be read back without one)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _assert_kernel(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _groups(spec):
+    ng, _ = grouped.group_capacity(NQ, N_PROBES, N_LISTS)
+    return ng, (spec((ng,), jnp.int32), spec((ng, grouped.GROUP), jnp.int32),
+                spec((NQ, ROT), jnp.float32), spec((N_LISTS, ROT), jnp.float32))
+
+
+def _adm(spec, ng, with_adm):
+    return spec((ng, grouped.GROUP, CAP // 32), jnp.int32) if with_adm \
+        else None
+
+
+@pytest.mark.parametrize("with_adm", [False, True])
+def test_fused_recon_scan(spec, with_adm):
+    ng, head = _groups(spec)
+    mw = pgs.fused_merge_window(CAP, ROT, KT, K, NQ)
+    assert mw > 0
+    fn = functools.partial(pgs.grouped_l2_scan_fused, kt=KT, k=K,
+                           n_probes=N_PROBES, merge_window=mw)
+    _assert_kernel(lambda *a: fn(*a[:7], adm_words=a[7]), *head,
+                   spec((N_LISTS, CAP, ROT), jnp.bfloat16),
+                   spec((N_LISTS, CAP), jnp.float32),
+                   spec((N_LISTS, CAP), jnp.int32), _adm(spec, ng, with_adm))
+
+
+@pytest.mark.parametrize("with_adm", [False, True])
+def test_fused_code_scan(spec, with_adm):
+    ng, head = _groups(spec)
+    mw = pcs.fused_codes_merge_window(CAP, ROT, KT, K, NQ, PQ_DIM, PQ_BITS)
+    assert mw > 0
+    fn = functools.partial(pcs.grouped_code_scan_fused, kt=KT, k=K,
+                           n_probes=N_PROBES, pq_bits=PQ_BITS,
+                           merge_window=mw)
+    _assert_kernel(lambda *a: fn(*a[:8], adm_words=a[8]), *head,
+                   spec((N_LISTS, pcs.code_lane_words(PQ_DIM, PQ_BITS), CAP),
+                        jnp.int32),
+                   spec((PQ_DIM, BOOK, ROT // PQ_DIM), jnp.float32),
+                   spec((N_LISTS, CAP), jnp.float32),
+                   spec((N_LISTS, CAP), jnp.int32), _adm(spec, ng, with_adm))
+
+
+def test_group_scan(spec):
+    _, head = _groups(spec)
+    _assert_kernel(functools.partial(pgs.grouped_l2_scan, kt=KT,
+                                     n_probes=N_PROBES), *head,
+                   spec((N_LISTS, CAP, ROT), jnp.bfloat16),
+                   spec((N_LISTS, CAP), jnp.float32),
+                   spec((N_LISTS, CAP), jnp.int32))
+
+
+def test_flat_scan(spec):
+    _, (gl, sp, q, _) = _groups(spec)
+    _assert_kernel(functools.partial(pgs.grouped_flat_l2_scan, kt=KT,
+                                     n_probes=N_PROBES), gl, sp, q,
+                   spec((N_LISTS, CAP, DIM), jnp.float32),
+                   spec((N_LISTS, CAP), jnp.float32),
+                   spec((N_LISTS, CAP), jnp.int32))
+
+
+def test_code_scan(spec):
+    _, head = _groups(spec)
+    _assert_kernel(functools.partial(pcs.grouped_code_scan, kt=KT,
+                                     n_probes=N_PROBES, pq_bits=PQ_BITS),
+                   *head,
+                   spec((N_LISTS, pcs.code_lane_words(PQ_DIM, PQ_BITS), CAP),
+                        jnp.int32),
+                   spec((PQ_DIM, BOOK, ROT // PQ_DIM), jnp.float32),
+                   spec((N_LISTS, CAP), jnp.float32),
+                   spec((N_LISTS, CAP), jnp.int32))
+
+
+def test_recon8_scan(spec):
+    _, head = _groups(spec)
+    _assert_kernel(functools.partial(pcs.grouped_recon8_scan, kt=KT,
+                                     n_probes=N_PROBES), *head,
+                   spec((N_LISTS, CAP, ROT), jnp.int8),
+                   spec((N_LISTS,), jnp.float32),
+                   spec((N_LISTS, CAP), jnp.float32),
+                   spec((N_LISTS, CAP), jnp.int32))
+
+
+def test_fused_l2_nn(spec):
+    _assert_kernel(lambda x, y: fused_l2_nn_pallas(x, y),
+                   spec((N_DB, DIM), jnp.float32),
+                   spec((N_CLUSTERS, DIM), jnp.float32))
+
+
+def test_fused_assign_update(spec):
+    tile = kup.best_tile(N_DB, DIM, N_CLUSTERS, True)
+    assert tile > 0
+    _assert_kernel(functools.partial(kup.fused_assign_update, tile=tile),
+                   spec((N_DB, DIM), jnp.float32),
+                   spec((N_DB,), jnp.float32),
+                   spec((N_CLUSTERS, DIM), jnp.float32))
+
+
+@pytest.mark.parametrize("itopk,variant", [(32, 1), (64, 2)])
+def test_fused_hop(spec, itopk, variant):
+    """itopk 32 takes the legacy in-pass merge, 64 the staged merge."""
+    assert chp.hop_merge_window(HOP_NQ, itopk, HOP_WD, HOP_PDIM) == variant
+    _assert_kernel(
+        functools.partial(chp.fused_hop, itopk=itopk, ip_metric=False),
+        spec((HOP_NQ, HOP_PDIM), jnp.float32), spec((HOP_NQ,), jnp.float32),
+        spec((HOP_NQ, HOP_WD, HOP_PDIM), jnp.float32),
+        spec((HOP_NQ, HOP_WD), jnp.float32), spec((HOP_NQ, HOP_WD), jnp.int32),
+        spec((HOP_NQ, itopk), jnp.float32), spec((HOP_NQ, itopk), jnp.int32),
+        spec((HOP_NQ, itopk), jnp.bool_))
