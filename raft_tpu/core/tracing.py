@@ -1,17 +1,23 @@
 """Tracing / profiling annotations.
 
 Counterpart of the reference's NVTX ranges (cpp/include/raft/core/nvtx.hpp:48-76):
-RAII ``common::nvtx::range<domain>`` plus ``push_range``/``pop_range``, used at
-every algorithm entry point.  On TPU the profiler is ``jax.profiler`` and the
-annotation primitive is ``jax.named_scope`` / ``jax.profiler.TraceAnnotation``;
-we expose the same surface.
+RAII ``common::nvtx::range<domain>``, used at every algorithm entry point.
+On TPU the profiler is ``jax.profiler`` and the annotation primitives are
+``jax.named_scope`` (names ops in traced programs) and
+``jax.profiler.TraceAnnotation`` (a host span on the profiler's clock).
+
+:func:`annotation` is the host span alone: a ``<domain>:<name>`` event on
+the calling thread's line of the profiler's host plane, the same clock the
+device planes are read on.  It costs about a microsecond when no profiler
+session is recording, so it may stay on in hot paths (the stage timers and
+the serving dispatcher open one per phase).  :func:`range` adds the named
+scope on top.
 """
 
 from __future__ import annotations
 
 import contextlib
-import threading
-from typing import Any, Iterator, List
+from typing import Any, Iterator
 
 import jax
 
@@ -23,15 +29,11 @@ class domain:
     raft = "raft_tpu"
 
 
-class _RangeStack(threading.local):
-    """Per-thread stack — jax.named_scope is thread-local, so imperative
-    push/pop must be too (the reference's nvtx ranges are per-thread)."""
-
-    def __init__(self) -> None:
-        self.items: List[Any] = []
-
-
-_range_stack = _RangeStack()
+def annotation(name: str, domain: str = domain.raft
+               ) -> jax.profiler.TraceAnnotation:
+    """The host span ``<domain>:<name>`` on the profiler's clock, with no
+    named scope: a context manager, entered with ``with``."""
+    return jax.profiler.TraceAnnotation(f"{domain}:{name}")
 
 
 @contextlib.contextmanager
@@ -44,20 +46,5 @@ def range(name: str, *fmt_args: Any, domain: str = domain.raft) -> Iterator[None
     """
     if fmt_args:
         name = name % fmt_args
-    label = f"{domain}:{name}"
-    with jax.named_scope(label), jax.profiler.TraceAnnotation(label):
+    with jax.named_scope(f"{domain}:{name}"), annotation(name, domain):
         yield
-
-
-def push_range(name: str, *fmt_args: Any) -> None:
-    """Imperative begin-range (reference: core/nvtx.hpp ``push_range``)."""
-    cm = range(name, *fmt_args)
-    cm.__enter__()
-    _range_stack.items.append(cm)
-
-
-def pop_range() -> None:
-    """Imperative end-range (reference: core/nvtx.hpp ``pop_range``)."""
-    if _range_stack.items:
-        cm = _range_stack.items.pop()
-        cm.__exit__(None, None, None)

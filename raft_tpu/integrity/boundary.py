@@ -23,6 +23,10 @@ Behavior is governed by :func:`raft_tpu.config.get_validation_policy`:
 
 Counters: ``integrity.boundary.checks`` / ``.raised`` / ``.masked_rows``
 (the masked-row count syncs only when observability collection is on).
+
+The ``raise`` policy's check, device or host, runs inside the always-on
+profiler annotation ``raft_tpu:integrity.sync``: on the device path it is
+where the host waits for everything queued before the check.
 """
 
 from __future__ import annotations
@@ -35,6 +39,7 @@ import numpy as np
 
 from raft_tpu import config
 from raft_tpu import observability as obs
+from raft_tpu.core import tracing as _tracing
 from raft_tpu.integrity.errors import ValidationError
 
 
@@ -73,8 +78,10 @@ def guard_nonfinite(x, *, site: str, policy: Optional[str] = None,
     reduce_axes = tuple(range(1, x.ndim))
     ok = jnp.all(jnp.isfinite(x.astype(jnp.float32)), axis=reduce_axes)
     if p == "raise":
-        if not bool(jnp.all(ok)):       # the policy's one host sync
-            bad = int(jnp.argmin(ok))
+        with _tracing.annotation("integrity.sync"):
+            # the policy's one host sync (a second only on a bad row)
+            bad = None if bool(jnp.all(ok)) else int(jnp.argmin(ok))
+        if bad is not None:
             if obs.enabled():
                 obs.registry().counter("integrity.boundary.raised").inc()
             raise ValidationError(
@@ -104,8 +111,9 @@ def _guard_nonfinite_host(x, *, site: str, policy: str
     reduce_axes = tuple(range(1, x.ndim))
     ok = np.all(np.isfinite(x.astype(np.float32)), axis=reduce_axes)
     if policy == "raise":
-        if not bool(np.all(ok)):
-            bad = int(np.argmin(ok))
+        with _tracing.annotation("integrity.sync"):
+            bad = None if bool(np.all(ok)) else int(np.argmin(ok))
+        if bad is not None:
             if obs.enabled():
                 obs.registry().counter("integrity.boundary.raised").inc()
             raise ValidationError(

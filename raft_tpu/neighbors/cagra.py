@@ -608,7 +608,7 @@ def _build_knn_graph_clustered(res, dataset, kg: int, p: IndexParams
 
     # coarse centers on a strided subsample (strided, not leading — see
     # _second_moment), then one assignment pass over all rows
-    with obs.stage("cagra.build.kmeans"):
+    with obs.stage("cagra.build.kmeans") as st:
         n_train = min(n, max(n_lists * 8, max(65536, n // 10)))
         bal = kmeans_balanced.KMeansBalancedParams(
             n_iters=10, metric=p.metric if ip_metric
@@ -619,6 +619,7 @@ def _build_knn_graph_clustered(res, dataset, kg: int, p: IndexParams
         sizes = jax.ops.segment_sum(jnp.ones(n, jnp.int32), labels,
                                     num_segments=n_lists)
         cap = max(-(-int(jnp.max(sizes)) // 8) * 8, 8)  # one host sync
+        st.fence(centers, labels)
 
     # candidate width: enough lists to reach ~build_candidates candidate
     # rows per node, never fewer than build_n_probes lists — per-LIST

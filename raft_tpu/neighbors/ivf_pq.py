@@ -49,6 +49,7 @@ from raft_tpu.core import serialize as ser
 from raft_tpu.core.error import expects
 from raft_tpu.core.interruptible import interruptible
 from raft_tpu.core.mdarray import ensure_array
+from raft_tpu.core import tracing as _tracing
 from raft_tpu.core.tracing import range as named_range
 from raft_tpu import observability as obs
 from raft_tpu.integrity import boundary as _boundary
@@ -1810,7 +1811,11 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
         def run_grouped(stage_label, dispatch):
             with obs.stage(stage_label) as st:
                 out = dispatch(n_groups)
-                if needed_dev is not None and int(needed_dev) > n_groups:
+                overflow = False
+                if needed_dev is not None:
+                    with _tracing.annotation("ivf_pq.search.group_sync"):
+                        overflow = int(needed_dev) > n_groups
+                if overflow:
                     # calibrated capacity exceeded: tick the overflow
                     # counter and re-dispatch at the worst-case bound,
                     # where no pair can drop — results stay exact

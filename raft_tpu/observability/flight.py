@@ -30,6 +30,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import time
 from typing import Any, Dict, List, Optional
 
 from raft_tpu.observability import trace as _trace
@@ -130,6 +131,13 @@ class FlightRecorder:
         ("X") events — the root span plus children; each anomaly is an
         instant ("i") event.  Timestamps are the monotonic trace clock in
         microseconds, so rows are mutually comparable within one process.
+
+        ``otherData["profiler_clock_offset_us"]``, sampled at dump time,
+        is what to add to a ``ts`` to put it on the profiler's host clock
+        (microseconds since the Unix epoch): a ``jax.profiler`` trace's
+        host and device events lie at its ``profile_start_time`` plus
+        their offset, so the dump lays over a device trace of the same
+        process.
         """
         pid = os.getpid()
         ev: List[Dict[str, Any]] = []
@@ -158,8 +166,10 @@ class FlightRecorder:
                         "pid": pid, "tid": rec.trace_id,
                         "args": _materialize(s.attrs or {}),
                     })
+        offset_us = time.time_ns() * 1e-3 - _trace.now() * 1e6
         doc = {"traceEvents": ev, "displayTimeUnit": "ms",
                "otherData": {"generator": "raft_tpu.observability.flight",
+                             "profiler_clock_offset_us": offset_us,
                              **({"reason": reason} if reason else {})}}
         text = json.dumps(doc)
         if path:

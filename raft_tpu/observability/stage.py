@@ -1,11 +1,16 @@
 """``stage(name)`` — the timing primitive, composed with tracing.range.
 
 A stage is one phase of an algorithm (``"cagra.build.scan"``,
-``"ivf_pq.search.coarse"``).  Entering a stage while collection is enabled
+``"ivf_pq.search.coarse"``).  Entering a stage **always** opens the host
+annotation ``raft_tpu:<name>`` (:func:`raft_tpu.core.tracing.annotation`),
+so a profiler trace shows the library's phases on the same clock as the
+device's work whether or not collection is on; with no profiler session
+recording it costs about a microsecond.  While collection is enabled the
+stage also
 
-  * opens the existing :func:`raft_tpu.core.tracing.range` under the **same
-    label**, so the TPU profiler timeline and the metrics registry agree on
-    stage names, and
+  * opens the named scope of :func:`raft_tpu.core.tracing.range` under the
+    **same label**, so the TPU profiler timeline and the metrics registry
+    agree on stage names, and
   * starts a wall clock whose reading is recorded into
     ``registry().timer(name)`` on exit.
 
@@ -13,9 +18,9 @@ JAX dispatch is async, so a wall clock alone would measure enqueue time; the
 yielded handle exposes :meth:`_StageHandle.fence` for the caller to block on
 the stage's outputs before the clock stops.  **When collection is disabled
 (the default) the context manager yields a no-op singleton: no named scope,
-no clock, and — critically — ``fence`` does nothing, so instrumented hot
-paths keep their async dispatch.**  That contract is load-bearing for search
-QPS and is pinned by tests/test_observability.py.
+no clock, no registry write and — critically — ``fence`` does nothing, so
+instrumented hot paths keep their async dispatch.**  That contract is
+load-bearing for search QPS and is pinned by tests/test_observability.py.
 
 Also here: the ``jax.monitoring`` listener that surfaces XLA compile events
 (``/jax/core/compile/*``) as registry metrics, making recompile storms
@@ -31,7 +36,7 @@ import contextlib
 
 import jax
 
-from raft_tpu.core.tracing import range as _trace_range
+from raft_tpu.core import tracing as _tracing
 from raft_tpu.observability import trace as _request_trace
 from raft_tpu.observability.registry import (
     MetricsRegistry,
@@ -96,10 +101,11 @@ def stage(name: str,
     The final fence is the caller's responsibility — without it the timer
     records dispatch time only (still useful for host-loop stages)."""
     if not _enabled():
-        yield _NOOP
+        with _tracing.annotation(name):
+            yield _NOOP
         return
     reg = registry if registry is not None else _registry()
-    with _trace_range(name):
+    with _tracing.range(name):
         t0 = time.perf_counter()
         try:
             yield _StageHandle(name)

@@ -14,6 +14,16 @@ timestamps even when collection is off, because ``max_wait`` and
 deadlines are control flow, not telemetry.  Histograms
 (``serving.latency.queue``, ``.exec``, ``.total`` seconds and
 ``serving.batch_fill``) are recorded only while collection is enabled.
+
+The dispatcher thread opens one always-on profiler annotation per phase
+(:func:`raft_tpu.core.tracing.annotation`; about a microsecond each when
+no profiler is recording): ``raft_tpu:serving.wait`` (waiting until a
+batch is due), ``serving.batch_cut`` (host assembly of the padded batch
+and its upload), ``serving.dispatch`` (the executor call),
+``serving.readback`` (the results' copy to the host) and
+``serving.resolve`` (slicing, masks, the futures and their callbacks, the
+shadow offer).  A device trace can then say which phase the chip waited
+on.
 """
 
 from __future__ import annotations
@@ -26,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from raft_tpu import observability as obs
+from raft_tpu.core import tracing as _tracing
 from raft_tpu.observability import flight as _flight
 from raft_tpu.observability import trace as _trace
 from raft_tpu.resilience import faults as _faults
@@ -94,7 +105,7 @@ class DynamicBatcher:
         self._drain = True
         while True:
             batch = None
-            with self.queue.cond:
+            with _tracing.annotation("serving.wait"), self.queue.cond:
                 while True:
                     if self._stop and (not self._drain or not len(self.queue)):
                         break
@@ -176,6 +187,91 @@ class DynamicBatcher:
                                          trace_id=traced[0].trace.trace_id,
                                          t0=t_dispatch)
                      if traced else None)
+        try:
+            with _tracing.annotation("serving.batch_cut"):
+                buf, fbuf = self._assemble(live, bucket)
+                t_exec0 = time.monotonic()
+                # the generation snapshot this batch serves from — pinned
+                # here so the shadow monitor can refuse to compare across
+                # a swap
+                idx_gen = self.executor.index
+                # named fault site: latency plans here (faults.delay_at)
+                # are how the chaos bench/CI slow the serving path down
+                # on demand; inactive it is one None check on the hot path
+                _faults.maybe_fail("serving.dispatch")
+                # kwarg only when a live request carries a filter, so
+                # executors (and test doubles) with the pre-filter
+                # search_bucket signature keep working unfiltered
+                fkw = ({"filter_words": jnp.asarray(fbuf)}
+                       if fbuf is not None else {})
+                q = jnp.asarray(buf)
+            with _tracing.annotation("serving.dispatch"), \
+                    _trace.activating(batch_rec):
+                d, i = self.executor.search_bucket(q, n, k, rung=rung, **fkw)
+            with _tracing.annotation("serving.readback"):
+                # graftlint: disable=host-sync -- THE one readback: results must leave the device to resolve request futures
+                d, i = np.asarray(d), np.asarray(i)
+        except BaseException as e:  # noqa: BLE001 - forwarded per request
+            _flight.record_event("serving.batch_error",
+                                 trace_id=(traced[0].trace.trace_id
+                                           if traced else None),
+                                 error=repr(e), rows=n, bucket=bucket, k=k)
+            for r in traced:
+                r.trace.annotate("error", repr(e))
+                _flight.record_trace(r.trace.close())
+            # post-mortem artifact: if RAFT_TPU_FLIGHT_DUMP is set, the
+            # ring (this error included) is written before futures fail
+            _flight.maybe_auto_dump("serving.batch_error")
+            for r in live:
+                r.future.set_exception(e)
+            if self._on_error is not None:
+                self._on_error(e)
+            return
+        t_done = time.monotonic()
+        with _tracing.annotation("serving.resolve"):
+            if batch_rec is not None:
+                batch_rec.span("serving.batch_cut", t_dispatch, t_exec0,
+                               rows=n, bucket=bucket, requests=len(live))
+                batch_rec.span("serving.exec", t_exec0, t_done)
+                if level:
+                    batch_rec.annotate("brownout_level", level)
+                    batch_rec.annotate("rung", rung)
+            self._record(live, n, bucket, t_dispatch, t_done)
+            off = 0
+            worst = np.inf if self.executor.select_min else -np.inf
+            results = []
+            for r in live:
+                rd = d[off:off + r.n]
+                ri = i[off:off + r.n]
+                if r.ok_rows is not None:
+                    # per-request boundary mask (policy "mask"): same output
+                    # contract as integrity.boundary.mask_search_outputs,
+                    # applied host-side on the already-fetched slice
+                    bad = ~np.asarray(r.ok_rows)[:, None]
+                    rd = np.where(bad, np.asarray(worst, rd.dtype), rd)
+                    ri = np.where(bad, np.asarray(-1, ri.dtype), ri)
+                off += r.n
+                results.append((r, rd, ri))
+            t_sliced = time.monotonic()
+            for r, rd, ri in results:
+                if r.trace is not None:
+                    rt = r.trace
+                    rt.span("serving.queue", r.t_enqueue, t_dispatch)
+                    rt.adopt(batch_rec)
+                    rt.span("serving.result_slice", t_done, t_sliced)
+                    _flight.record_trace(rt.close(t_sliced))
+                r.future.set_result((rd, ri))
+            sh = self.shadow
+            if sh is not None:
+                # host-side arrays only — the sampler must add no device
+                # work to this thread (see ShadowMonitor.offer)
+                sh.offer(results, k, idx_gen, rung)
+            if self._on_batch is not None:
+                self._on_batch(n, bucket)
+
+    def _assemble(self, live, bucket: int):
+        """The padded ``(bucket, dim)`` query batch and, where a live
+        request carries a filter, its ``(bucket, n_words)`` bitsets."""
         # batch assembly and result slicing are HOST-side numpy: request
         # sizes vary continuously, and any jnp op keyed on them
         # (concatenate / pad / slice) would compile per novel shape —
@@ -201,81 +297,7 @@ class DynamicBatcher:
                 if r.filter_words is not None:
                     fbuf[off:off + r.n] = r.filter_words
                 off += r.n
-        t_exec0 = time.monotonic()
-        # the generation snapshot this batch serves from — pinned here so
-        # the shadow monitor can refuse to compare across a swap
-        idx_gen = self.executor.index
-        try:
-            # named fault site: latency plans here (faults.delay_at) are
-            # how the chaos bench/CI slow the serving path down on
-            # demand; inactive it is one None check on the hot path
-            _faults.maybe_fail("serving.dispatch")
-            with _trace.activating(batch_rec):
-                # kwarg only when a live request carries a filter, so
-                # executors (and test doubles) with the pre-filter
-                # search_bucket signature keep working unfiltered
-                fkw = ({"filter_words": jnp.asarray(fbuf)}
-                       if fbuf is not None else {})
-                d, i = self.executor.search_bucket(
-                    jnp.asarray(buf), n, k, rung=rung, **fkw)
-                # graftlint: disable=host-sync -- THE one readback: results must leave the device to resolve request futures
-                d, i = np.asarray(d), np.asarray(i)
-        except BaseException as e:  # noqa: BLE001 - forwarded per request
-            _flight.record_event("serving.batch_error",
-                                 trace_id=(traced[0].trace.trace_id
-                                           if traced else None),
-                                 error=repr(e), rows=n, bucket=bucket, k=k)
-            for r in traced:
-                r.trace.annotate("error", repr(e))
-                _flight.record_trace(r.trace.close())
-            # post-mortem artifact: if RAFT_TPU_FLIGHT_DUMP is set, the
-            # ring (this error included) is written before futures fail
-            _flight.maybe_auto_dump("serving.batch_error")
-            for r in live:
-                r.future.set_exception(e)
-            if self._on_error is not None:
-                self._on_error(e)
-            return
-        t_done = time.monotonic()
-        if batch_rec is not None:
-            batch_rec.span("serving.batch_cut", t_dispatch, t_exec0,
-                           rows=n, bucket=bucket, requests=len(live))
-            batch_rec.span("serving.exec", t_exec0, t_done)
-            if level:
-                batch_rec.annotate("brownout_level", level)
-                batch_rec.annotate("rung", rung)
-        self._record(live, n, bucket, t_dispatch, t_done)
-        off = 0
-        worst = np.inf if self.executor.select_min else -np.inf
-        results = []
-        for r in live:
-            rd = d[off:off + r.n]
-            ri = i[off:off + r.n]
-            if r.ok_rows is not None:
-                # per-request boundary mask (policy "mask"): same output
-                # contract as integrity.boundary.mask_search_outputs,
-                # applied host-side on the already-fetched slice
-                bad = ~np.asarray(r.ok_rows)[:, None]
-                rd = np.where(bad, np.asarray(worst, rd.dtype), rd)
-                ri = np.where(bad, np.asarray(-1, ri.dtype), ri)
-            off += r.n
-            results.append((r, rd, ri))
-        t_sliced = time.monotonic()
-        for r, rd, ri in results:
-            if r.trace is not None:
-                rt = r.trace
-                rt.span("serving.queue", r.t_enqueue, t_dispatch)
-                rt.adopt(batch_rec)
-                rt.span("serving.result_slice", t_done, t_sliced)
-                _flight.record_trace(rt.close(t_sliced))
-            r.future.set_result((rd, ri))
-        sh = self.shadow
-        if sh is not None:
-            # host-side arrays only — the sampler must add no device
-            # work to this thread (see ShadowMonitor.offer)
-            sh.offer(results, k, idx_gen, rung)
-        if self._on_batch is not None:
-            self._on_batch(n, bucket)
+        return buf, fbuf
 
     def _record(self, live, n, bucket, t_dispatch, t_done) -> None:
         if not obs.enabled():

@@ -96,12 +96,49 @@ class TestExport:
         assert "raft_tpu_k_total 1" in obs.to_prometheus()
 
 
+def _record_annotations(monkeypatch):
+    """Route the host-annotation primitive through a recorder; returns the
+    list of labels opened."""
+    from raft_tpu.core import tracing
+    opened, real = [], tracing.annotation
+
+    def recording(name, *a):
+        opened.append(name)
+        return real(name, *a)
+    monkeypatch.setattr(tracing, "annotation", recording)
+    return opened
+
+
 class TestStage:
-    def test_disabled_is_noop(self):
+    def test_disabled_is_noop(self, monkeypatch):
+        """Collection off: the stage opens its host annotation and nothing
+        else — no named scope, no clock, no fence, no registry write."""
+        from types import SimpleNamespace
+
+        from raft_tpu.core import tracing
+        from raft_tpu.observability import trace
+
+        def forbidden(*a, **kw):
+            raise AssertionError("disabled stage did timing work")
+        opened = _record_annotations(monkeypatch)
+        monkeypatch.setattr(tracing, "range", forbidden)
+        monkeypatch.setattr(stage_mod, "_block_until_ready", forbidden)
+        monkeypatch.setattr(stage_mod, "time",
+                            SimpleNamespace(perf_counter=forbidden))
+        monkeypatch.setattr(trace, "stage_hook", forbidden)
         assert not obs.enabled()
         with obs.stage("nothing") as st:
             st.fence(jnp.zeros(3))
+        assert opened == ["nothing"]
         assert obs.snapshot()["timers"] == {}
+
+    def test_enabled_opens_the_same_annotation(self, monkeypatch):
+        opened = _record_annotations(monkeypatch)
+        with obs.collecting():
+            with obs.stage("work") as st:
+                st.fence(jnp.arange(4))
+        assert opened == ["work"]
+        assert obs.snapshot()["timers"]["work"]["count"] == 1
 
     def test_disabled_shares_singleton(self):
         with obs.stage("a") as h1:

@@ -693,6 +693,89 @@ class TestServingTracing:
 # histogram metric (observability satellite)
 
 
+class _EchoExecutor:
+    """The executor surface the server and batcher read, with no index:
+    each row's answer is its own first column."""
+
+    max_batch, ks, dim, buckets = 8, (2,), 4, (1, 2, 4, 8)
+    query_dtype, select_min, index = np.float32, True, None
+
+    def warmup(self):
+        return 0
+
+    def search_bucket(self, queries, n_valid, k, rung=0):
+        col = queries[:, :1]
+        return jnp.tile(col, (1, k)), jnp.zeros((queries.shape[0], k),
+                                                jnp.int32)
+
+
+class TestDispatchSpans:
+    PHASES = ["serving.wait", "serving.batch_cut", "serving.dispatch",
+              "serving.readback", "serving.resolve"]
+
+    def test_dispatcher_opens_one_annotation_per_phase(self, monkeypatch):
+        """The dispatcher thread walks wait -> batch_cut -> dispatch ->
+        readback -> resolve, each phase closed before the next opens; the
+        submit path's boundary check runs inside integrity.sync on the
+        caller's thread."""
+        import contextlib
+
+        from raft_tpu.core import tracing
+
+        log = []
+
+        @contextlib.contextmanager
+        def recording(name, *a):
+            log.append(("enter", name, threading.current_thread().name))
+            try:
+                yield
+            finally:
+                log.append(("exit", name, threading.current_thread().name))
+        monkeypatch.setattr(tracing, "annotation", recording)
+        cfg = serving.ServerConfig(max_batch=8, max_wait_us=1_000)
+        with serving.Server(_EchoExecutor(), cfg) as srv:
+            d, i = srv.search(np.full((3, 4), 7.0, np.float32), 2)
+        np.testing.assert_array_equal(d, 7.0)
+        batcher = [(what, name) for what, name, th in log
+                   if th == "raft-tpu-serving-batcher"]
+        # the first batch's phases, each a closed span, in order (the
+        # loop then waits again until the server stops)
+        assert batcher[:10] == [(w, n) for n in self.PHASES
+                                for w in ("enter", "exit")]
+        assert batcher[10:] == [("enter", "serving.wait"),
+                                ("exit", "serving.wait")]
+        caller = threading.current_thread().name
+        assert ("enter", "integrity.sync", caller) in log
+
+    def test_callbacks_run_inside_resolve(self, monkeypatch):
+        """A request's done-callbacks run on the dispatcher thread inside
+        serving.resolve."""
+        import contextlib
+
+        from raft_tpu.core import tracing
+
+        current = []
+
+        @contextlib.contextmanager
+        def recording(name, *a):
+            current.append(name)
+            try:
+                yield
+            finally:
+                current.pop()
+        monkeypatch.setattr(tracing, "annotation", recording)
+        seen_in, gate = [], threading.Event()
+        cfg = serving.ServerConfig(max_batch=8, max_wait_us=1_000)
+        with serving.Server(_EchoExecutor(), cfg) as srv:
+            srv.batcher.stop(drain=False)        # hold the request queued
+            fut = srv.submit(np.ones((2, 4), np.float32), 2)
+            fut.add_done_callback(
+                lambda f: (seen_in.append(list(current)), gate.set()))
+            srv.batcher.start()
+            assert gate.wait(timeout=10)
+        assert seen_in == [["serving.resolve"]]
+
+
 class TestHistogram:
     def test_observe_and_quantiles(self):
         reg = obs.MetricsRegistry()

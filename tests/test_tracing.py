@@ -170,6 +170,35 @@ class TestFlightRecorder:
         assert instant[0]["name"] == "distributed.degraded_search"
         assert instant[0]["args"] == {"failed": [2]}
 
+    def test_dump_lays_over_a_profiler_trace(self, tmp_path):
+        """otherData's clock offset puts a flight event recorded inside a
+        profiler annotation inside that annotation on the trace's host
+        clock (the trace's profile_start_time plus the event's offset)."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with jax.profiler.TraceAnnotation("raft_tpu:flight.probe"):
+                flight.record_event("serving.batch_error", error="probe")
+        finally:
+            jax.profiler.stop_trace()
+        doc = json.loads(flight.dump())
+        ev = next(e for e in doc["traceEvents"] if e["ph"] == "i")
+        at_ns = (ev["ts"] + doc["otherData"]["profiler_clock_offset_us"]) * 1e3
+        pd = ProfileData.from_file(glob.glob(
+            str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0])
+        start = next(dict(p.stats)["profile_start_time"] for p in pd.planes
+                     if p.name == "Task Environment")
+        probe = next(e for p in pd.planes if p.name.startswith("/host:")
+                     for line in p.lines for e in line.events
+                     if e.name == "raft_tpu:flight.probe")
+        slack_ns = 1e6
+        assert start + probe.start_ns - slack_ns <= at_ns
+        assert at_ns <= start + probe.end_ns + slack_ns
+
     def test_maybe_auto_dump_env_gated(self, tmp_path, monkeypatch):
         monkeypatch.delenv(flight.DUMP_ENV, raising=False)
         assert flight.maybe_auto_dump("x") is None
