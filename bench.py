@@ -121,8 +121,8 @@ OPERATING_POINTS = (
     dict(n_probes=72, refine_ratio=2, scan_mode="recon8"),
     dict(n_probes=72, refine_ratio=2, scan_mode="recon8", per_probe_topk=4),
     # round-7 fused in-kernel top-k: scan + extraction in ONE stage,
-    # candidate distance matrices never reach HBM (since round 14 these
-    # resolve merge_window="auto" — the windowed merge engine)
+    # candidate distance matrices never reach HBM (merge_window is
+    # accepted; the scans merge every grid step)
     dict(n_probes=72, refine_ratio=2, scan_mode="fused"),
     dict(n_probes=72, refine_ratio=2, scan_mode="fused", per_probe_topk=4),
     dict(n_probes=96, refine_ratio=2, scan_mode="fused", per_probe_topk=4),
@@ -255,13 +255,11 @@ def _search_stage_probe(res, index, queries) -> dict:
 
 
 def _fused_windowed_grid(res, index, queries) -> list:
-    """Round-14 grid: the windowed fused-scan merge engine across
-    (k, merge_window) at batch :data:`FUSED_WINDOWED_BATCH` and matched
-    kt.  Results are bit-identical across W (the merge is
-    order-insensitive over the finite-sentinel staging ring) — only QPS
-    moves, so the grid reports QPS plus the fused_fallback tick delta
-    that proves the fused kernel actually served the point (large k is
-    exactly where the old per-step merge used to fall back)."""
+    """Round-14 grid: the fused scans across (k, merge_window) at batch
+    :data:`FUSED_WINDOWED_BATCH` and matched kt.  Results are
+    bit-identical across W (the row-addressed kernels merge every grid
+    step whatever W is), so the grid reports QPS plus the fused_fallback
+    tick delta that proves the fused kernel actually served the point."""
     from raft_tpu import observability as obs
     from raft_tpu.neighbors import ivf_pq
 

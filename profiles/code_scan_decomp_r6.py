@@ -6,13 +6,11 @@ Two parts:
   HBM traffic of each scan mode at the bench shape — the acceptance
   number for the compact-code path (codes bytes/row must be < half the
   recon path's) — plus the per-batch totals implied by the measured
-  group count, and (round 14) the merge-side decomposition: selection
-  rows swept per grid step by the per-step merge vs the windowed merge
-  at the modeled optimal W, for k in {10, 64, 128, 256}.
+  group count.
 - on-chip timing (default): kernel-only A/B of recon vs codes vs recon8
-  at matched (n_probes, kt), isolating the scan from coarse select and
-  refine, plus (round 14) a windowed-vs-per-step fused A/B at the
-  flagship shape; --trace captures a profiler trace of all three.
+  (and the fused kernels) at matched (n_probes, kt), isolating the scan
+  from coarse select and refine; --trace captures a profiler trace of
+  all three.
 
 Run on the real chip:  python profiles/code_scan_decomp_r6.py [--trace]
 Traffic model only:    python profiles/code_scan_decomp_r6.py --model
@@ -72,36 +70,6 @@ def output_model(kt, k, nq, n_probes, n_groups, group=128):
     return split_total, fused_total
 
 
-def merge_model(kt, nq, cap, rot, group=128):
-    """Round-14 columns: the MERGE side of the fused scan — the cost the
-    windowed staging ring amortizes.  The per-step merge sweeps a
-    (k + kt, cols) concat k times every grid step; the windowed merge
-    stages W steps with an O(kt) one-hot write and sweeps the
-    (k + kt*W, cols) concat only every W-th step, so the amortized
-    per-step selection rows drop by ~(k + kt) * W / (k + kt*W).  W is
-    the budget model's host-static choice (ops.vmem_budget via
-    pq_group_scan_pallas.fused_merge_window) at this shape."""
-    from raft_tpu.ops import pq_group_scan_pallas as pqp
-
-    print(f"fused-scan merge decomposition at kt={kt}, nq={nq} "
-          f"(stream side: {cap * rot * 2} B recon bytes per group, "
-          "for scale):")
-    for k in (10, 64, 128, 256):
-        per_step = k * (k + kt)
-        W = pqp.fused_merge_window(cap, rot, kt, k, nq)
-        if W == 0:
-            reason = pqp.fused_reject_reason(True, cap, rot, kt, k, nq)
-            print(f"  k={k:>3}: fused unsupported ({reason})")
-            continue
-        # amortized selection rows per grid step + the staging write
-        windowed = k * (k + kt * W) / W + 2 * kt
-        note = "" if k <= 64 else "  (per-step merge hypothetical: the" \
-                                  " unrolled path gates k<=64)"
-        print(f"  k={k:>3}: per-step {per_step:6d} rows/step   "
-              f"windowed W={W}: {windowed:8.0f} rows/step   "
-              f"{per_step / windowed:5.2f}x fewer{note}")
-
-
 def main():
     import jax
 
@@ -120,12 +88,6 @@ def main():
         traffic_model(cap, rot, pq_dim, pq_bits, n_groups)
         output_model(kt=4, k=10, nq=5_000, n_probes=96,
                      n_groups=n_groups)
-        # round 14: the merge side at the flagship batch and at the
-        # large-k operating points the windowed engine unlocks (large k
-        # exceeds the flagship's VMEM at nq=5000 — model the serving
-        # large-k bucket's batch as well)
-        merge_model(kt=16, nq=5_000, cap=cap, rot=rot)
-        merge_model(kt=16, nq=1_024, cap=cap, rot=rot)
         return
 
     bench._setup_jax_cache()
@@ -179,18 +141,17 @@ def main():
             queries, probes, k, kt_, m, n_groups, block8, use_pallas=True,
             packed=packed)[1]
 
-    def run_fused_codes(kt_, mw=1):
+    def run_fused_codes(kt_):
         return ivf_pq._search_impl_fused_codes_grouped(
             index.centers, index.codebooks, index.list_code_lanes,
             index.list_code_rsq, index.list_indices, index.rotation,
-            queries, probes, k, kt_, m, n_groups, index.pq_bits,
-            merge_window=mw)[1]
+            queries, probes, k, kt_, m, n_groups, index.pq_bits)[1]
 
-    def run_fused_recon(kt_, mw=1):
+    def run_fused_recon(kt_):
         return ivf_pq._search_impl_fused_recon_grouped(
             index.centers, index.list_recon, index.list_recon_sq,
             index.list_indices, index.rotation, queries, probes, k, kt_,
-            m, n_groups, merge_window=mw)[1]
+            m, n_groups)[1]
 
     variants = [
         ("recon      kt=k ", lambda: run_recon(0)),
@@ -205,20 +166,6 @@ def main():
         (f"fused-cod  kt={kt} ", lambda: run_fused_codes(kt)),
         (f"fused-rec  kt={kt} ", lambda: run_fused_recon(kt)),
     ]
-    # round-14: windowed merge A/B at the flagship shape — same kernels,
-    # merge every W-th grid step instead of every step (bit-identical)
-    from raft_tpu.ops import pq_code_scan_pallas as pcs_mod
-    from raft_tpu.ops import pq_group_scan_pallas as pqp_mod
-    w_cod = pcs_mod.fused_codes_merge_window(cap, rot, kt, k,
-                                             queries.shape[0],
-                                             index.pq_dim, index.pq_bits)
-    w_rec = pqp_mod.fused_merge_window(cap, rot, kt, k, queries.shape[0])
-    if w_cod > 1:
-        variants.append((f"fused-cod  W={w_cod}  ",
-                         lambda: run_fused_codes(kt, mw=w_cod)))
-    if w_rec > 1:
-        variants.append((f"fused-rec  W={w_rec}  ",
-                         lambda: run_fused_recon(kt, mw=w_rec)))
     timed = {}
     for name, fn in variants:
         i = fn()
@@ -239,15 +186,8 @@ def main():
     print(f"measured extraction elimination (codes kt={kt} -> fused): "
           f"{(split - fused) * 1e3:+.1f} ms/batch "
           f"({split / fused:.2f}x)")
-    if w_rec > 1:
-        w1 = timed[f"fused-rec  kt={kt}".strip()]
-        ww = timed[f"fused-rec  W={w_rec}".strip()]
-        print(f"measured windowed-merge gain (fused-rec W=1 -> "
-              f"W={w_rec}): {(w1 - ww) * 1e3:+.1f} ms/batch "
-              f"({w1 / ww:.2f}x)")
     output_model(kt=kt, k=k, nq=queries.shape[0], n_probes=n_probes,
                  n_groups=n_groups)
-    merge_model(kt=kt, nq=queries.shape[0], cap=cap, rot=rot)
 
     if "--trace" in sys.argv:
         with jax.profiler.trace("profiles/code_scan_trace"):

@@ -1,0 +1,172 @@
+"""Time the fused IVF-PQ recon list scan alone, per grid step.
+
+    python3 profiles/fused_scan_addressing.py [--nq 64,640,2560,5000]
+        [--groups 6909] [--cap 416] [--reps 10] [--cell-groups 0]
+
+Runs ``pq_group_scan_pallas.grouped_l2_scan_fused`` of the checkout it
+is started from at the SIFT-1M IVF-PQ cell's kernel shape (4,096 lists
+of ``--cap`` rows, rot 128, k = kt = 20, 72 probes) on synthetic lists,
+and prints one JSON line per (nq, merge window): milliseconds per call,
+microseconds per grid step, and a digest of the answers (values at every
+rank, ids at every live rank, in query-major order) so two checkouts can
+be compared for bit-identity.
+
+Each nq in ``--nq`` runs at ``--groups`` grid steps with every slot
+holding a real query (query ids cycle below nq, distinct in a group), so
+only the batch width changes between lines.  Widths below 128 queries
+cannot fill a group with distinct queries; they run at the shape the
+search dispatches instead (``grouped.group_capacity`` groups built from
+random probes).  Where the search's automatic merge window is not 1 at a
+shape, that window is timed too.
+
+``--cell-groups N`` builds the cell's index from ``benchmark/data.py``
+(seed 1), cuts N batches of 5,000 queries from the pool and prints how
+many pair groups each needs (``grouped.num_groups``) against the static
+capacity the search dispatches at.
+
+``--tiny`` rehearses the whole script off the chip at a toy size in
+Pallas interpret mode.
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import hashlib
+import json
+import sys
+import time
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from raft_tpu.neighbors import grouped  # noqa: E402
+from raft_tpu.ops import pq_group_scan_pallas as pgs  # noqa: E402
+
+N_PROBES, ROT, K = 72, 128, 20
+
+
+def lists(key, n_lists, cap):
+    kr, kc, ki = jax.random.split(key, 3)
+    recon = jax.random.normal(kr, (n_lists, cap, ROT), jnp.bfloat16)
+    rsq = jnp.sum(recon.astype(jnp.float32) ** 2, axis=-1)
+    ids = jax.random.randint(ki, (n_lists, cap), 0, 1 << 20, jnp.int32)
+    # the allocator pads every list: the last eighth of each is empty
+    ids = jnp.where(jnp.arange(cap)[None, :] >= cap - cap // 8, -1, ids)
+    centers = jax.random.normal(kc, (n_lists, ROT), jnp.float32)
+    return centers, recon, rsq, ids
+
+
+def full_groups(nq, n_groups, n_lists):
+    """Every slot real: group g scans list g % n_lists with queries
+    (g*128 + r) % nq, distinct within the group when nq >= 128."""
+    g = np.arange(n_groups)[:, None]
+    r = np.arange(grouped.GROUP)[None, :]
+    q = (g * grouped.GROUP + r) % nq
+    slots = (q * N_PROBES + g % N_PROBES).astype(np.int32)
+    return (jnp.asarray((np.arange(n_groups) % n_lists).astype(np.int32)),
+            jnp.asarray(slots))
+
+
+def probe_groups(rng, nq, n_lists):
+    probes = np.stack([rng.choice(n_lists, N_PROBES, replace=False)
+                       for _ in range(nq)]).astype(np.int32)
+    ng, _ = grouped.group_capacity(nq, N_PROBES, n_lists)
+    return grouped.build_groups(jnp.asarray(probes), n_lists, ng)
+
+
+def digest(v, i, nq):
+    """Query-major (nq, k) answers, whatever layout the kernel emits."""
+    v, i = np.asarray(v), np.asarray(i)
+    if v.shape[0] == K and v.shape[1] != K:
+        v, i = v.T, i.T
+    v, i = v[:nq, :K], i[:nq, :K]
+    i = np.where(v < pgs._ACC_WORST / 2, i, -1)
+    return hashlib.sha256(v.tobytes() + i.tobytes()).hexdigest()[:16]
+
+
+def time_call(fn, args, reps):
+    out = fn(*args)
+    jax.block_until_ready(out)
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / reps, out
+
+
+def cell_groups(n_batches):
+    from benchmark import data
+    from raft_tpu import DeviceResources
+    from raft_tpu.neighbors import ivf_pq
+
+    conf = json.loads((ROOT / "benchmark/configs/sift1m-ivfpq.json")
+                      .read_text())
+    db, pool = data.make(1, conf["dataset"])
+    res = DeviceResources()
+    index = ivf_pq.build(res, ivf_pq.IndexParams(**conf["index"]["build"]),
+                         db)
+    n_probes = conf["index"]["search"]["n_probes"]
+    for b in range(n_batches):
+        q = pool[b * 5000:(b + 1) * 5000]
+        probes = ivf_pq._select_clusters(index.centers, index.rotation, q,
+                                         n_probes, index.metric)
+        cap_groups, _ = grouped.group_capacity(q.shape[0], n_probes,
+                                               index.n_lists)
+        print(json.dumps({
+            "batch": b, "num_groups": int(grouped.num_groups(
+                probes, index.n_lists)),
+            "capacity": cap_groups, "list_cap": int(index.capacity)}),
+            flush=True)
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--nq", default="64,640,2560,5000")
+    ap.add_argument("--groups", type=int, default=6909)
+    ap.add_argument("--cap", type=int, default=416)
+    ap.add_argument("--lists", type=int, default=4096)
+    ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--cell-groups", type=int, default=0)
+    ap.add_argument("--tiny", action="store_true")
+    a = ap.parse_args()
+    if a.tiny:
+        a.groups, a.cap, a.lists, a.reps = 80, 32, 128, 1
+    dev = jax.devices()[0]
+    interpret = dev.platform != "tpu"
+    if interpret and not a.tiny:
+        raise SystemExit("needs a TPU (or --tiny to rehearse)")
+    centers, recon, rsq, ids = lists(jax.random.PRNGKey(0), a.lists, a.cap)
+    rng = np.random.default_rng(0)
+    for nq in (int(x) for x in a.nq.split(",")):
+        if nq >= grouped.GROUP:
+            gl, sp = full_groups(nq, a.groups, a.lists)
+        else:
+            gl, sp = probe_groups(rng, nq, a.lists)
+        qrot = jax.random.normal(jax.random.PRNGKey(nq), (nq, ROT),
+                                 jnp.float32)
+        auto = pgs.fused_merge_window(a.cap, ROT, K, K, nq)
+        for w in sorted({1, auto} - {0}):
+            fn = jax.jit(functools.partial(
+                pgs.grouped_l2_scan_fused, kt=K, k=K, n_probes=N_PROBES,
+                merge_window=w, interpret=interpret))
+            args = (gl, sp, qrot, centers, recon, rsq, ids)
+            sec, (v, i) = time_call(fn, args, a.reps)
+            ng = int(gl.shape[0])
+            print(json.dumps({
+                "nq": nq, "n_groups": ng, "merge_window": w,
+                "auto_window": auto, "ms_per_call": sec * 1e3,
+                "us_per_step": sec * 1e6 / ng,
+                "digest": digest(v, i, nq),
+                "device": dev.device_kind}), flush=True)
+    if a.cell_groups:
+        cell_groups(a.cell_groups)
+
+
+if __name__ == "__main__":
+    main()

@@ -288,8 +288,7 @@ def export_ivf_pq_search(res, index, n_probes: int, k: int, batch: int,
 
     ``merge_window`` ("auto" | int, see
     :data:`raft_tpu.neighbors.ivf_pq.SearchParams.merge_window`) windows
-    the baked grouped scan's staged scatter (the XLA twin of the fused
-    kernels' staging ring) and keys the artifact in
+    the baked grouped scan's staged scatter and keys the artifact in
     :class:`ExecutableCache` — serving pre-warms one executable per
     (bucket, k, merge_window) point, so the live Pallas dispatch and the
     exported twin share a cache dimension.  Ignored by the non-grouped

@@ -12,7 +12,7 @@ HBM traffic per candidate instead of 32.
 
 The admission seam reuses the tombstone seam (PRs 7/8/10): an
 inadmissible candidate folds to the finite ``_ACC_WORST`` distance and
-id -1 *before* top-k / the fused windowed merge, so filtered results are
+id -1 *before* top-k / the fused merge, so filtered results are
 bit-identical to a post-hoc filtered exact scan at full probe — the same
 kernel computes the same distances; folding a row to worst before
 selection is equivalent to removing it from the candidate set.

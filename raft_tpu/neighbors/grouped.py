@@ -356,9 +356,9 @@ def scan_and_scatter(group_list, slot_pairs, P, cap, k, select_min, block,
     clamped tail block emits duplicate pairs with identical values, so the
     final scatter stays idempotent.
 
-    ``merge_window`` is the XLA twin of the fused kernels' staging ring
-    (ops.vmem_budget): 0 stages every block's outputs before the single
-    scatter (the round-7 shape, maximal staging footprint); W >= 1
+    ``merge_window`` windows the staged scatter: 0 stages every block's
+    outputs before the single scatter (the round-7 shape, maximal
+    staging footprint); W >= 1
     scatters once per W-block window inside an outer scan, bounding the
     staged (n_blocks * B * GROUP, kt) output pair to W blocks at the
     cost of one (P, kt) carry copy per window instead of none.  Exact

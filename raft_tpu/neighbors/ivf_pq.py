@@ -162,14 +162,11 @@ class SearchParams:
     # cost of truncating ~log2(capacity) distance mantissa bits (~2^-13
     # relative at bench shapes; ordering-only effect, far below PQ noise).
     packed_extract: bool = False
-    # Fused-scan merge window W: the fused kernels stage each grid
-    # step's kt candidates into a VMEM ring and pay the full top-k merge
-    # only every W-th step (~W x fewer merge passes; bit-identical
-    # results — the merge is order-insensitive over the finite-sentinel
-    # ring).  "auto" (or 0) picks the largest W the kernel's VMEM budget
-    # admits via ops.vmem_budget; an explicit int >= 1 is honored as an
-    # upper bound (1 = the round-7 per-step merge).  Also selects the
-    # staged CAGRA-hop merge — see cagra.SearchParams.merge_window.
+    # Merge window W ("auto" / 0, or an int >= 1).  The fused IVF-PQ
+    # scans merge every grid step whatever W is (results never depend
+    # on it); it windows the XLA grouped scan's staged scatter, keys
+    # the serving executable cache, and selects the staged CAGRA-hop
+    # merge — see cagra.SearchParams.merge_window.
     merge_window: object = "auto"
 
 
@@ -1367,15 +1364,15 @@ def _search_impl_recon8_grouped(centers, list_recon_i8, list_recon_scale,
 
 
 def _fused_epilogue(vals, ids, qorder, nq, k, metric):
-    """Shared tail of the fused scans: column-major (k, nq_pad) kernel
+    """Shared tail of the fused scans: query-major (nq_pad, k) kernel
     output -> (nq, k) rows, finite-worst sentinel -> the public +inf /
     id -1 contract, sqrt for the sqrt-L2 metrics, and the un-permute of
     the probe-overlap query order.  Note what is ABSENT: no scatter and
     no select — the kernel already holds each query's final top-k."""
     from raft_tpu.ops.pq_group_scan_pallas import _ACC_WORST
 
-    d = vals[:, :nq].T
-    i = ids[:, :nq].T
+    d = vals[:nq]
+    i = ids[:nq]
     bad = d >= _ACC_WORST / 2
     d = jnp.where(bad, jnp.inf, d)
     i = jnp.where(bad, -1, i)
@@ -1767,9 +1764,9 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
             getattr(params, "merge_window", "auto"))
         G = grouped.GROUP
         on_tpu = _platform.on_tpu()
-        # the fused kernels' one-hot id contraction is f32 — require
-        # every actual candidate id (incl. user-supplied extend ids)
-        # to be f32-exact, not just the row count
+        # the kernels carry candidate ids in f32 lanes — require every
+        # actual candidate id (incl. user-supplied extend ids) to be
+        # f32-exact, not just the row count
         ids_ok = grouped.ids_f32_exact(index, index.list_indices)
 
         if mode == "codes" and not (

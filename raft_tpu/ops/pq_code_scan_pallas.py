@@ -52,21 +52,19 @@ from jax.experimental.pallas import tpu as pltpu
 from raft_tpu.neighbors.grouped import GROUP
 from raft_tpu.ops import vmem_budget as vb
 from raft_tpu.ops.pq_group_scan_pallas import (_KT_MAX, _KT_UNROLL,
+                                               _copy_slot_rows,
                                                _extract_topk,
-                                               _fused_step,
+                                               _fused_init,
+                                               _fused_merge,
                                                _gather_queries,
-                                               _gather_queries_masked,
                                                _scratch_shapes,
                                                _unpack_admission,
-                                               query_table)
+                                               fused_scan_call,
+                                               fused_stream_bytes,
+                                               query_table, row_table)
 from raft_tpu.ops.pq_group_scan_pallas import _ACC_WORST  # noqa: F401 (re-export)
 
 _VMEM_BUDGET = 10 << 20
-# merge-side budget of the fused codes kernel: accumulator + staging
-# ring + merge transients, charged NEXT TO the streaming budget above
-# (raised from the round-7 2 MiB accumulator cap — the windowed merge
-# spends staging VMEM to buy back per-step merge passes)
-_FUSED_MERGE_BUDGET = 4 << 20
 
 
 def _round_up(x: int, m: int) -> int:
@@ -234,47 +232,43 @@ def _kernel_recon8(gl_ref, slot_ref, qrot_ref, cf_ref, data_ref, scale_ref,
              packed, cap_bits, adm=adm)
 
 
-def _kernel_codes_fused(gl_ref, slot_ref, qrot_ref, cf_ref, codes_ref,
-                        cb_ref, rsq_ref, ids_ref, *rest, kt, k, n_probes,
-                        P, pq_dim, pq_bits, n_groups, merge_window,
-                        has_adm=False):
+def _kernel_codes_fused(gl_ref, qid_ref, q_hbm, cf_ref, codes_ref, cb_ref,
+                        rsq_ref, ids_ref, *rest, kt, k, pq_dim, pq_bits,
+                        n_groups, has_adm=False):
     """Fused compact-code scan: the ``_kernel_codes`` decode + distance
-    block feeding the in-kernel per-query accumulator
-    (pq_group_scan_pallas._fused_step — per-step merge at W=1, staged
-    ring + windowed merge at W>1) instead of per-pair output rows —
-    candidates never reach HBM; the final (k, nq_pad) answers flush
-    once on the last grid step."""
+    block feeding the row-addressed per-query merge of
+    ``pq_group_scan_pallas._fused_merge`` instead of per-pair output
+    rows — candidates never reach HBM; the final query-major answers
+    are copied out once, on the last grid step."""
     adm_ref, rest = (rest[0], rest[1:]) if has_adm else (None, rest)
-    vals_ref, ids_out_ref, acc_v, acc_i, *stg = rest
+    (vals_hbm, ids_hbm, qtab, acc_v, acc_i, qrows, rows_v, rows_i, mer_v,
+     mer_i) = rest
     g = pl.program_id(0)
 
     @pl.when(g == 0)
     def _init():
-        acc_v[:] = jnp.full(acc_v.shape, _ACC_WORST, jnp.float32)
-        acc_i[:] = jnp.full(acc_i.shape, -1.0, jnp.float32)
-        if merge_window > 1:
-            stg[0][:] = jnp.full(stg[0].shape, _ACC_WORST, jnp.float32)
-            stg[1][:] = jnp.full(stg[1].shape, -1.0, jnp.float32)
+        _fused_init(q_hbm, qtab, acc_v, acc_i, qrows, rows_v, rows_i,
+                    mer_v, mer_i)
 
-    qv, oh = _gather_queries_masked(slot_ref, qrot_ref, n_probes, P)
-    sub = qv - cf_ref[0, 0][None, :]                     # (G, rot_pad) f32
+    _copy_slot_rows(qid_ref, ((qtab, qrows),), gather=True)
+    sub = qrows[...] - cf_ref[0, 0][None, :]             # (G, rot_pad) f32
     sub_sq = jnp.sum(sub * sub, axis=1)                  # (G,)
     cap = codes_ref.shape[2]
     reconT = _decode_reconT(codes_ref, cb_ref, pq_dim, pq_bits,
-                            qrot_ref.shape[2], cap)      # (rot_pad, cap)
+                            qrows.shape[1], cap)         # (rot_pad, cap)
     ip = jax.lax.dot_general(sub.astype(jnp.bfloat16), reconT,
                              (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
     d = sub_sq[:, None] + rsq_ref[0, 0][None, :] - 2.0 * ip
     d = jnp.maximum(d, 0.0)
     adm = _unpack_admission(adm_ref, cap) if has_adm else None
-    _fused_step(g, oh, d, ids_ref[0, 0], acc_v, acc_i, stg, kt=kt,
-                merge_window=merge_window, n_groups=n_groups, adm=adm)
+    _fused_merge(qid_ref, d, ids_ref[0, 0], kt, k, acc_v, acc_i, rows_v,
+                 rows_i, mer_v, mer_i, adm=adm)
 
     @pl.when(g == n_groups - 1)
     def _flush():
-        vals_ref[:] = acc_v[:]
-        ids_out_ref[:] = acc_i[:].astype(jnp.int32)
+        pltpu.sync_copy(acc_v, vals_hbm)
+        pltpu.sync_copy(acc_i, ids_hbm)
 
 
 @functools.partial(jax.jit, static_argnames=("kt", "k", "n_probes",
@@ -288,66 +282,42 @@ def grouped_code_scan_fused(group_list, slot_pairs, qrot, centers_f32,
 
     Inputs as :func:`grouped_code_scan`; output contract as
     ``pq_group_scan_pallas.grouped_l2_scan_fused`` — the batch's final
-    ``(vals (k, nq_pad) f32, ids (k, nq_pad) int32)``, ascending per
-    column, exhausted ranks at the finite ``_ACC_WORST`` sentinel.
+    ``(vals (nq_pad, k) f32, ids (nq_pad, k) int32)``, ascending per
+    row, exhausted ranks at the finite ``_ACC_WORST`` sentinel;
+    ``merge_window`` is accepted and unused there too.
     ``adm_words`` (n_groups, GROUP, ceil(cap/32)) int32 streams packed
     per-(slot, candidate) admission bits (filtered search).
     """
-    n_groups = group_list.shape[0]
+    del merge_window
     nq, rot = qrot.shape
     _, _, cap = codes_lanes.shape
     pq_dim, book, pq_len = codebooks.shape
     Wi = codes_lanes.shape[1]
-    P = nq * n_probes
     rot_pad = _round_up(rot, 128)
-
-    nq_pad = _round_up(nq + 1, 128)
-    qrot_pad = query_table(qrot, nq_pad, rot_pad)
-    cf_pad = _pad_lanes(centers_f32, rot_pad)
-    cbT = jnp.swapaxes(codebooks.astype(jnp.float32), 1, 2)
-
-    has_adm = adm_words is not None
-    in_specs = [
-        pl.BlockSpec((1, 1, GROUP), lambda g, gl: (g, 0, 0)),
-        pl.BlockSpec((3, nq_pad, rot_pad), lambda g, gl: (0, 0, 0)),
+    stream_specs = [
         pl.BlockSpec((1, 1, rot_pad), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, Wi, cap), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((pq_dim, pq_len, book), lambda g, gl: (0, 0, 0)),
         pl.BlockSpec((1, 1, cap), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, 1, cap), lambda g, gl: (gl[g], 0, 0)),
     ]
-    inputs = [group_list, slot_pairs[:, None, :], qrot_pad,
-              cf_pad[:, None, :], codes_lanes, cbT, rsq[:, None, :],
-              list_indices[:, None, :]]
-    if has_adm:
-        wc = adm_words.shape[2]
-        in_specs.append(pl.BlockSpec((1, GROUP, wc),
-                                     lambda g, gl: (g, 0, 0)))
-        inputs.append(adm_words)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_groups,),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((k, nq_pad), lambda g, gl: (0, 0)),
-            pl.BlockSpec((k, nq_pad), lambda g, gl: (0, 0)),
-        ],
-        scratch_shapes=vb.fused_scan_scratch(k, kt, merge_window, nq_pad),
-    )
-    vals, gids = pl.pallas_call(
-        functools.partial(_kernel_codes_fused, kt=kt, k=k,
-                          n_probes=n_probes, P=P, pq_dim=pq_dim,
-                          pq_bits=pq_bits, n_groups=n_groups,
-                          merge_window=merge_window, has_adm=has_adm),
-        out_shape=[
-            jax.ShapeDtypeStruct((k, nq_pad), jnp.float32),
-            jax.ShapeDtypeStruct((k, nq_pad), jnp.int32),
-        ],
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(*inputs)
-    return vals, gids
+    stream_inputs = [_pad_lanes(centers_f32, rot_pad)[:, None, :],
+                     codes_lanes,
+                     jnp.swapaxes(codebooks.astype(jnp.float32), 1, 2),
+                     rsq[:, None, :], list_indices[:, None, :]]
+    kernel = functools.partial(_kernel_codes_fused, kt=kt, k=k,
+                               pq_dim=pq_dim, pq_bits=pq_bits,
+                               n_groups=group_list.shape[0],
+                               has_adm=adm_words is not None)
+    return fused_scan_call(
+        kernel, group_list=group_list, slot_pairs=slot_pairs,
+        n_probes=n_probes, P=nq * n_probes,
+        q_table=row_table(qrot, vb.nq_padded(nq), rot_pad),
+        stream_specs=stream_specs, stream_inputs=stream_inputs,
+        adm_words=adm_words, k=k,
+        total_bytes=_fused_codes_bytes(cap, rot, kt, k, nq, pq_dim,
+                                       pq_bits),
+        interpret=interpret)
 
 
 def _pad_lanes(x, width):
@@ -507,6 +477,20 @@ def _extract_ok(kt: int, packed: bool) -> bool:
     return 0 < kt <= (_KT_UNROLL if packed else _KT_MAX)
 
 
+def _decode_bytes(cap: int, rot: int, pq_dim: int, pq_bits: int) -> int:
+    """VMEM of the code scans' decode: the packed-code block, the
+    VMEM-resident codebook table, the decoded reconT block with its
+    concat temporary and one subspace's one-hot transient."""
+    book = 1 << pq_bits
+    pq_len = max(rot // pq_dim, 1) if pq_dim else 1
+    rot_pad = _round_up(rot, 128)
+    Wi = code_lane_words(pq_dim, pq_bits)
+    return (_round_up(Wi, 8) * cap * 4           # packed-code block
+            + pq_dim * _round_up(pq_len, 8) * _round_up(book, 128) * 4
+            + 2 * rot_pad * cap * 2              # reconT + concat temp
+            + _round_up(book, 8) * cap * 2)      # one-hot transient
+
+
 def supported_codes(metric_is_l2: bool, per_subspace: bool, cap: int,
                     rot: int, kt: int, nq: int, pq_dim: int, pq_bits: int,
                     packed: bool = False) -> bool:
@@ -521,36 +505,35 @@ def supported_codes(metric_is_l2: bool, per_subspace: bool, cap: int,
     caller (grouped.ids_f32_exact), as for the recon kernel."""
     if not (metric_is_l2 and per_subspace and pq_bits in (4, 8)):
         return False
-    book = 1 << pq_bits
-    pq_len = rot // pq_dim if pq_dim and rot % pq_dim == 0 else 0
-    if not pq_len:
+    if not (pq_dim and rot % pq_dim == 0):
         return False
     rot_pad = _round_up(rot, 128)
     nq_pad = _round_up(nq + 1, 128)
-    Wi = code_lane_words(pq_dim, pq_bits)
     vmem = (2 * nq_pad * rot_pad * 4            # query table + one-hot
-            + _round_up(Wi, 8) * cap * 4        # packed-code block
-            + pq_dim * _round_up(pq_len, 8) * _round_up(book, 128) * 4
-            + 2 * rot_pad * cap * 2             # reconT + concat temp
-            + _round_up(book, 8) * cap * 2      # one-hot transient
+            + _decode_bytes(cap, rot, pq_dim, pq_bits)
             + 2 * GROUP * cap * 4)              # distances + extraction
     return (cap % 16 == 0 and GROUP % 16 == 0 and _extract_ok(kt, packed)
             and nq <= 6144 and vmem <= _VMEM_BUDGET)
 
 
+def _fused_codes_bytes(cap: int, rot: int, kt: int, k: int, nq: int,
+                       pq_dim: int, pq_bits: int) -> int:
+    Wi = code_lane_words(pq_dim, pq_bits)
+    return vb.fused_scan_bytes(
+        k, kt, vb.nq_padded(nq), _round_up(rot, 128), GROUP,
+        fused_stream_bytes(cap, _round_up(Wi, 8) * cap * 4)
+        + _decode_bytes(cap, rot, pq_dim, pq_bits))
+
+
 def fused_codes_merge_window(cap: int, rot: int, kt: int, k: int, nq: int,
                              pq_dim: int, pq_bits: int,
                              requested: int = 0) -> int:
-    """Host-static merge window for the fused codes scan (0 = no window
-    fits).  The streaming side (codes + codebook + decode transients)
-    is budgeted by :func:`supported_codes`; the merge side —
-    accumulator + staging ring + merge transients — gets its own
-    ``_FUSED_MERGE_BUDGET`` next to it, so ``base_bytes`` is 0 here."""
-    del cap, rot, pq_dim, pq_bits    # streaming side budgeted separately
-    nq_pad = _round_up(nq + 1, 128)
-    return vb.select_merge_window(
-        requested, kt=kt, k=k, nq_pad=nq_pad, group=GROUP, base_bytes=0,
-        budget=_FUSED_MERGE_BUDGET, w_min=1 if k <= _KT_UNROLL else 2)
+    """Host-static merge window for the fused codes scan: 1 where its
+    VMEM model (resident query table and accumulator, row blocks,
+    streamed codes, decode) fits, 0 where it does not; the kernel
+    merges every step, as the recon scan does."""
+    return vb.fused_scan_window(
+        requested, _fused_codes_bytes(cap, rot, kt, k, nq, pq_dim, pq_bits))
 
 
 def supported_fused_codes(metric_is_l2: bool, per_subspace: bool, cap: int,
@@ -558,12 +541,10 @@ def supported_fused_codes(metric_is_l2: bool, per_subspace: bool, cap: int,
                           pq_bits: int, merge_window: int = 0) -> bool:
     """Shapes the FUSED code-scan kernel handles: the static
     :func:`supported_codes` preconditions (generic extraction — the
-    packed-key variant has no fused twin) plus the merge side —
-    (k, nq_pad) accumulator pair, staging ring, merge transients —
-    within ``_FUSED_MERGE_BUDGET`` for some window W
-    (:func:`fused_codes_merge_window`); kt stays unrolled while k
-    extends to ``vmem_budget.FUSED_K_MAX`` through the windowed
-    merge."""
+    packed-key variant has no fused twin) plus the fused kernel's own
+    VMEM model (:func:`fused_codes_merge_window`); kt stays unrolled
+    while k extends to ``vmem_budget.FUSED_K_MAX`` through the looped
+    per-step merge."""
     if not supported_codes(metric_is_l2, per_subspace, cap, rot, kt, nq,
                            pq_dim, pq_bits, packed=False):
         return False

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: fused grouped PQ-reconstruction scan + local top-k.
+"""Pallas TPU kernels: grouped PQ-reconstruction scans + top-k in VMEM.
 
 The ``compute_similarity_kernel`` analogue (reference:
 neighbors/detail/ivf_pq_search.cuh:611) for the grouped search layout
@@ -12,23 +12,34 @@ Structure per program ``g``:
   the list's bf16 reconstructions, squared norms, and candidate ids are
   DMA'd directly by list id (the TPU equivalent of the reference
   assigning one CTA per (list, query-group));
-- the group's rotated queries are gathered from the VMEM-resident
-  ``qrot`` table (it is only nq x rot ~ a few MB) by a **one-hot MXU
-  matmul** — Mosaic has no native row-gather, and the XLA-side gather
-  this replaces measured ~120 ms/batch at bench shapes versus a few ms
-  of MXU time for the one-hot contraction;
+- the group's rotated queries come from a query table resident in VMEM
+  for the whole grid.  The FUSED kernels (:func:`grouped_l2_scan_fused`
+  and the codes twin) copy each slot's row by index, the query ids
+  streamed per group through SMEM: O(GROUP) row copies per step,
+  whatever the batch.  The non-fused kernels gather by a one-hot MXU
+  contraction against the exact three-part bf16 split of the table
+  (:func:`query_table`), whose cost grows with the batch;
 - residuals against the list center, the distance GEMM
   ``d = ||sub||^2 + ||recon||^2 - 2 sub.recon``, and kt passes of
   max / where-iota argmin / mask extract the top-kt per row — all in
   VMEM;
-- selected positions map to **global candidate ids** by a second one-hot
-  contraction against the list's id row (ids < 2^24 are exact in f32),
-  so the XLA side needs no post-hoc id gather.
+- selected positions map to **global candidate ids** by a masked reduce
+  over the list's id row (ids < 2^24 are exact in f32), so the XLA side
+  needs no post-hoc id gather.
 
-Outputs are per-pair (values, global ids); callers scatter them into the
-(P, kt) buffers by pair slot.  Rows with fewer than kt finite candidates
-emit +inf values; callers map those to the -1 id sentinel (valid L2
-distances are finite).
+The non-fused kernels output per-pair (values, global ids); callers
+scatter them into the (P, kt) buffers by pair slot.  Rows with fewer
+than kt finite candidates emit +inf values; callers map those to the -1
+id sentinel (valid L2 distances are finite).  The fused kernels merge
+into a per-query accumulator instead and output each query's final
+top-k (see the section comment above :func:`slot_query_rows`).
+
+On one v5e at the SIFT-1M IVF-PQ cell's shape (4,096 lists of 416 rows,
+k = kt = 20, 6,909 pair groups) the fused recon kernel's one-hot
+addressing of a 5,000-query batch cost 11.1 us per grid step against
+7.5 us at 640 queries; row addressing reads 7.6 us at either width with
+every slot live, and 6.8 us for a 64-query batch, whose groups hold
+about one live slot each (profiles/fused_scan_addressing.py).
 """
 
 from __future__ import annotations
@@ -48,18 +59,15 @@ from raft_tpu.ops import vmem_budget as vb
 _KT_UNROLL = 64
 _KT_MAX = 128
 
-# Finite "worst distance" sentinel of the fused accumulator.  The
-# accumulator is read and written through one-hot f32 contractions, and
-# IEEE 0 * inf = nan would leak a +inf sentinel into every gathered row
-# — so the fused kernels keep exhausted slots at a large FINITE value
-# and the epilogue maps values past _ACC_WORST/2 to the public
-# +inf / id -1 contract.
+# Finite "worst distance" sentinel of the fused accumulator: exhausted
+# ranks hold a large FINITE value, so no inf ever meets a product or a
+# comparison chain in the merge, and the epilogue maps values past
+# _ACC_WORST/2 to the public +inf / id -1 contract.
 _ACC_WORST = 3.0e38
 
-# The one-hot contractions move f32 values and f32-encoded ids that must
-# arrive bit-exact, and Mosaic's default contraction precision rounds f32
-# operands to bf16 (ids past 256 come back as other ids).  So every f32
-# operand of a one-hot contraction is split into three bf16 parts that
+# The non-fused kernels' one-hot query gather must move f32 rows
+# bit-exact, and Mosaic's default contraction precision rounds f32
+# operands to bf16.  So the table is split into three bf16 parts that
 # together hold its 24-bit significand: a bf16 one-hot times a bf16 part
 # is exact and every output takes at most one non-zero product, so three
 # single bf16 passes sum back exactly — half the passes of a
@@ -83,41 +91,25 @@ def _split3(x):
     return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, lo))
 
 
-def _parts_dot(parts, oh, dims, onehot_lhs=False):
-    """Exact f32 ``dot_general(x, oh)`` (``dot_general(oh, x)`` with
-    ``onehot_lhs``) from the :func:`_split3` parts of ``x`` against a
-    0/1 one-hot ``oh``."""
-    ohb = oh.astype(jnp.bfloat16)
-    out = None
-    for part in parts:
-        lhs, rhs = (ohb, part) if onehot_lhs else (part, ohb)
-        p = jax.lax.dot_general(lhs, rhs, dims,
-                                preferred_element_type=jnp.float32)
-        out = p if out is None else out + p
-    return out
-
-
-def _onehot_dot(x, oh, dims):
-    """Exact f32 ``dot_general(x, oh)`` against a 0/1 one-hot ``oh``."""
-    return _parts_dot(_split3(x), oh, dims)
-
-
 def query_table(q, nq_pad: int, width: int):
-    """The scan kernels' VMEM-resident query table: ``q`` zero-padded to
-    (nq_pad, width) f32 and stored as its (3, nq_pad, width) bf16
-    :func:`_split3` parts, split once per search instead of once per
-    grid step.  Padded rows are the zero row empty slots gather."""
-    nq, d = q.shape
-    qp = jnp.zeros((nq_pad, width), jnp.float32)
-    qp = qp.at[:nq, :d].set(q.astype(jnp.float32))
-    return jnp.stack(_split3(qp))
+    """The non-fused scan kernels' VMEM-resident query table: ``q``
+    zero-padded to (nq_pad, width) f32 (:func:`row_table`) and stored as
+    its (3, nq_pad, width) bf16 :func:`_split3` parts, split once per
+    search instead of once per grid step.  Padded rows are the zero row
+    empty slots gather."""
+    return jnp.stack(_split3(row_table(q, nq_pad, width)))
 
 
 def _gather_rows(onehot, q_ref):
     """(G, nq_pad) one-hot x the (3, nq_pad, d) split table -> exact
-    (G, d) f32 rows."""
-    return _parts_dot([q_ref[i] for i in range(q_ref.shape[0])], onehot,
-                      (((1,), (0,)), ((), ())), onehot_lhs=True)
+    (G, d) f32 rows, one bf16 pass per part."""
+    ohb = onehot.astype(jnp.bfloat16)
+    out = None
+    for i in range(q_ref.shape[0]):
+        p = jax.lax.dot_general(ohb, q_ref[i], (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        out = p if out is None else out + p
+    return out
 
 
 def _scratch_shapes(kt):
@@ -235,39 +227,51 @@ def _extract_topk(d, ids_row, vals_ref, ids_out_ref, vscratch, pscratch,
 #
 # The non-fused kernels emit (n_groups, GROUP, kt) per-pair winners that
 # the XLA side scatters into (P, kt) buffers and reduces with a final
-# select — at bench shapes that round-trip plus the select is the
-# dominant remaining cost (PERFORMANCE.md round 6: ~3.3 us per kept
-# candidate).  The fused variants exploit the TPU grid's SEQUENTIAL
-# execution: a (k, nq_pad) per-query accumulator lives in VMEM scratch
-# across ALL grid steps, each group's local top-kt is merged into its
-# queries' rows in-kernel, and only the final (k, nq_pad) answer is
-# written to HBM on the last step.  No scatter, no final select — the
-# extraction stage disappears from the profile.
+# select.  The fused variants exploit the TPU grid's SEQUENTIAL
+# execution: a query-major (nq_pad, acc_lanes(k)) per-query accumulator
+# lives in VMEM scratch across ALL grid steps, each group's local top-kt
+# is merged into its queries' rows in-kernel, and only the final answers
+# are copied to HBM on the last step.  No scatter, no final select.
 #
-# The accumulator is addressed by query id through the SAME one-hot
-# matrix the query gather builds (rows are gathered by
-# ``onehot @ acc`` and written back as ``acc*(1-cover) + onehotT @
-# merged``).  Every slot of a group holds a DISTINCT query (a group is
+# Both the query table and the accumulator are addressed by ROW.  Each
+# slot's query row (``slot // n_probes``; the zero padding row
+# ``nq_pad - 1`` for an empty slot) and the group's live-slot count are
+# computed once in XLA (:func:`slot_query_rows`) and streamed one group
+# per step into SMEM; the step copies its live slots' query rows and
+# accumulator rows out by dynamic single-row reads, merges in the
+# sublane-stacked (rows, GROUP) layout, and writes the accumulator rows
+# back.  A group's real slots come first (``grouped.build_groups`` fills
+# slots in rank order), so the copies stop at the live count, rounded up
+# to the copy loop's unroll: an all-empty tail group copies nothing, and
+# the lanes past the count merge stale rows that are never written
+# back.  Every real slot of a group holds a DISTINCT query (a group is
 # one list; a query probes each list at most once), so the write-back
-# touches each row through exactly one one-hot lane — the update is
-# EXACT in f32, and candidate ids ride along as exact-below-2^24 f32
-# lanes just like the id mapping of the non-fused extraction.
+# touches each real row once; empty slots inside the live range read and
+# write the padding row, which is never returned.  Row copies are exact, so neither the query table nor the
+# accumulator needs the three-part split the one-hot contractions of the
+# non-fused query gather take, and no per-step array has an nq_pad
+# dimension.
 
 
-def _gather_queries_masked(slot_ref, q_ref, n_probes, P):
-    """Query gather that also returns the validity-masked one-hot used
-    to address the fused accumulator.  Sentinel slots have an all-zero
-    one-hot row: they gather the zero query row AND are excluded from
-    the accumulator write-back (their merged columns are discarded)."""
-    nq_pad = q_ref.shape[1]
-    slot = slot_ref[0, 0]                              # (G,) int32 pair ids
-    # sentinel slots map to column -1, which no iota column matches —
-    # validity rides the int32 id, because Mosaic cannot lay out the
-    # (G,) -> (G, 1) reshape of a bool vector
-    qid = jnp.where(slot < P, slot // n_probes, -1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (GROUP, nq_pad), 1)
-    oh = (cols == qid[:, None]).astype(jnp.float32)
-    return _gather_rows(oh, q_ref), oh
+def slot_query_rows(slot_pairs, n_probes: int, P: int, nq_pad: int):
+    """(n_groups, GROUP) pair slots -> (n_groups, 2, GROUP) int32: row 0
+    holds each slot's query-table row (``slot // n_probes`` for a real
+    slot, the padding row ``nq_pad - 1`` for the empty-slot sentinel
+    ``P``), row 1 the group's live-slot count (one past its last real
+    slot) in every lane."""
+    real = slot_pairs < P
+    rows = jnp.where(real, slot_pairs // n_probes, nq_pad - 1)
+    live = jnp.max(jnp.where(real, jnp.arange(1, GROUP + 1), 0), axis=1)
+    return jnp.stack([rows, jnp.broadcast_to(live[:, None], rows.shape)],
+                     axis=1).astype(jnp.int32)
+
+
+def row_table(q, nq_pad: int, width: int):
+    """The fused kernels' query table: ``q`` zero-padded to
+    (nq_pad, width) f32, one copy (rows are copied, never contracted)."""
+    nq, d = q.shape
+    return jnp.zeros((nq_pad, width), jnp.float32).at[:nq, :d].set(
+        q.astype(jnp.float32))
 
 
 def _topk_rows(d, ids_row, kt, adm=None):
@@ -277,8 +281,7 @@ def _topk_rows(d, ids_row, kt, adm=None):
     results stay in registers for the in-kernel merge and exhausted
     slots carry the finite ``_ACC_WORST`` instead of +inf.  ``adm``
     folds per-(slot, candidate) admission bits through the same seam
-    BEFORE any value reaches the staging ring or the accumulator's
-    one-hot products (only finite sentinels ever meet a product)."""
+    BEFORE any value reaches the accumulator."""
     invalid = (ids_row < 0)[None, :]
     if adm is not None:
         invalid = invalid | (adm == 0)
@@ -300,69 +303,23 @@ def _topk_rows(d, ids_row, kt, adm=None):
     return jnp.concatenate(vs, 0), jnp.concatenate(gs, 0)   # (kt, G)
 
 
-def _merge_topk(cat_v, cat_i, k):
-    """k selection passes over sublane-stacked (rows, G) candidates:
-    merge of the accumulator's sorted k rows with a group's local kt
-    rows.  Cross-SUBLANE reduces (rows <= k + kt, tiny) — the lane axis
-    stays the 128 pair slots."""
-    rows_n = cat_v.shape[0]
-    rows = jax.lax.broadcasted_iota(jnp.int32, cat_v.shape, 0)
-    out_v, out_i = [], []
-    for _ in range(k):
-        m = jnp.min(cat_v, axis=0)                     # (G,)
-        p = jnp.min(jnp.where(cat_v == m[None, :], rows, rows_n), axis=0)
-        p = jnp.minimum(p, rows_n - 1)
-        sel = rows == p[None, :]
-        gi = jnp.max(jnp.where(sel, cat_i, -jnp.inf), axis=0)
-        out_v.append(m[None, :])
-        out_i.append(gi[None, :])
-        cat_v = jnp.where(sel, _ACC_WORST, cat_v)
-    return jnp.concatenate(out_v, 0), jnp.concatenate(out_i, 0)  # (k, G)
-
-
-def _fused_accumulate(oh, d, ids_row, acc_v, acc_i, kt, adm=None):
-    """Merge one group's (G, cap) distances into the per-query
-    accumulator: local top-kt, gather the slots' accumulator rows via
-    the one-hot, merge sorted k+kt candidates per slot, write back.
-    The one-hot write-back is exact (each real row is covered by at
-    most one slot; sentinel slots have all-zero one-hot rows)."""
-    k = acc_v.shape[0]
-    new_v, new_i = _topk_rows(d, ids_row, kt, adm=adm)  # (kt, G)
-    old_v = _onehot_dot(acc_v[:], oh, (((1,), (1,)), ((), ())))
-    old_i = _onehot_dot(acc_i[:], oh, (((1,), (1,)), ((), ())))
-    mer_v, mer_i = _merge_topk(jnp.concatenate([old_v, new_v], 0),
-                               jnp.concatenate([old_i, new_i], 0), k)
-    cover = jnp.sum(oh, axis=0)                        # (nq_pad,) 0/1
-    keep = (1.0 - cover)[None, :]
-    acc_v[:] = acc_v[:] * keep + _onehot_dot(
-        mer_v, oh, (((1,), (0,)), ((), ())))
-    acc_i[:] = acc_i[:] * keep + _onehot_dot(
-        mer_i, oh, (((1,), (0,)), ((), ())))
-
-
-def _merge_cols(acc_v, acc_i, stg_v, stg_i, k):
-    """Windowed merge: fold the staged (kt*W, nq_pad) ring into the
-    sorted (k, nq_pad) accumulator at FULL column width — no one-hot
-    gather or write-back, every query column merges in place.  Same
-    selection rule as :func:`_merge_topk` (min, lowest-row tie-break,
-    masked-id reduce, winner re-masked to the finite sentinel), with
-    rows ordered [accumulator | ring in arrival order] so tie retention
-    matches the per-step merge bit-for-bit.  Columns whose staged rows
-    are all sentinels reproduce the accumulator exactly (it is sorted
-    and its rows precede the ring's), so partially-filled windows and
-    all-sentinel tails are free.
+def _merge_topk(cat_v, cat_i, k, mer_v, mer_i):
+    """k selection passes over sublane-stacked (rows, G) candidates —
+    the accumulator's sorted k rows, then a group's local kt rows —
+    into rows ``[:k]`` of the (acc_lanes(k), G) ``mer_v`` / ``mer_i``
+    scratch.  Cross-SUBLANE reduces; the lane axis stays the 128 pair
+    slots.  Each pass takes the minimum, breaks ties to the lowest row
+    (accumulator before new, stable like sort), reads the winner's id
+    by a masked reduce and re-masks the winner to ``_ACC_WORST``.
 
     k past the unrolled regime runs as a ``fori_loop`` with dynamic
-    SUBLANE stores into the accumulator — the concatenated working set
-    is materialized before the loop, so the in-place row writes never
-    feed back into the selection carry."""
-    cat_v = jnp.concatenate([acc_v[:], stg_v[:]], axis=0)
-    cat_i = jnp.concatenate([acc_i[:], stg_i[:]], axis=0)
+    sublane stores into the scratch (the candidates are materialized
+    before the loop, so the stores never feed back into the carry)."""
     rows_n = cat_v.shape[0]
     rows = jax.lax.broadcasted_iota(jnp.int32, cat_v.shape, 0)
 
     def step(cat_v):
-        m = jnp.min(cat_v, axis=0)                     # (nq_pad,)
+        m = jnp.min(cat_v, axis=0)                     # (G,)
         p = jnp.min(jnp.where(cat_v == m[None, :], rows, rows_n), axis=0)
         p = jnp.minimum(p, rows_n - 1)
         sel = rows == p[None, :]
@@ -376,73 +333,97 @@ def _merge_cols(acc_v, acc_i, stg_v, stg_i, k):
             out_v.append(m[None, :])
             out_i.append(gi[None, :])
             cat_v = jnp.where(sel, _ACC_WORST, cat_v)
-        acc_v[:] = jnp.concatenate(out_v, 0)
-        acc_i[:] = jnp.concatenate(out_i, 0)
+        mer_v[:k, :] = jnp.concatenate(out_v, 0)
+        mer_i[:k, :] = jnp.concatenate(out_i, 0)
     else:
         def body(j, cat_v):
             m, sel, gi = step(cat_v)
-            acc_v[pl.ds(j, 1), :] = m[None, :]
-            acc_i[pl.ds(j, 1), :] = gi[None, :]
+            mer_v[pl.ds(j, 1), :] = m[None, :]
+            mer_i[pl.ds(j, 1), :] = gi[None, :]
             return jnp.where(sel, _ACC_WORST, cat_v)
 
         jax.lax.fori_loop(0, k, body, cat_v, unroll=False)
 
 
-def _fused_step(g, oh, d, ids_row, acc_v, acc_i, stg, *, kt,
-                merge_window, n_groups, adm=None):
-    """One grid step of the fused accumulator, windowed.
-
-    W <= 1 is the original per-step path (:func:`_fused_accumulate` —
-    gather + merge + write-back every step).  W > 1 stages instead:
-    the step's local top-kt lands in the ring slot ``g % W`` by ONE
-    one-hot scatter per operand — uncovered columns take the
-    ``_ACC_WORST`` / id -1 sentinel fill (``dot + _ACC_WORST*(1-cover)``
-    is exact: covered columns add 0, uncovered columns add to 0) — and
-    only every W-th step (and the flush step) pays
-    :func:`_merge_cols`.  The ring resets to sentinels after each
-    merge so stale slots of a partial final window merge as no-ops.
-    """
-    if merge_window <= 1:
-        _fused_accumulate(oh, d, ids_row, acc_v, acc_i, kt, adm=adm)
-        return
-    stg_v, stg_i = stg
-    new_v, new_i = _topk_rows(d, ids_row, kt, adm=adm)  # (kt, G), finite
-    cover = jnp.sum(oh, axis=0)                        # (nq_pad,) 0/1
-    fill = (1.0 - cover)[None, :]
-    row0 = (g % merge_window) * vb.stage_stride(kt)
-    stg_v[pl.ds(row0, kt), :] = _onehot_dot(
-        new_v, oh, (((1,), (0,)), ((), ()))) + _ACC_WORST * fill
-    stg_i[pl.ds(row0, kt), :] = _onehot_dot(
-        new_i, oh, (((1,), (0,)), ((), ()))) - fill
-
-    @pl.when(((g + 1) % merge_window == 0) | (g == n_groups - 1))
-    def _merge():
-        _merge_cols(acc_v, acc_i, stg_v, stg_i, acc_v.shape[0])
-        stg_v[:] = jnp.full(stg_v.shape, _ACC_WORST, jnp.float32)
-        stg_i[:] = jnp.full(stg_i.shape, -1.0, jnp.float32)
+# rows copied per unrolled iteration of the per-slot row loops
+_ROW_UNROLL = 8
 
 
-def _kernel_fused(gl_ref, slot_ref, qrot_ref, cf_ref, data_ref, rsq_ref,
-                  ids_ref, *rest, kt, k, n_probes, P, n_groups,
-                  merge_window, has_adm=False):
+def _copy_slot_rows(qid_ref, pairs, *, gather):
+    """Per-slot row copies between the resident tables and the step's
+    (GROUP, width) row blocks, for every ``(table, block)`` in
+    ``pairs``: ``block[r] = table[qid[r]]`` (``gather``) or
+    ``table[qid[r]] = block[r]``, over the group's live slots (the count
+    :func:`slot_query_rows` streams) rounded up to ``_ROW_UNROLL``.
+    Slots run in order, so the empty slots' writes to the shared padding
+    row land last-wins."""
+    def body(i, carry):
+        # Mosaic lowers a fori_loop only fully unrolled or not at all:
+        # unroll by hand, _ROW_UNROLL slots per iteration
+        for j in range(_ROW_UNROLL):
+            r = i * _ROW_UNROLL + j
+            q = qid_ref[0, 0, r]
+            for table, block in pairs:
+                if gather:
+                    block[pl.ds(r, 1), :] = table[pl.ds(q, 1), :]
+                else:
+                    table[pl.ds(q, 1), :] = block[pl.ds(r, 1), :]
+        return carry
+
+    n_iter = (qid_ref[0, 1, 0] + _ROW_UNROLL - 1) // _ROW_UNROLL
+    jax.lax.fori_loop(0, n_iter, body, 0)
+
+
+def _fused_init(q_hbm, qtab, acc_v, acc_i, qrows, rows_v, rows_i, mer_v,
+                mer_i):
+    """Step 0: copy the query table into VMEM once and fill the
+    accumulator, the row blocks (whose lanes past a group's live slots
+    are never copied into) and the merged rows' pad lanes with zeros or
+    the finite sentinel pair."""
+    pltpu.sync_copy(q_hbm, qtab)
+    qrows[...] = jnp.zeros(qrows.shape, jnp.float32)
+    for ref, fill in ((acc_v, _ACC_WORST), (acc_i, -1.0),
+                      (rows_v, _ACC_WORST), (rows_i, -1.0),
+                      (mer_v, _ACC_WORST), (mer_i, -1.0)):
+        ref[...] = jnp.full(ref.shape, fill, jnp.float32)
+
+
+def _fused_merge(qid_ref, d, ids_row, kt, k, acc_v, acc_i, rows_v, rows_i,
+                 mer_v, mer_i, adm=None):
+    """Merge one group's (G, cap) distances into its queries'
+    accumulator rows: local top-kt, read the slots' rows, transpose to
+    the sublane-stacked layout, merge sorted k + kt candidates per slot,
+    transpose back, write the rows."""
+    new_v, new_i = _topk_rows(d, ids_row, kt, adm=adm)  # (kt, G)
+    _copy_slot_rows(qid_ref, ((acc_v, rows_v), (acc_i, rows_i)),
+                    gather=True)
+    old_v = rows_v[...].T[:k]                          # (k, G)
+    old_i = rows_i[...].T[:k]
+    _merge_topk(jnp.concatenate([old_v, new_v], 0),
+                jnp.concatenate([old_i, new_i], 0), k, mer_v, mer_i)
+    rows_v[...] = mer_v[...].T
+    rows_i[...] = mer_i[...].T
+    _copy_slot_rows(qid_ref, ((acc_v, rows_v), (acc_i, rows_i)),
+                    gather=False)
+
+
+def _kernel_fused(gl_ref, qid_ref, q_hbm, cf_ref, data_ref, rsq_ref,
+                  ids_ref, *rest, kt, k, n_groups, has_adm=False):
     """Fused recon scan: the non-fused ``_kernel`` distance block plus
-    the in-kernel accumulator merge (windowed through the staging ring
-    when merge_window > 1); outputs are the FINAL per-query (k, nq_pad)
-    answers, flushed once on the last grid step."""
+    the in-kernel per-query merge; the final query-major answers are
+    copied to HBM once, on the last grid step."""
     adm_ref, rest = (rest[0], rest[1:]) if has_adm else (None, rest)
-    vals_ref, ids_out_ref, acc_v, acc_i, *stg = rest
+    (vals_hbm, ids_hbm, qtab, acc_v, acc_i, qrows, rows_v, rows_i, mer_v,
+     mer_i) = rest
     g = pl.program_id(0)
 
     @pl.when(g == 0)
     def _init():
-        acc_v[:] = jnp.full(acc_v.shape, _ACC_WORST, jnp.float32)
-        acc_i[:] = jnp.full(acc_i.shape, -1.0, jnp.float32)
-        if merge_window > 1:
-            stg[0][:] = jnp.full(stg[0].shape, _ACC_WORST, jnp.float32)
-            stg[1][:] = jnp.full(stg[1].shape, -1.0, jnp.float32)
+        _fused_init(q_hbm, qtab, acc_v, acc_i, qrows, rows_v, rows_i,
+                    mer_v, mer_i)
 
-    qv, oh = _gather_queries_masked(slot_ref, qrot_ref, n_probes, P)
-    sub = qv - cf_ref[0, 0][None, :]                   # (G, rot) f32
+    _copy_slot_rows(qid_ref, ((qtab, qrows),), gather=True)
+    sub = qrows[...] - cf_ref[0, 0][None, :]           # (G, rot) f32
     sub_sq = jnp.sum(sub * sub, axis=1)                # (G,)
     data = data_ref[0]                                 # (cap, rot) bf16
     ip = jax.lax.dot_general(sub.astype(jnp.bfloat16), data,
@@ -451,13 +432,56 @@ def _kernel_fused(gl_ref, slot_ref, qrot_ref, cf_ref, data_ref, rsq_ref,
     d = sub_sq[:, None] + rsq_ref[0, 0][None, :] - 2.0 * ip
     d = jnp.maximum(d, 0.0)
     adm = _unpack_admission(adm_ref, d.shape[1]) if has_adm else None
-    _fused_step(g, oh, d, ids_ref[0, 0], acc_v, acc_i, stg, kt=kt,
-                merge_window=merge_window, n_groups=n_groups, adm=adm)
+    _fused_merge(qid_ref, d, ids_ref[0, 0], kt, k, acc_v, acc_i, rows_v,
+                 rows_i, mer_v, mer_i, adm=adm)
 
     @pl.when(g == n_groups - 1)
     def _flush():
-        vals_ref[:] = acc_v[:]
-        ids_out_ref[:] = acc_i[:].astype(jnp.int32)
+        pltpu.sync_copy(acc_v, vals_hbm)
+        pltpu.sync_copy(acc_i, ids_hbm)
+
+
+def fused_scan_call(kernel, *, group_list, slot_pairs, n_probes, P,
+                    q_table, stream_specs, stream_inputs, adm_words, k,
+                    total_bytes, interpret):
+    """One ``pallas_call`` of a fused scan kernel over the pair groups:
+    the per-slot query rows and live-slot counts
+    (:func:`slot_query_rows`) stream into SMEM, the query table and both
+    outputs stay in HBM (the kernel copies them itself), and
+    ``stream_specs`` / ``stream_inputs`` are the kernel's per-list
+    blocks.  Returns the final ``(vals (nq_pad, k) f32, ids (nq_pad, k)
+    int32)``.  Call from the jitted scan function: the Pallas call's
+    instruction is named after it."""
+    n_groups = group_list.shape[0]
+    nq_pad, width = q_table.shape
+    kl = vb.acc_lanes(k)
+    in_specs = [pl.BlockSpec((1, 2, GROUP), lambda g, gl: (g, 0, 0),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pl.ANY), *stream_specs]
+    inputs = [group_list, slot_query_rows(slot_pairs, n_probes, P, nq_pad),
+              q_table, *stream_inputs]
+    if adm_words is not None:
+        in_specs.append(pl.BlockSpec((1, GROUP, adm_words.shape[2]),
+                                     lambda g, gl: (g, 0, 0)))
+        inputs.append(adm_words)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_groups,),
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)],
+        scratch_shapes=vb.fused_scan_scratch(k, nq_pad, width, GROUP),
+    )
+    vals, ids = pl.pallas_call(
+        kernel,
+        out_shape=[jax.ShapeDtypeStruct((nq_pad, kl), jnp.float32),
+                   jax.ShapeDtypeStruct((nq_pad, kl), jnp.float32)],
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vb.fused_scan_vmem_limit(total_bytes)),
+        interpret=interpret,
+    )(*inputs)
+    return vals[:, :k], ids[:, :k].astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("kt", "k", "n_probes",
@@ -469,79 +493,63 @@ def grouped_l2_scan_fused(group_list, slot_pairs, qrot, centers_f32,
 
     Inputs as :func:`grouped_l2_scan`; instead of per-pair winners the
     kernel returns the batch's FINAL per-query answers —
-    ``(vals (k, nq_pad) f32, ids (k, nq_pad) int32)`` sorted ascending
-    per column, query q in column q.  Exhausted ranks carry values at
-    the finite ``_ACC_WORST`` sentinel (callers map values past
+    ``(vals (nq_pad, k) f32, ids (nq_pad, k) int32)`` sorted ascending
+    per row, query q in row q.  Exhausted ranks carry values at the
+    finite ``_ACC_WORST`` sentinel (callers map values past
     ``_ACC_WORST/2`` to +inf / id -1).  ``kt`` bounds the per-(query,
     probe) keep-set exactly like the non-fused path: each group
     contributes at most its local top-kt per pair before the merge, so
     results match the scatter+select reference at matched kt.
 
-    ``merge_window`` W amortizes the accumulator merge: steps stage
-    their top-kt in a (kt*W, nq_pad) VMEM ring and the merge runs every
-    W-th step — bit-identical to W=1 (the merge is order-insensitive
-    under the finite sentinel; ring order preserves tie retention).
-    Pick W with :func:`fused_merge_window`; k > 64 requires W >= 2.
+    ``merge_window`` is accepted for the callers' plans and cache keys;
+    the kernel merges every grid step whatever its value (see
+    :func:`fused_merge_window`).
 
     ``adm_words`` (n_groups, GROUP, ceil(cap/32)) int32 streams packed
     per-(slot, candidate) admission bits (filtered search): rejected
-    candidates fold to the finite sentinel before the windowed merge.
+    candidates fold to the finite sentinel before the merge.
     """
-    n_groups = group_list.shape[0]
+    del merge_window
     nq, rot = qrot.shape
     _, cap, _ = list_recon.shape
-    P = nq * n_probes
-
-    nq_pad = vb.nq_padded(nq)
-    qrot_pad = query_table(qrot, nq_pad, rot)
-
-    has_adm = adm_words is not None
-    in_specs = [
-        pl.BlockSpec((1, 1, GROUP), lambda g, gl: (g, 0, 0)),
-        pl.BlockSpec((3, nq_pad, rot), lambda g, gl: (0, 0, 0)),
+    stream_specs = [
         pl.BlockSpec((1, 1, rot), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, cap, rot), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, 1, cap), lambda g, gl: (gl[g], 0, 0)),
         pl.BlockSpec((1, 1, cap), lambda g, gl: (gl[g], 0, 0)),
     ]
-    inputs = [group_list, slot_pairs[:, None, :], qrot_pad,
-              centers_f32[:, None, :], list_recon, rec_sq[:, None, :],
-              list_indices[:, None, :]]
-    if has_adm:
-        wc = adm_words.shape[2]
-        in_specs.append(pl.BlockSpec((1, GROUP, wc),
-                                     lambda g, gl: (g, 0, 0)))
-        inputs.append(adm_words)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_groups,),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((k, nq_pad), lambda g, gl: (0, 0)),
-            pl.BlockSpec((k, nq_pad), lambda g, gl: (0, 0)),
-        ],
-        scratch_shapes=vb.fused_scan_scratch(k, kt, merge_window, nq_pad),
-    )
-    vals, gids = pl.pallas_call(
-        functools.partial(_kernel_fused, kt=kt, k=k, n_probes=n_probes,
-                          P=P, n_groups=n_groups,
-                          merge_window=merge_window, has_adm=has_adm),
-        out_shape=[
-            jax.ShapeDtypeStruct((k, nq_pad), jnp.float32),
-            jax.ShapeDtypeStruct((k, nq_pad), jnp.int32),
-        ],
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(*inputs)
-    return vals, gids
+    stream_inputs = [centers_f32[:, None, :], list_recon,
+                     rec_sq[:, None, :], list_indices[:, None, :]]
+    kernel = functools.partial(_kernel_fused, kt=kt, k=k,
+                               n_groups=group_list.shape[0],
+                               has_adm=adm_words is not None)
+    return fused_scan_call(
+        kernel, group_list=group_list, slot_pairs=slot_pairs,
+        n_probes=n_probes, P=nq * n_probes,
+        q_table=row_table(qrot, vb.nq_padded(nq), rot),
+        stream_specs=stream_specs, stream_inputs=stream_inputs,
+        adm_words=adm_words, k=k,
+        total_bytes=_fused_bytes(cap, rot, kt, k, nq,
+                                 list_recon.dtype.itemsize),
+        interpret=interpret)
 
 
-def _fused_base_bytes(cap: int, rot: int, nq_pad: int,
-                      data_elem_bytes: int) -> int:
-    return (2 * nq_pad * rot * 4              # query table + one-hot
-            + cap * rot * data_elem_bytes     # per-list data block
-            + 2 * GROUP * cap * 4)            # distances + local passes
+def fused_stream_bytes(cap: int, data_bytes: int) -> int:
+    """A fused scan's streamed VMEM besides the query table and the
+    accumulator: the double-buffered per-list ``data_bytes`` block, its
+    norm / id rows and admission words (sublane- and lane-padded), and
+    the (GROUP, cap) distance block with its extraction temporaries."""
+    return (2 * data_bytes
+            + 2 * 2 * 8 * cap * 4
+            + 2 * GROUP * vb.round_up(-(-cap // 32), 128) * 4
+            + 2 * GROUP * cap * 4)
+
+
+def _fused_bytes(cap: int, rot: int, kt: int, k: int, nq: int,
+                 data_elem_bytes: int) -> int:
+    return vb.fused_scan_bytes(
+        k, kt, vb.nq_padded(nq), rot, GROUP,
+        fused_stream_bytes(cap, cap * rot * data_elem_bytes))
 
 
 def _fused_static_ok(metric_is_l2: bool, cap: int, rot: int, kt: int,
@@ -553,25 +561,22 @@ def _fused_static_ok(metric_is_l2: bool, cap: int, rot: int, kt: int,
 
 def fused_merge_window(cap: int, rot: int, kt: int, k: int, nq: int,
                        data_elem_bytes: int = 2, requested: int = 0) -> int:
-    """Host-static merge window for the fused recon scan at this shape
-    (0 = no window fits -> fused unsupported).  ``requested`` 0 is auto
-    (largest fitting W); k past the unrolled per-step merge needs the
-    windowed path, so W >= 2 is forced there."""
-    nq_pad = vb.nq_padded(nq)
-    return vb.select_merge_window(
-        requested, kt=kt, k=k, nq_pad=nq_pad, group=GROUP,
-        base_bytes=_fused_base_bytes(cap, rot, nq_pad, data_elem_bytes),
-        budget=10 << 20, w_min=1 if k <= _KT_UNROLL else 2)
+    """Host-static merge window for the fused recon scan at this shape:
+    1 where its VMEM model fits, 0 where none does (fused unsupported).
+    ``requested`` is the public knob (0 auto, n >= 1 an upper bound);
+    the kernel merges every step, so any accepted request gives 1."""
+    return vb.fused_scan_window(
+        requested, _fused_bytes(cap, rot, kt, k, nq, data_elem_bytes))
 
 
 def supported_fused(metric_is_l2: bool, cap: int, rot: int, kt: int,
                     k: int, nq: int, data_elem_bytes: int = 2,
                     merge_window: int = 0) -> bool:
     """Shapes the fused recon kernel handles.  Beyond :func:`supported`:
-    the (k, nq_pad) accumulator pair and the staging ring join the VMEM
-    budget (:mod:`raft_tpu.ops.vmem_budget`); kt stays in the unrolled
-    regime while k extends to ``FUSED_K_MAX`` through the windowed
-    merge (some W must fit — check :func:`fused_merge_window`)."""
+    the resident query table and (nq_pad, acc_lanes(k)) accumulator pair
+    join the VMEM model (:mod:`raft_tpu.ops.vmem_budget`); kt stays in
+    the unrolled regime while k extends to ``FUSED_K_MAX`` through the
+    looped per-step merge."""
     return (_fused_static_ok(metric_is_l2, cap, rot, kt, k, nq)
             and fused_merge_window(cap, rot, kt, k, nq, data_elem_bytes,
                                    merge_window) > 0)
@@ -582,8 +587,8 @@ def fused_reject_reason(metric_is_l2: bool, cap: int, rot: int, kt: int,
                         merge_window: int = 0) -> str:
     """Reason code for a fused-recon gate miss ('' when supported):
     'dtype' (metric), 'k-too-large' (k/kt bounds), 'bucket-too-wide'
-    (batch, layout, or VMEM — no merge window fits).  Drives the
-    ``fused_fallback`` counter attrs and flight events."""
+    (batch, layout, or VMEM).  Drives the ``fused_fallback`` counter
+    attrs and flight events."""
     if not metric_is_l2:
         return "dtype"
     if not (0 < kt <= _KT_UNROLL and 0 < k <= vb.FUSED_K_MAX):
@@ -752,8 +757,8 @@ def supported(metric_is_l2: bool, cap: int, rot: int, kt: int,
     footprint is bounded (the one-hot gather cost also grows with nq —
     larger batches should be split by the caller anyway).
 
-    Candidate-id f32-exactness (|id| < 2^24, required by the one-hot id
-    contraction) is data-dependent and checked by the caller on the
+    Candidate-id f32-exactness (|id| < 2^24, required by the f32 id
+    lanes of the extraction and the fused merge) is data-dependent and checked by the caller on the
     index's actual ids (:func:`raft_tpu.neighbors.grouped.ids_f32_exact`)
     — user-supplied ids from ``extend(new_indices=...)`` can exceed any
     row-count proxy."""

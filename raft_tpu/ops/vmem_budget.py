@@ -1,25 +1,25 @@
-"""Shared VMEM-budget model for the fused Pallas kernels' windowed merge.
+"""Shared VMEM-budget model for the fused Pallas kernels.
 
-The three fused kernels (:mod:`raft_tpu.ops.pq_group_scan_pallas`,
-:mod:`raft_tpu.ops.pq_code_scan_pallas`,
-:mod:`raft_tpu.ops.cagra_hop_pallas`) amortize their per-step top-k merge
-through a VMEM **staging ring**: each grid step appends its kt candidates
-(in a slot of :func:`stage_stride` rows) into a (stride*W, nq_pad)
-scratch pair with a cheap one-hot scatter + sentinel fill, and only
-every W-th step (and at flush) pays the full
-merge into the (k, nq_pad) accumulator.  ``W`` is host-static: it is
-chosen here, from shapes only, by one budget model all three kernels
-share — staging + accumulator + merge working set must fit the kernel's
-existing VMEM budget next to its streaming blocks.  graftlint's
-mask-seam pass requires the fused kernels to size their scratch through
-:func:`fused_scan_scratch` / :func:`hop_scratch` so the scratch a kernel
-allocates and the bytes this model charges cannot drift apart.
+The two fused IVF-PQ list scans (:mod:`raft_tpu.ops.pq_group_scan_pallas`,
+:mod:`raft_tpu.ops.pq_code_scan_pallas`) keep three arrays resident in
+VMEM for the whole grid: the (nq_pad, width) f32 query table and the
+query-major (nq_pad, round_up(k, 128)) f32 value/id accumulator pair.
+Every grid step addresses them by ROW: it copies its GROUP slots' query
+rows and accumulator rows out by the per-slot query ids streamed through
+SMEM, merges, and writes the accumulator rows back — per-step work that
+does not grow with the batch.  :func:`fused_scan_bytes` charges the
+resident arrays, the per-step row blocks and the kernel's streaming
+blocks; a shape is admitted when the total fits
+:data:`FUSED_SCAN_BUDGET`, and the kernel raises its scoped VMEM limit to
+:func:`fused_scan_vmem_limit` of the same total.
 
-Selection is monotone: the amortized per-step merge cost
-``k * (k + kt*W) / W`` column passes strictly decreases in W while the
-staging write stays O(kt), so ``auto`` picks the LARGEST W that fits,
-capped at :data:`MERGE_WINDOW_MAX` (past which the staged rows' own
-merge passes dominate and the VMEM spent stops buying wall-clock).
+The fused CAGRA hop (:mod:`raft_tpu.ops.cagra_hop_pallas`) keeps its
+within-hop staged merge and its own model (:func:`hop_bytes`).
+
+graftlint's mask-seam pass requires the fused kernels to size their
+scratch through :func:`fused_scan_scratch` / :func:`hop_scratch` so the
+scratch a kernel allocates and the bytes this model charges cannot drift
+apart.
 """
 
 from __future__ import annotations
@@ -27,15 +27,16 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-# requested merge_window sentinel: pick the largest window that fits
+# requested merge_window sentinel: the selectors pick the window
 MERGE_WINDOW_AUTO = 0
-# staging rings larger than this stop paying: the merge over k + kt*W
-# staged rows grows linearly in W while the amortization factor 1/W
-# saturates
-MERGE_WINDOW_MAX = 8
-# the windowed merge's fori_loop accumulator store lifts the unrolled
-# k <= 64 merge bound up to the radix-select regime
+# k ceiling of the fused scans: past the unrolled merge the per-step
+# merge runs as a fori_loop over sublane-stacked rows
 FUSED_K_MAX = 256
+# admission budget of the fused scans' VMEM model; the scoped limit the
+# kernel asks for adds FUSED_SCAN_HEADROOM for compiler temporaries
+# (a v5e core has 128 MiB of VMEM)
+FUSED_SCAN_BUDGET = 32 << 20
+FUSED_SCAN_HEADROOM = 16 << 20
 
 
 def round_up(x: int, m: int) -> int:
@@ -58,92 +59,64 @@ def merge_window_request(value) -> int:
 
 
 def nq_padded(nq: int) -> int:
-    """Lane-padded query-table height shared by the fused scan kernels
-    (one sentinel row for empty slots, then 128-lane alignment)."""
+    """Query-table height shared by the fused scan kernels: one padding
+    row (``nq_pad - 1``, zero, never returned) that empty slots address,
+    then 128-row alignment."""
     return round_up(nq + 1, 128)
 
 
-def accumulator_bytes(k: int, nq_pad: int) -> int:
-    """The (k, nq_pad) f32 value/id accumulator pair."""
-    return 2 * k * nq_pad * 4
+def acc_lanes(k: int) -> int:
+    """Lane width of one query-major accumulator row: k rounded up to
+    whole 128-lane vregs, so a row block transposes tile by tile."""
+    return round_up(k, 128)
 
 
-def stage_stride(kt: int) -> int:
-    """Rows one grid step owns in the staging ring: kt rounded up to
-    the 8-row sublane tile, because Mosaic only stores a multi-row block
-    at a dynamic row offset it can prove is a multiple of 8.  The pad
-    rows keep the sentinel fill and merge as no-ops."""
-    return round_up(kt, 8)
+def fused_scan_bytes(k: int, kt: int, nq_pad: int, width: int, group: int,
+                     stream_bytes: int) -> int:
+    """VMEM footprint of a fused scan: resident query table and
+    accumulator pair, the per-step row blocks (gathered query rows,
+    accumulator rows in and out, the merge's sublane-stacked rows and
+    its (k + kt, group) working set), plus ``stream_bytes`` — the
+    kernel's double-buffered per-list blocks and distance temporaries."""
+    kl = acc_lanes(k)
+    return (nq_pad * width * 4                 # query table
+            + 2 * nq_pad * kl * 4              # accumulator pair
+            + group * width * 4                # gathered query rows
+            + 4 * group * kl * 4               # slot rows + merged rows
+            + 4 * (k + kt) * group * 4         # merge working set
+            + stream_bytes)
 
 
-def staging_bytes(kt: int, merge_window: int, nq_pad: int) -> int:
-    """The (stage_stride(kt)*W, nq_pad) f32 staging-ring pair; W <= 1
-    stages nothing (the per-step merge never materializes a window)."""
-    if merge_window <= 1:
+def fused_scan_window(requested: int, total_bytes: int) -> int:
+    """Host-static merge window of a fused scan: 1 where the shape's
+    VMEM model fits :data:`FUSED_SCAN_BUDGET`, 0 where it does not
+    (callers treat 0 as "fused unsupported at this shape").  The scans
+    merge every grid step, so any accepted request resolves to 1."""
+    if requested < 0 or total_bytes > FUSED_SCAN_BUDGET:
         return 0
-    return 2 * stage_stride(kt) * merge_window * nq_pad * 4
+    return 1
 
 
-def merge_temps_bytes(k: int, kt: int, merge_window: int, nq_pad: int,
-                      group: int) -> int:
-    """Transient working set of one merge.
-
-    W <= 1 is the per-step merge: one-hot gather/write-back temps at
-    GROUP width, 4 (k+kt, GROUP) f32 arrays (values + ids, in + out).
-    W > 1 merges at FULL column width: the concatenated
-    (k + kt*W, nq_pad) value/id pair the selection passes sweep.
-    """
-    if merge_window <= 1:
-        return 4 * (k + kt) * group * 4
-    return 2 * (k + stage_stride(kt) * merge_window) * nq_pad * 4
+def fused_scan_vmem_limit(total_bytes: int) -> int:
+    """Scoped VMEM limit a fused scan kernel asks the compiler for."""
+    return round_up(total_bytes + FUSED_SCAN_HEADROOM, 1 << 20)
 
 
-def select_merge_window(requested: int, *, kt: int, k: int, nq_pad: int,
-                        group: int, base_bytes: int, budget: int,
-                        w_min: int = 1,
-                        w_max: int = MERGE_WINDOW_MAX) -> int:
-    """Host-static merge-window choice for a fused scan shape.
-
-    ``base_bytes`` is the kernel's non-merge VMEM floor (query table,
-    streamed data block, distance block, ...); the merge side —
-    accumulator + staging ring + merge transients — must fit in
-    ``budget - base_bytes``.  ``requested`` is the user knob:
-    :data:`MERGE_WINDOW_AUTO` (0) picks the largest fitting W; a
-    positive W is honored as an upper bound (clamped down to what
-    fits).  ``w_min`` > 1 expresses shapes the per-step merge cannot
-    serve (k past the unrolled regime needs the windowed fori_loop
-    merge).  Returns the chosen W, or 0 when NO window fits — callers
-    treat 0 as "fused unsupported at this shape".
-    """
-    if requested < 0 or kt <= 0 or k <= 0:
-        return 0
-
-    def fits(w: int) -> bool:
-        total = (base_bytes + accumulator_bytes(k, nq_pad)
-                 + staging_bytes(kt, w, nq_pad)
-                 + merge_temps_bytes(k, kt, w, nq_pad, group))
-        return total <= budget
-
-    hi = w_max if requested == MERGE_WINDOW_AUTO else min(requested, w_max)
-    for w in range(hi, w_min - 1, -1):
-        if fits(w):
-            return w
-    return 0
-
-
-def fused_scan_scratch(k: int, kt: int, merge_window: int, nq_pad: int):
-    """Scratch list for the fused scan kernels: the (k, nq_pad)
-    accumulator pair, plus the (stage_stride(kt)*W, nq_pad) staging-ring
-    pair when a window is in play.  The fused kernels MUST allocate
-    through this helper (graftlint-enforced) so scratch and the budget
-    model agree."""
-    scratch = [pltpu.VMEM((k, nq_pad), jnp.float32),
-               pltpu.VMEM((k, nq_pad), jnp.float32)]
-    if merge_window > 1:
-        rows = stage_stride(kt) * merge_window
-        scratch += [pltpu.VMEM((rows, nq_pad), jnp.float32),
-                    pltpu.VMEM((rows, nq_pad), jnp.float32)]
-    return scratch
+def fused_scan_scratch(k: int, nq_pad: int, width: int, group: int):
+    """Scratch list for the fused scan kernels, in kernel-argument
+    order: the resident (nq_pad, width) query table, the (nq_pad,
+    acc_lanes(k)) value/id accumulator pair, the (group, width) gathered
+    query rows, the (group, acc_lanes(k)) value/id slot-row pair and the
+    (acc_lanes(k), group) value/id merged-row pair.  The fused kernels
+    MUST allocate through this helper (graftlint-enforced) so scratch
+    and the budget model agree."""
+    kl = acc_lanes(k)
+    f32 = jnp.float32
+    return [pltpu.VMEM((nq_pad, width), f32),
+            pltpu.VMEM((nq_pad, kl), f32), pltpu.VMEM((nq_pad, kl), f32),
+            pltpu.VMEM((group, width), f32),
+            pltpu.VMEM((group, kl), f32), pltpu.VMEM((group, kl), f32),
+            pltpu.VMEM((kl, group), f32), pltpu.VMEM((kl, group), f32)]
 
 
 # ---------------------------------------------------------------------------

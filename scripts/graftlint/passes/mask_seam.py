@@ -9,20 +9,20 @@ respect the seam are sign tests (``< 0`` / ``>= 0``); the only place an
 exact ``-1`` is legitimate is AFTER ``grouped.finalize_topk`` clamps
 encoded ids to the public sentinel (suppress with a reason there).
 
-The second seam is numeric: the fused kernels' one-hot accumulator
-merges (PR 6) multiply masks into distance values — IEEE says
+The second seam is numeric: Pallas kernels that multiply masks or
+one-hots into distance values must never see an inf — IEEE says
 ``0 * inf = NaN``, so sentinel distances inside ``ops/*_pallas.py``
 must be the finite ``3.0e38`` (``_ACC_WORST``) wherever they can meet
 a product.  An ``inf`` flowing into ``*`` / ``@`` / ``dot`` poisons
-whole accumulator rows.
+whole rows.
 
-The third seam is the round-14 staging ring: the windowed fused merge
-parks per-step candidates in VMEM scratch (``stg_*`` / ``acc_*`` /
-``*ring*`` refs) whose uncovered slots MUST hold the finite sentinel —
-an ``inf`` (or any huge float that is not ``_ACC_WORST``) written into
-the ring re-enters the one-hot merge as a product operand on the next
-flush.  And because the merge-window selector (``ops/vmem_budget``)
-and the kernel must agree on the VMEM footprint, the fused kernels'
+The third seam is the fused kernels' VMEM scratch: the per-query
+accumulator (``acc_*`` refs) and any staging block (``stg_*`` /
+``*ring*``) hold exhausted ranks at exactly the finite sentinel — an
+``inf`` (or any huge float that is not ``_ACC_WORST``) written there
+breaks the merge's liveness test (``< _ACC_WORST/2``) that the
+epilogue shares.  And because the VMEM model (``ops/vmem_budget``) and
+the kernel must agree on the footprint, the fused kernels'
 ``scratch_shapes`` must be sized by the shared budget helpers, never
 by inline shape lists.
 

@@ -601,9 +601,10 @@ def ring_case():
 
 
 class TestWindowedMerge:
-    """Round-14 windowed fused-scan merge: a VMEM staging ring defers
-    the (k x k+kt) merge to every W-th grid step.  The contract is
-    bit-identity with the round-7 per-step merge (W=1): VALUES bit-equal
+    """``merge_window`` on the fused scans: still accepted (it is the
+    public knob and a cache-key dimension), and the row-addressed
+    kernels merge every grid step whatever its value.  The contract is
+    bit-identity with the per-step merge (W=1): VALUES bit-equal
     at every rank, IDS bit-equal at every live rank (exhausted ranks
     all carry the sentinel value, so their relative id order is
     unspecified; the epilogue maps every such rank to +inf / -1)."""
@@ -654,10 +655,10 @@ class TestWindowedMerge:
             fin = np.isfinite(cd)
             order = np.argsort(cd[fin], kind="stable")
             ref_d, ref_i = cd[fin][order][:k], ci[fin][order][:k]
-            good = av[:k, q] < pqp._ACC_WORST / 2
-            np.testing.assert_array_equal(av[:k, q][good],
+            good = av[q, :k] < pqp._ACC_WORST / 2
+            np.testing.assert_array_equal(av[q, :k][good],
                                           ref_d[:good.sum()])
-            np.testing.assert_array_equal(ai[:k, q][good],
+            np.testing.assert_array_equal(ai[q, :k][good],
                                           ref_i[:good.sum()])
             assert good.sum() == min(k, fin.sum())
 
@@ -682,7 +683,7 @@ class TestWindowedMerge:
             assert all(int(x) in alive for x in i[live])
 
     def test_fused_codes_windowed_large_k(self, scan_index):
-        """Codes-kernel staging ring at k=128: windowed merge is
+        """Codes kernel at k=128: any merge_window is
         bit-identical to the per-step merge and lands the same
         candidates as the non-fused codes path at matched kt."""
         q, built = scan_index
@@ -713,7 +714,7 @@ class TestWindowedMerge:
 
     def test_xla_twin_windowed_scatter_matches(self, scan_index):
         """grouped.scan_and_scatter's merge_window (the AOT export's
-        XLA twin of the staging ring) defers the scatter to one pass
+        XLA grouped scan) defers the scatter to one pass
         per W blocks; the scatter is idempotent over disjoint slots, so
         every W must reproduce the unwindowed result exactly."""
         q, built = scan_index

@@ -192,7 +192,7 @@ class TestExactOneHot:
 class TestFusedScanMatchesXlaTwin:
     """The fused in-kernel top-k scans (interpret mode) against the XLA
     grouped twin at full probe.  k = kt = 10 is not a multiple of the
-    8-row sublane tile, so every staging-ring slot carries pad rows, and
+    8-row sublane tile, so every accumulator row carries pad lanes, and
     eight queries x eight lists leave sentinel slots in every group."""
 
     @pytest.fixture(scope="class")

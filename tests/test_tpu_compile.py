@@ -66,10 +66,10 @@ def _assert_kernel(fn, *args):
     assert "tpu_custom_call" in text
 
 
-def _groups(spec):
-    ng, _ = grouped.group_capacity(NQ, N_PROBES, N_LISTS)
+def _groups(spec, nq=NQ):
+    ng, _ = grouped.group_capacity(nq, N_PROBES, N_LISTS)
     return ng, (spec((ng,), jnp.int32), spec((ng, grouped.GROUP), jnp.int32),
-                spec((NQ, ROT), jnp.float32), spec((N_LISTS, ROT), jnp.float32))
+                spec((nq, ROT), jnp.float32), spec((N_LISTS, ROT), jnp.float32))
 
 
 def _adm(spec, ng, with_adm):
@@ -77,12 +77,20 @@ def _adm(spec, ng, with_adm):
         else None
 
 
+# (nq, k, kt) of the fused scans: the flagship, the benchmark's batch
+# cell (k = kt = 20 before a 2x refine) and a 64-row serving bucket —
+# the SMEM query-row block, the dynamic row copies and the in-VMEM
+# transposes must lower at each
+FUSED_SHAPES = [(NQ, K, KT), (5000, 20, 20), (64, 20, 20)]
+
+
+@pytest.mark.parametrize("nq,k,kt", FUSED_SHAPES)
 @pytest.mark.parametrize("with_adm", [False, True])
-def test_fused_recon_scan(spec, with_adm):
-    ng, head = _groups(spec)
-    mw = pgs.fused_merge_window(CAP, ROT, KT, K, NQ)
+def test_fused_recon_scan(spec, with_adm, nq, k, kt):
+    ng, head = _groups(spec, nq)
+    mw = pgs.fused_merge_window(CAP, ROT, kt, k, nq)
     assert mw > 0
-    fn = functools.partial(pgs.grouped_l2_scan_fused, kt=KT, k=K,
+    fn = functools.partial(pgs.grouped_l2_scan_fused, kt=kt, k=k,
                            n_probes=N_PROBES, merge_window=mw)
     _assert_kernel(lambda *a: fn(*a[:7], adm_words=a[7]), *head,
                    spec((N_LISTS, CAP, ROT), jnp.bfloat16),
@@ -90,12 +98,13 @@ def test_fused_recon_scan(spec, with_adm):
                    spec((N_LISTS, CAP), jnp.int32), _adm(spec, ng, with_adm))
 
 
+@pytest.mark.parametrize("nq,k,kt", FUSED_SHAPES)
 @pytest.mark.parametrize("with_adm", [False, True])
-def test_fused_code_scan(spec, with_adm):
-    ng, head = _groups(spec)
-    mw = pcs.fused_codes_merge_window(CAP, ROT, KT, K, NQ, PQ_DIM, PQ_BITS)
+def test_fused_code_scan(spec, with_adm, nq, k, kt):
+    ng, head = _groups(spec, nq)
+    mw = pcs.fused_codes_merge_window(CAP, ROT, kt, k, nq, PQ_DIM, PQ_BITS)
     assert mw > 0
-    fn = functools.partial(pcs.grouped_code_scan_fused, kt=KT, k=K,
+    fn = functools.partial(pcs.grouped_code_scan_fused, kt=kt, k=k,
                            n_probes=N_PROBES, pq_bits=PQ_BITS,
                            merge_window=mw)
     _assert_kernel(lambda *a: fn(*a[:8], adm_words=a[8]), *head,
