@@ -64,6 +64,7 @@ from raft_tpu.core import platform as _platform
 from raft_tpu.core.compat import shard_map
 from raft_tpu.core.error import expects
 from raft_tpu.core.mdarray import ensure_array
+from raft_tpu.core.tracing import annotation as _annotation
 from raft_tpu.core.tracing import range as named_range
 from raft_tpu.distance.types import DistanceType
 from raft_tpu.filters import bitset as _fbits
@@ -71,6 +72,7 @@ from raft_tpu.matrix.select_k import select_k
 from raft_tpu.neighbors import grouped
 from raft_tpu.neighbors import ivf_pq
 from raft_tpu.neighbors import mutate as _mutate
+from raft_tpu.neighbors.refine import exact_distances as _exact_distances
 from raft_tpu.observability import flight as _flight
 from raft_tpu.observability import trace as _rtrace
 from raft_tpu.ops import vmem_budget as vb
@@ -103,6 +105,14 @@ def _entry(site, fn, retry_policy, deadline):
         return fn()
     return _retry.retry_call(attempt, site=site, policy=retry_policy,
                              deadline=deadline)
+
+
+def _dispatch(fn, retry_policy, deadline):
+    """The search's device dispatch: :func:`_entry` at the
+    ``distributed.ann.search`` site, inside the host span
+    ``raft_tpu:distributed.dispatch``."""
+    with _annotation("distributed.dispatch"):
+        return _entry("distributed.ann.search", fn, retry_policy, deadline)
 
 
 def _degraded_set(n_shards: int, failed_shards: Sequence[int]
@@ -651,6 +661,46 @@ def _merge_gathered(ld, li, q, k, metric, axis_name, failed):
         nq, k, select_min, False, select_k)
 
 
+def _merge_refined(rows, row_pos, q, ld, li, k, metric, axis_name):
+    """The refined candidate exchange, after a shard's scan at k*r:
+
+    1. every shard's top-(k*r) scan candidates ``(ld, li)`` are
+       all_gathered and the replicated merge keeps the global top-(k*r)
+       by the scan's distance — the set ``ivf_pq.search`` at k*r returns
+       on one chip, whichever replica served each list;
+    2. the shard that scanned a kept candidate computes its exact f32
+       distance against the raw rows it holds (``rows``, ``row_pos``),
+       with the arithmetic of :func:`raft_tpu.neighbors.refine.refine`;
+    3. one psum assembles the exact distances (every other shard adds
+       zero) and the replicated exact top-k is returned.
+
+    Every shard re-ranks the same (q, k*r) layout, so the answer is
+    bit-identical under any assignment of lists to replicas; a shard's
+    own exact top-k would make it depend on which replica scanned which
+    list."""
+    nq, kr = ld.shape
+    select_min = metric != DistanceType.InnerProduct
+
+    def gathered(x):                     # (n_dev, q, kr) -> (q, n_dev*kr)
+        return jnp.transpose(jax.lax.all_gather(x, axis_name),
+                             (1, 0, 2)).reshape(nq, -1)
+    all_d, all_i = gathered(ld), gathered(li)
+    _, pos = select_k(all_d, kr, select_min=select_min)
+    cand_d = jnp.take_along_axis(all_d, pos, axis=1)
+    cand_i = jnp.take_along_axis(all_i, pos, axis=1)
+    mine = pos // kr == jax.lax.axis_index(axis_name)
+    # only a slot the scan filled (a real id at a finite distance) is
+    # re-ranked: exhausted, tombstoned and filter-rejected slots stay at
+    # the worst value
+    at = row_pos[jnp.where(mine & (cand_i >= 0), cand_i, 0)]
+    valid = (cand_i >= 0) & jnp.isfinite(cand_d) & (at >= 0)
+    flat = rows.reshape(-1, rows.shape[-1])
+    exact = _exact_distances(q, flat[jnp.maximum(at, 0)], valid, metric)
+    exact = jax.lax.psum(jnp.where(mine, exact, 0.0), axis_name)
+    return grouped.finalize_topk(exact, cand_i, nq, k, select_min, False,
+                                 select_k)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "k", "kt", "n_probes", "metric", "axis_name", "mesh", "n_groups",
     "form", "use_pallas", "merge_window", "failed"))
@@ -771,7 +821,8 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
            shard_deadline_s: Optional[float] = None,
            hedge: bool = True,
            routing=None,
-           filter=None):
+           filter=None,
+           refine_ratio: int = 1):
     """Sharded search + merge; returns replicated (distances, global ids)
     of shape (q, k).  Accepts both placements: a
     :class:`DistributedIndex` (data-parallel full-shard scan) or a
@@ -879,6 +930,25 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
     ``return_stats=True`` the stats dict gains ``admitted_rows``, and
     the lazy per-shard vector is annotated on the ambient trace as
     ``distributed.admitted_rows``.
+
+    ``refine_ratio=r > 1`` (a routed index that carries raw rows: built
+    from a dataset, or :func:`shard_by_list` with ``dataset=``) re-ranks
+    on the owning shard: every shard scans at ``k * r``, the replicated
+    merge keeps the global top-``k * r`` by the scan's distance, the
+    shard that scanned each kept candidate computes its exact f32
+    distance against the rows it holds (the arithmetic of
+    :func:`raft_tpu.neighbors.refine.refine`), and the exact top-``k``
+    is returned — what ``ivf_pq.search`` at ``k * r`` followed by
+    ``refine`` to ``k`` returns on one chip (:func:`_merge_refined`).
+    The candidate set does not depend on which replica serves a list, so
+    failover, hedging and routing tables stay bit-identical.  Each such
+    search ticks ``distributed.routed.refined_rows`` by ``nq * k * r``
+    while collection is on.
+
+    Host spans (``core.tracing.annotation``, always on):
+    ``raft_tpu:distributed.route`` covers failover, hedging, the routing
+    policy's plan and the effective tables' upload;
+    ``raft_tpu:distributed.dispatch`` the ``shard_map`` call.
     """
     with named_range("distributed::ivf_pq_search"):
         expects(handle.comms_initialized(),
@@ -896,6 +966,19 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
         k = int(k)
         fw = _fbits.query_filter_words(filter, nq, "distributed.ann.search")
         routed = isinstance(index, RoutedIndex)
+        refine_ratio = int(refine_ratio)
+        expects(refine_ratio >= 1,
+                "distributed.ann.search: refine_ratio must be >= 1")
+        refine_to = k if refine_ratio > 1 else 0
+        if refine_to:
+            expects(routed, "distributed.ann.search: refine_ratio > 1 "
+                    "needs a routed (placement='by_list') index")
+            expects(index.list_rows is not None,
+                    "distributed.ann.search: refine_ratio > 1 needs the "
+                    "raw rows on the shards (build by_list from a "
+                    "dataset, or shard_by_list(..., dataset=))")
+        # the shards scan at k * r when they re-rank
+        k_scan = k * refine_ratio
         rec = _rtrace.current()
         rf = (index.placement.replication_factor
               if routed and index.placement is not None else 1)
@@ -930,77 +1013,85 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
             for s in flagged:
                 health.note_straggle(s)
         # -- replica failover + hedging (host-side, data not shape) ----
-        hedge_cand = set()
-        if hedge and routed and rf > 1:
-            hedge_cand = set(flagged) - set(failed)
-            if health is not None:
-                hedge_cand |= set(health.suspect_shards()) - set(failed)
-        hedged: Tuple[int, ...] = ()
-        residual = failed
-        replica_served: Tuple[int, ...] = ()
-        eff = None  # (eff_owner, eff_slot) host numpy, or None
-        # load-aware policy: plan() honors the same keep-primary-when-
-        # uncovered contract as healthy_routing, so the residual /
-        # covered bookkeeping below composes with either table source
-        use_policy = routing is not None and routed and rf > 1
+        with _annotation("distributed.route"):
+            hedge_cand = set()
+            if hedge and routed and rf > 1:
+                hedge_cand = set(flagged) - set(failed)
+                if health is not None:
+                    hedge_cand |= set(health.suspect_shards()) - set(failed)
+            hedged: Tuple[int, ...] = ()
+            residual = failed
+            replica_served: Tuple[int, ...] = ()
+            eff = None  # (eff_owner, eff_slot) host numpy, or None
+            # load-aware policy: plan() honors the same keep-primary-when-
+            # uncovered contract as healthy_routing, so the residual /
+            # covered bookkeeping below composes with either table source
+            use_policy = routing is not None and routed and rf > 1
 
-        def _route_tables(d):
-            if use_policy:
-                return routing.plan(index.placement, down=d)
-            return index.placement.healthy_routing(d)
+            def _route_tables(d):
+                if use_policy:
+                    return routing.plan(index.placement, down=d)
+                return index.placement.healthy_routing(d)
 
-        if routed and rf > 1 and (failed or hedge_cand or use_policy):
-            down = set(failed) | hedge_cand
-            eo, es = _route_tables(tuple(sorted(down)))
-            still = down & set(np.unique(eo).tolist())
-            # a hedge candidate whose lists have no live replica is
-            # UN-hedged: the shard is alive, just slow — wait for it
-            # rather than drop its lists
-            unhedged = hedge_cand & still
-            hedged = tuple(sorted(hedge_cand - unhedged))
-            down = set(failed) | set(hedged)
-            if unhedged and down:
+            if routed and rf > 1 and (failed or hedge_cand or use_policy):
+                down = set(failed) | hedge_cand
                 eo, es = _route_tables(tuple(sorted(down)))
-            if down:
                 still = down & set(np.unique(eo).tolist())
-                residual = tuple(sorted(set(failed) & still))
-                replica_served = tuple(sorted(down - still))
-                eff = (eo, es)
-            elif use_policy:
-                # pure load spreading: nothing down, every list served
-                # by its least-loaded live rank
-                eff = (eo, es)
-            if use_policy:
-                reason = ("failover" if failed
-                          else "hedge" if hedged else "load_spread")
-                choice = routing.choice_summary()
-                _flight.record_event(
-                    "distributed.replica_choice",
-                    trace_id=rec.trace_id if rec else None,
-                    reason=reason,
-                    scores=choice.get("scores"),
-                    per_rank_lists=choice.get("per_rank_lists"),
-                    per_shard_lists=choice.get("per_shard_lists"))
-                from raft_tpu import observability as obs
-                if obs.enabled():
-                    obs.registry().counter(
-                        "distributed.replica_choice").inc()
-            if failed and set(failed) - set(residual):
-                _flight.record_event(
-                    "distributed.replica_failover",
-                    trace_id=rec.trace_id if rec else None,
-                    failed=list(failed), residual=list(residual),
-                    covered=sorted(set(failed) - set(residual)))
-            for s in hedged:
-                _flight.record_event("distributed.hedged_read",
-                                     trace_id=rec.trace_id if rec else None,
-                                     shard=s, delay_s=delays[s]
-                                     if s < len(delays) else 0.0)
-            if hedged:
-                from raft_tpu import observability as obs
-                if obs.enabled():
-                    obs.registry().counter(
-                        "distributed.hedged_reads").inc(len(hedged))
+                # a hedge candidate whose lists have no live replica is
+                # UN-hedged: the shard is alive, just slow — wait for it
+                # rather than drop its lists
+                unhedged = hedge_cand & still
+                hedged = tuple(sorted(hedge_cand - unhedged))
+                down = set(failed) | set(hedged)
+                if unhedged and down:
+                    eo, es = _route_tables(tuple(sorted(down)))
+                if down:
+                    still = down & set(np.unique(eo).tolist())
+                    residual = tuple(sorted(set(failed) & still))
+                    replica_served = tuple(sorted(down - still))
+                    eff = (eo, es)
+                elif use_policy:
+                    # pure load spreading: nothing down, every list served
+                    # by its least-loaded live rank
+                    eff = (eo, es)
+                if use_policy:
+                    reason = ("failover" if failed
+                              else "hedge" if hedged else "load_spread")
+                    choice = routing.choice_summary()
+                    _flight.record_event(
+                        "distributed.replica_choice",
+                        trace_id=rec.trace_id if rec else None,
+                        reason=reason,
+                        scores=choice.get("scores"),
+                        per_rank_lists=choice.get("per_rank_lists"),
+                        per_shard_lists=choice.get("per_shard_lists"))
+                    from raft_tpu import observability as obs
+                    if obs.enabled():
+                        obs.registry().counter(
+                            "distributed.replica_choice").inc()
+                if failed and set(failed) - set(residual):
+                    _flight.record_event(
+                        "distributed.replica_failover",
+                        trace_id=rec.trace_id if rec else None,
+                        failed=list(failed), residual=list(residual),
+                        covered=sorted(set(failed) - set(residual)))
+                for s in hedged:
+                    _flight.record_event(
+                        "distributed.hedged_read",
+                        trace_id=rec.trace_id if rec else None,
+                        shard=s,
+                        delay_s=delays[s] if s < len(delays) else 0.0)
+                if hedged:
+                    from raft_tpu import observability as obs
+                    if obs.enabled():
+                        obs.registry().counter(
+                            "distributed.hedged_reads").inc(len(hedged))
+            # effective routing tables: same shape as the healthy tables
+            # (replica choice is data, not shape — no recompile)
+            eff_tables = None
+            if eff is not None:
+                eff_tables = (_replicate(jnp.asarray(eff[0]), handle.mesh),
+                              _replicate(jnp.asarray(eff[1]), handle.mesh))
         # pay the straggler wait: a hedged shard's wait collapses to the
         # deadline (the replica answered instead); everyone else is
         # waited for in full.  The sleep stays in the resilience layer.
@@ -1015,7 +1106,7 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
         faults.pause(wait)
         n_probes = min(params.n_probes,
                        index.n_lists if routed else index.centers.shape[1])
-        r = _resolve_scan_mode(params, index, nq, n_probes, k)
+        r = _resolve_scan_mode(params, index, nq, n_probes, k_scan)
         # per-request tracing: annotate the ambient recorder (pushed by
         # the serving batcher around its executor call) with the host-
         # static facts of this dispatch.  Everything attached here is
@@ -1055,19 +1146,16 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
                            index.list_recon_sq, index.list_indices)
                 replicated = (index.coarse_centers, index.rotation,
                               index.owner, index.local_slot)
-                if eff is not None:
-                    # effective routing tables: same shape as the
-                    # healthy tables (replica choice is data, not
-                    # shape — no recompile), swapped in host-side
-                    replicated = replicated[:2] + (
-                        _replicate(jnp.asarray(eff[0]), handle.mesh),
-                        _replicate(jnp.asarray(eff[1]), handle.mesh))
-                out = _entry(
-                    "distributed.ann.search",
+                if eff_tables is not None:
+                    replicated = replicated[:2] + eff_tables
+                if refine_to:
+                    sharded += (index.list_rows, index.row_pos)
+                out = _dispatch(
                     lambda: _dist_search_routed(
-                        sharded, replicated, queries, k, n_probes,
+                        sharded, replicated, queries, k_scan, n_probes,
                         index.metric, comms.axis_name, handle.mesh,
-                        failed=residual, filter_words=fw),
+                        failed=residual, filter_words=fw,
+                        refine_to=refine_to),
                     retry_policy, deadline)
                 if fw is not None:
                     d, i, scanned, phist, admitted = out
@@ -1075,24 +1163,24 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
                     d, i, scanned, phist = out
             else:
                 sharded, replicated = _routed_leaves(index, r.form)
-                if eff is not None:
-                    replicated = replicated[:2] + (
-                        _replicate(jnp.asarray(eff[0]), handle.mesh),
-                        _replicate(jnp.asarray(eff[1]), handle.mesh),
-                    ) + replicated[4:]
+                if eff_tables is not None:
+                    replicated = (replicated[:2] + eff_tables
+                                  + replicated[4:])
+                if refine_to:
+                    sharded += (index.list_rows, index.row_pos)
 
                 def dispatch(ng):
                     out = _dist_search_routed_grouped(
-                        sharded, replicated, queries, k, r.kt, n_probes,
-                        index.metric, comms.axis_name, handle.mesh, ng,
-                        r.form, pq_bits=int(index.pq_bits),
+                        sharded, replicated, queries, k_scan, r.kt,
+                        n_probes, index.metric, comms.axis_name,
+                        handle.mesh, ng, r.form,
+                        pq_bits=int(index.pq_bits),
                         use_pallas=r.use_pallas,
                         merge_window=r.merge_window, failed=residual,
-                        filter_words=fw)
+                        filter_words=fw, refine_to=refine_to)
                     return out if fw is not None else out + (None,)
 
-                d, i, scanned, needed, phist, admitted = _entry(
-                    "distributed.ann.search",
+                d, i, scanned, needed, phist, admitted = _dispatch(
                     lambda: dispatch(r.n_groups), retry_policy, deadline)
                 if not r.exact:
                     # calibrated-capacity regime: the ONE deliberate host
@@ -1116,8 +1204,7 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
         elif r.form == "probe_recon":
             leaves = (index.centers, index.list_indices, index.rotation,
                       index.list_recon)
-            out = _entry(
-                "distributed.ann.search",
+            out = _dispatch(
                 lambda: _dist_search(leaves, queries, k, n_probes,
                                      index.metric, comms.axis_name,
                                      handle.mesh, failed=residual,
@@ -1129,8 +1216,7 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
                       index.list_indices, index.rotation)
             lut_dtype = jnp.dtype(
                 getattr(params, "lut_dtype", jnp.float32)).name
-            out = _entry(
-                "distributed.ann.search",
+            out = _dispatch(
                 lambda: _dist_search_lut(
                     leaves, queries, k, n_probes, index.metric,
                     index.codebook_kind, lut_dtype,
@@ -1142,8 +1228,7 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
             leaves = (index.centers, index.list_recon,
                       _recon_sq_stack(index), index.list_indices,
                       index.rotation)
-            out = _entry(
-                "distributed.ann.search",
+            out = _dispatch(
                 lambda: _dist_search_grouped(
                     leaves, queries, k, r.kt, n_probes, index.metric,
                     comms.axis_name, handle.mesh, r.n_groups, r.form,
@@ -1170,6 +1255,11 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
                 # lazy, like scanned_rows: per-shard admitted-candidate
                 # counts ride the existing candidate gather
                 rec.annotate("distributed.admitted_rows", admitted)
+        if refine_to:
+            from raft_tpu import observability as obs
+            if obs.enabled():
+                obs.registry().counter(
+                    "distributed.routed.refined_rows").inc(nq * k_scan)
         if routing is not None and phist is not None:
             # the probe-frequency counters: the policy retains the lazy
             # device histogram; materialization happens only in its
@@ -1190,7 +1280,7 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
             else:
                 # graftlint: disable=host-sync -- opt-in stats readback (return_stats=True), not the serving dispatch
                 per = np.asarray(scanned, np.int64)
-            gather = (index.n_shards, nq, k)
+            gather = (index.n_shards, nq, k_scan)
             stats = {"scanned_rows": per, "gather_shape": gather,
                      "scan_mode": {"probe_recon": "recon"}.get(
                          r.form, r.form),
@@ -1464,6 +1554,14 @@ class RoutedIndex:
     codebooks: Optional[jax.Array] = None        # replicated
     list_code_lanes: Optional[jax.Array] = None  # (n_dev, L+1, Wi, cap)
     list_code_rsq: Optional[jax.Array] = None    # (n_dev, L+1, cap)
+    # optional raw rows for the re-rank on the owning shard: each held
+    # list's dataset rows in slot order (f32, placed exactly as
+    # list_recon, the dummy slot zero), and each shard's map from a
+    # global id to its row of the flattened (slot * cap + pos) leaf, -1
+    # where the shard holds no copy.  Carried when the index was placed
+    # from a dataset (build, or shard_by_list(dataset=)); None otherwise.
+    list_rows: Optional[jax.Array] = None        # (n_dev, L+1, cap, dim)
+    row_pos: Optional[jax.Array] = None          # (n_dev, n_ids) int32
     metric: int = DistanceType.L2Expanded
     size: int = 0
     pq_bits: int = 0
@@ -1498,7 +1596,7 @@ class RoutedIndex:
                  self.local_slot, self.local_centers, self.list_recon,
                  self.list_recon_sq, self.list_indices, self.list_sizes,
                  self.codebooks, self.list_code_lanes,
-                 self.list_code_rsq),
+                 self.list_code_rsq, self.list_rows, self.row_pos),
                 (self.metric, self.size, self.pq_bits, self.group_est))
 
     @classmethod
@@ -1525,21 +1623,45 @@ def _replicate(arr, mesh):
         mesh, P(*([None] * jnp.ndim(arr)))))
 
 
+@functools.partial(jax.jit, static_argnames=("n_ids",))
+def _row_positions(list_indices, n_ids):
+    """(n_ids,) int32: the row of the flattened (slot * cap + pos) leaf
+    that holds each global id in one shard's ``list_indices``, -1 for
+    ids the shard holds no copy of."""
+    flat = list_indices.reshape(-1)
+    at = jnp.where(flat >= 0, flat, n_ids)
+    pos = jnp.arange(flat.shape[0], dtype=jnp.int32)
+    return jnp.full((n_ids,), -1, jnp.int32).at[at].set(pos, mode="drop")
+
+
+@jax.jit
+def _gather_list_rows(dataset, list_indices):
+    """(n_lists, cap, dim) f32: the dataset row of every list slot, zero
+    where the slot holds no live row."""
+    valid = list_indices >= 0
+    rows = dataset[jnp.where(valid, list_indices, 0)].astype(jnp.float32)
+    return jnp.where(valid[..., None], rows, 0.0)
+
+
 def _place_lists(handle, global_leaves, rotation, placement: Placement,
                  metric, size, code_leaves=None, pq_bits=0,
-                 group_est=0.0) -> RoutedIndex:
+                 group_est=0.0, rows=None) -> RoutedIndex:
     """Assemble a :class:`RoutedIndex` from global per-list arrays
     (centers, recon, recon_sq, indices, sizes) under ``placement``.
     ``code_leaves`` optionally carries (codebooks, list_code_lanes,
     list_code_rsq) — the lane-major compact-code cache the routed fused
     scan streams; the lanes/rsq shard like the recon leaves (axis 0 is
-    the global list id), the codebooks replicate."""
+    the global list id), the codebooks replicate.  ``rows`` optionally
+    carries the (n_lists, cap, dim) raw rows, placed like the recon
+    leaves, with each shard's id -> row map built beside them."""
     centers, recon, rsq, li, sizes = global_leaves
     comms, mesh, axis, n_dev, devs = _mesh_layout(handle)
     expects(placement.n_shards == n_dev,
             f"distributed.ann: placement maps {placement.n_shards} "
             f"shards but the mesh has {n_dev} devices")
     slots = placement.n_local + 1  # terminal dummy slot
+    # one host read on the admin path: the id map's static width
+    n_ids = int(jnp.max(li)) + 1 if rows is not None else 0
 
     per_shard = []
     for s in range(n_dev):
@@ -1550,12 +1672,16 @@ def _place_lists(handle, global_leaves, rotation, placement: Placement,
             width = ((0, slots - sel.shape[0]),) + ((0, 0),) * (a.ndim - 1)
             return jnp.pad(sel, width, constant_values=fill)
 
+        li_s = pad(li, -1)
         leaves_s = (pad(centers, 0), pad(recon, 0), pad(rsq, 0),
-                    pad(li, -1), pad(sizes, 0))
+                    li_s, pad(sizes, 0))
         if code_leaves is not None:
             leaves_s += (pad(code_leaves[1], 0), pad(code_leaves[2], 0))
+        if rows is not None:
+            leaves_s += (pad(rows, 0), _row_positions(li_s, n_ids))
         per_shard.append(leaves_s)
     placed = _stack_leaves(per_shard, mesh, axis, devs)
+    n_code = 2 if code_leaves is not None else 0
     return RoutedIndex(
         coarse_centers=_replicate(centers, mesh),
         rotation=_replicate(rotation, mesh),
@@ -1568,13 +1694,16 @@ def _place_lists(handle, global_leaves, rotation, placement: Placement,
                    if code_leaves is not None else None),
         list_code_lanes=placed[5] if code_leaves is not None else None,
         list_code_rsq=placed[6] if code_leaves is not None else None,
+        list_rows=placed[5 + n_code] if rows is not None else None,
+        row_pos=placed[6 + n_code] if rows is not None else None,
         metric=metric, size=size, pq_bits=int(pq_bits),
         group_est=float(group_est), placement=placement)
 
 
 def shard_by_list(handle, index, *,
                   placement: Optional[Placement] = None,
-                  replication_factor: int = 1) -> RoutedIndex:
+                  replication_factor: int = 1,
+                  dataset=None) -> RoutedIndex:
     """Partition a single-chip IVF-PQ index's lists across the mesh.
 
     The index must carry the reconstruction cache (the shard-local scan
@@ -1589,7 +1718,14 @@ def shard_by_list(handle, index, *,
     routing serves every list from its primary, and a failed shard's
     lists fail over to replicas with results bit-identical to the
     healthy run (ignored when an explicit ``placement`` is passed — the
-    placement carries its own factor)."""
+    placement carries its own factor).
+
+    ``dataset`` (the rows ``index`` was built over, ids indexing it)
+    places each list's raw rows with its copies, f32, on every shard
+    that holds the list — what ``search(..., refine_ratio > 1)``
+    re-ranks against on the owning shard.  No shard holds the whole
+    dataset: a shard's rows cost ``(L+1) * cap * dim * 4`` bytes, about
+    ``r / n_shards`` of the dataset's."""
     with named_range("distributed::shard_by_list"):
         expects(handle.comms_initialized(),
                 "distributed.ann.shard_by_list: handle has no comms")
@@ -1603,6 +1739,16 @@ def shard_by_list(handle, index, *,
             placement = compute_placement(
                 np.asarray(live), n_dev,
                 replication_factor=replication_factor)
+        rows = None
+        if dataset is not None:
+            dataset = ensure_array(dataset, "dataset")
+            expects(dataset.ndim == 2 and dataset.shape[1] == index.dim,
+                    f"distributed.ann.shard_by_list: dataset must be "
+                    f"(n, {index.dim}), got {tuple(dataset.shape)}")
+            expects(int(jnp.max(index.list_indices)) < dataset.shape[0],
+                    "distributed.ann.shard_by_list: the index holds ids "
+                    "past the dataset's rows")
+            rows = _gather_list_rows(dataset, index.list_indices)
         rsq = index.list_recon_sq
         if rsq is None:
             rsq = ivf_pq._recon_sq(index.list_recon)
@@ -1624,7 +1770,7 @@ def shard_by_list(handle, index, *,
                      index.list_indices, index.list_sizes),
             index.rotation, placement, index.metric, size,
             code_leaves=code_leaves, pq_bits=pq_bits,
-            group_est=float(getattr(index, "group_est", 0.0)))
+            group_est=float(getattr(index, "group_est", 0.0)), rows=rows)
         out.canaries = getattr(index, "canaries", None)
         out.generation = _mutate.generation(index)
         # precompute the fused kernels' id-exactness verdict now (one
@@ -1652,7 +1798,8 @@ def _build_by_list(handle, params: ivf_pq.IndexParams, dataset,
         # tiny and replicated; only the lists are partitioned
         base = ivf_pq.build(handle, params, dataset)
         return shard_by_list(handle, base,
-                             replication_factor=replication_factor)
+                             replication_factor=replication_factor,
+                             dataset=dataset)
 
 
 def _gather_global(index: RoutedIndex):
@@ -1670,7 +1817,18 @@ def _gather_global(index: RoutedIndex):
     if index.list_code_lanes is not None:
         code_leaves = (index.codebooks, index.list_code_lanes[own, slot],
                        index.list_code_rsq[own, slot])
-    return centers, recon, rsq, li, sizes, code_leaves
+    rows = (index.list_rows[own, slot] if index.list_rows is not None
+            else None)
+    return centers, recon, rsq, li, sizes, code_leaves, rows
+
+
+def global_list_sizes(index: RoutedIndex) -> np.ndarray:
+    """Live rows of each global list, (n_lists,) host numpy, counted on
+    the list's primary copy (admin path: one small cross-device gather)
+    — the list sizes a routed search's work accounting reads."""
+    own = jnp.asarray(np.asarray(index.owner), jnp.int32)
+    slot = jnp.asarray(np.asarray(index.local_slot), jnp.int32)
+    return np.asarray(jnp.sum(index.list_indices[own, slot] >= 0, axis=-1))
 
 
 def route_vectors(index: RoutedIndex, vectors) -> np.ndarray:
@@ -1692,9 +1850,15 @@ def route_vectors(index: RoutedIndex, vectors) -> np.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("k", "n_probes", "metric",
-                                             "axis_name", "mesh", "failed"))
+                                             "axis_name", "mesh", "failed",
+                                             "refine_to"))
 def _dist_search_routed(sharded, replicated, queries, k, n_probes, metric,
-                        axis_name, mesh, failed=(), filter_words=None):
+                        axis_name, mesh, failed=(), filter_words=None,
+                        refine_to=0):
+    """Routed probe-order recon scan under ``shard_map``.  With
+    ``refine_to`` > 0 the shards scan at ``k`` (= refine_to * ratio)
+    and re-rank the kept candidates against the raw rows they hold (the
+    last two ``sharded`` leaves): :func:`_merge_refined`."""
     sspecs = tuple(P(axis_name, *([None] * (leaf.ndim - 1)))
                    for leaf in sharded)
     rspecs = tuple(P() for _ in replicated)
@@ -1710,7 +1874,7 @@ def _dist_search_routed(sharded, replicated, queries, k, n_probes, metric,
                        in_specs=in_specs, out_specs=out_specs,
                        check_vma=False)
     def run(sl, rl, q, *rest):
-        local_centers, list_recon, list_recon_sq, list_indices = sl
+        local_centers, list_recon, list_recon_sq, list_indices = sl[:4]
         coarse, rot, owner, local_slot = rl
         s = jax.lax.axis_index(axis_name)
         cap = list_recon.shape[2]
@@ -1744,23 +1908,27 @@ def _dist_search_routed(sharded, replicated, queries, k, n_probes, metric,
             ld = jnp.where(bad, jnp.full_like(ld, sentinel), ld)
             li = jnp.where(bad, jnp.full_like(li, -1), li)
             scanned = jnp.where(bad, 0, scanned)
-        # the k-bounded candidate exchange: exactly (q, k) pairs per
-        # shard regardless of index size — the payload the data-parallel
-        # path also ships, but here each pair was mined from 1/n_shards
-        # of the probed rows
-        all_d = jax.lax.all_gather(ld, axis_name)        # (n_dev, q, k)
-        all_i = jax.lax.all_gather(li, axis_name)
         all_scanned = jax.lax.all_gather(scanned, axis_name)  # (n_dev,)
         nq = q.shape[0]
-        # hierarchical exactness: a global top-k candidate is in its
-        # owning shard's local top-k, so the replicated merge over the
-        # (n_dev * k)-wide survivors equals the single-index search.
-        # sqrt=False: the shard-local epilogue already applied it for
-        # the sqrt metrics, and the merge is monotone
-        md, mi = grouped.finalize_topk(
-            jnp.transpose(all_d, (1, 0, 2)),
-            jnp.transpose(all_i, (1, 0, 2)),
-            nq, k, select_min, False, select_k)
+        if refine_to:
+            md, mi = _merge_refined(sl[4][0], sl[5][0], q, ld, li,
+                                    refine_to, metric, axis_name)
+        else:
+            # the k-bounded candidate exchange: exactly (q, k) pairs per
+            # shard regardless of index size — the payload the
+            # data-parallel path also ships, but here each pair was
+            # mined from 1/n_shards of the probed rows
+            all_d = jax.lax.all_gather(ld, axis_name)    # (n_dev, q, k)
+            all_i = jax.lax.all_gather(li, axis_name)
+            # hierarchical exactness: a global top-k candidate is in its
+            # owning shard's local top-k, so the replicated merge over
+            # the (n_dev * k)-wide survivors equals the single-index
+            # search.  sqrt=False: the shard-local epilogue already
+            # applied it for the sqrt metrics, and the merge is monotone
+            md, mi = grouped.finalize_topk(
+                jnp.transpose(all_d, (1, 0, 2)),
+                jnp.transpose(all_i, (1, 0, 2)),
+                nq, k, select_min, False, select_k)
         if has_f:
             admitted = jax.lax.all_gather(
                 jnp.sum((li >= 0).astype(jnp.int32)), axis_name)
@@ -1792,12 +1960,14 @@ def _routed_leaves(index: "RoutedIndex", form: str):
 
 @functools.partial(jax.jit, static_argnames=(
     "k", "kt", "n_probes", "metric", "axis_name", "mesh", "n_groups",
-    "form", "pq_bits", "use_pallas", "merge_window", "failed"))
+    "form", "pq_bits", "use_pallas", "merge_window", "failed",
+    "refine_to"))
 def _dist_search_routed_grouped(sharded, replicated, queries, k, kt,
                                 n_probes, metric, axis_name, mesh,
                                 n_groups, form, pq_bits=0,
                                 use_pallas=False, merge_window=1,
-                                failed=(), filter_words=None):
+                                failed=(), filter_words=None,
+                                refine_to=0):
     """Routed (by_list) grouped/fused scan under ``shard_map``
     (round 10): the tentpole dispatch.  Replicated coarse routing picks
     the probe set, ownership maps it to local slots, and the shard scans
@@ -1808,7 +1978,8 @@ def _dist_search_routed_grouped(sharded, replicated, queries, k, kt,
     all_gathers its true required group count so the HOST can check the
     calibrated capacity without a second collective; the check itself
     (and the rare exact re-dispatch) lives in :func:`search`, keeping
-    this function sync-free."""
+    this function sync-free.  ``refine_to`` re-ranks on the owning
+    shard as in :func:`_dist_search_routed`."""
     sspecs = tuple(P(axis_name, *([None] * (leaf.ndim - 1)))
                    for leaf in sharded)
     rspecs = tuple(P() for _ in replicated)
@@ -1820,7 +1991,7 @@ def _dist_search_routed_grouped(sharded, replicated, queries, k, kt,
                        in_specs=in_specs, out_specs=out_specs,
                        check_vma=False)
     def run(sl, rl, q, *rest):
-        local_centers, data, rownorm, list_indices = sl
+        local_centers, data, rownorm, list_indices = sl[:4]
         fw = rest[0] if has_f else None
         coarse, rot, owner, local_slot = rl[:4]
         s = jax.lax.axis_index(axis_name)
@@ -1874,15 +2045,19 @@ def _dist_search_routed_grouped(sharded, replicated, queries, k, kt,
             li = jnp.where(bad, jnp.full_like(li, -1), li)
             scanned = jnp.where(bad, 0, scanned)
             needed = jnp.where(bad, 0, needed)
-        all_d = jax.lax.all_gather(ld, axis_name)        # (n_dev, q, k)
-        all_i = jax.lax.all_gather(li, axis_name)
         all_scanned = jax.lax.all_gather(scanned, axis_name)  # (n_dev,)
         all_needed = jax.lax.all_gather(needed, axis_name)    # (n_dev,)
         nq = q.shape[0]
-        md, mi = grouped.finalize_topk(
-            jnp.transpose(all_d, (1, 0, 2)),
-            jnp.transpose(all_i, (1, 0, 2)),
-            nq, k, select_min, False, select_k)
+        if refine_to:
+            md, mi = _merge_refined(sl[4][0], sl[5][0], q, ld, li,
+                                    refine_to, metric, axis_name)
+        else:
+            all_d = jax.lax.all_gather(ld, axis_name)    # (n_dev, q, k)
+            all_i = jax.lax.all_gather(li, axis_name)
+            md, mi = grouped.finalize_topk(
+                jnp.transpose(all_d, (1, 0, 2)),
+                jnp.transpose(all_i, (1, 0, 2)),
+                nq, k, select_min, False, select_k)
         if has_f:
             admitted = jax.lax.all_gather(
                 jnp.sum((li >= 0).astype(jnp.int32)), axis_name)
@@ -1910,7 +2085,8 @@ def rebalance_placement(handle, index: RoutedIndex, *,
         expects(index.placement is not None,
                 "distributed.ann.rebalance_placement: index carries no "
                 "placement map")
-        centers, recon, rsq, li, sizes, code_leaves = _gather_global(index)
+        (centers, recon, rsq, li, sizes, code_leaves,
+         rows) = _gather_global(index)
         if placement is None:
             live = jnp.sum(li >= 0, axis=1).astype(jnp.int32)
             placement = compute_placement(
@@ -1921,7 +2097,7 @@ def rebalance_placement(handle, index: RoutedIndex, *,
                            index.rotation, placement, index.metric,
                            index.size, code_leaves=code_leaves,
                            pq_bits=index.pq_bits,
-                           group_est=index.group_est)
+                           group_est=index.group_est, rows=rows)
         out.canaries = index.canaries
         _mutate.next_generation(index, out)
         return out
@@ -1935,7 +2111,10 @@ def rebalance_placement(handle, index: RoutedIndex, *,
 # bump marks the back-compat read window.  v1/v2 streams still read
 # (and land r=1); v2 READERS cannot open a replicated v3 stream — the
 # version check fails loudly instead of mis-parsing the rank tables.
-_ROUTED_SERIALIZATION_VERSION = 3
+# v4: trailing raw-rows block after the canaries — ``has_rows`` and,
+# when set, the global (n_lists, cap, dim) f32 rows; v1-v3 streams land
+# without rows (search refuses ``refine_ratio > 1`` on them).
+_ROUTED_SERIALIZATION_VERSION = 4
 _ROUTED_MIN_READ_VERSION = 1
 
 
@@ -1948,7 +2127,8 @@ def serialize_routed(res, stream, index: RoutedIndex) -> None:
     expects(index.placement is not None,
             "distributed.ann.serialize_routed: index carries no "
             "placement map")
-    centers, recon, rsq, li, sizes, code_leaves = _gather_global(index)
+    (centers, recon, rsq, li, sizes, code_leaves,
+     rows) = _gather_global(index)
     with ser.enveloped_writer(stream) as body:
         ser.serialize_scalar(
             res, body, np.int32(_ROUTED_SERIALIZATION_VERSION))
@@ -1975,6 +2155,9 @@ def serialize_routed(res, stream, index: RoutedIndex) -> None:
             ser.serialize_mdspan(res, body, crsq)
         from raft_tpu.integrity import canary as _canary
         _canary.to_stream(res, body, index.canaries)
+        ser.serialize_scalar(res, body, np.int32(rows is not None))
+        if rows is not None:
+            ser.serialize_mdspan(res, body, rows)
 
 
 def deserialize_routed(handle, stream) -> RoutedIndex:
@@ -2015,10 +2198,13 @@ def deserialize_routed(handle, stream) -> RoutedIndex:
             code_leaves = (books, lanes, crsq)
     from raft_tpu.integrity import canary as _canary
     canaries = _canary.from_stream(handle, body)
+    rows = None
+    if version >= 4 and int(ser.deserialize_scalar(handle, body)):
+        rows = jnp.asarray(ser.deserialize_mdspan(handle, body))
     out = _place_lists(handle, (centers, recon, rsq, li, sizes),
                        rotation, placement, metric, size,
                        code_leaves=code_leaves, pq_bits=pq_bits,
-                       group_est=group_est)
+                       group_est=group_est, rows=rows)
     out.canaries = canaries
     out.generation = generation
     return out

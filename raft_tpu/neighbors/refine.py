@@ -29,29 +29,37 @@ from raft_tpu.utils.precision import get_matmul_precision
 from raft_tpu.core.outputs import auto_convert_output
 
 
-@functools.partial(jax.jit, static_argnames=("k", "metric"))
-def _refine_impl(dataset, queries, candidates, k, metric):
-    nq, n_cand = candidates.shape
-    valid = candidates >= 0
-    safe = jnp.where(valid, candidates, 0)
-    cand_vecs = dataset[safe]                       # (q, n_cand, d)
+def exact_distances(queries, cand_vecs, valid, metric):
+    """Exact (q, n_cand) distances from each query to its candidate
+    vectors ``cand_vecs`` (q, n_cand, d), in f32 at the library's matmul
+    precision; slots where ``valid`` is False read the metric's worst
+    value (+inf, or -inf for inner product).  The arithmetic every
+    re-rank shares: :func:`refine` here, and the routed index's re-rank
+    on the shard that owns the rows (``distributed.ann``)."""
     qf = queries.astype(jnp.float32)
     cf = cand_vecs.astype(jnp.float32)
-
     if metric == DistanceType.InnerProduct:
         ip = jnp.einsum("qd,qcd->qc", qf, cf,
                         precision=get_matmul_precision())
-        d = jnp.where(valid, ip, -jnp.inf)
+        return jnp.where(valid, ip, -jnp.inf)
+    # squared L2 (sqrt applied for the sqrt metrics below)
+    diff2 = jnp.sum(cf * cf, axis=-1) - 2.0 * jnp.einsum(
+        "qd,qcd->qc", qf, cf, precision=get_matmul_precision())
+    d = jnp.maximum(diff2 + jnp.sum(qf * qf, axis=-1, keepdims=True), 0.0)
+    if metric in (DistanceType.L2SqrtExpanded,
+                  DistanceType.L2SqrtUnexpanded):
+        d = jnp.sqrt(d)
+    return jnp.where(valid, d, jnp.inf)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "metric"))
+def _refine_impl(dataset, queries, candidates, k, metric):
+    valid = candidates >= 0
+    safe = jnp.where(valid, candidates, 0)
+    d = exact_distances(queries, dataset[safe], valid, metric)
+    if metric == DistanceType.InnerProduct:
         vals, pos = jax.lax.top_k(d, k)
     else:
-        # squared L2 (sqrt applied for the sqrt metrics below)
-        diff2 = jnp.sum(cf * cf, axis=-1) - 2.0 * jnp.einsum(
-            "qd,qcd->qc", qf, cf, precision=get_matmul_precision())
-        d = jnp.maximum(diff2 + jnp.sum(qf * qf, axis=-1, keepdims=True), 0.0)
-        if metric in (DistanceType.L2SqrtExpanded,
-                      DistanceType.L2SqrtUnexpanded):
-            d = jnp.sqrt(d)
-        d = jnp.where(valid, d, jnp.inf)
         vals, pos = select_k(d, k, select_min=True)
     idx = jnp.take_along_axis(candidates, pos, axis=1)
     return vals, idx

@@ -490,8 +490,8 @@ def rebalance_routed(handle, index, *,
             obs.registry().counter("rebalance.routed.noops").inc()
         return index
 
-    centers, recon, rsq, gli, sizes, code_leaves = _dann._gather_global(
-        index)
+    (centers, recon, rsq, gli, sizes, code_leaves,
+     rows) = _dann._gather_global(index)
 
     faults.maybe_fail("rebalance.compact")
     if eligible:
@@ -508,6 +508,10 @@ def rebalance_routed(handle, index, *,
             drop[:, :, None], 0,
             jnp.take_along_axis(recon, order[:, :, None], axis=1))
         rsq = jnp.where(drop, 0, jnp.take_along_axis(rsq, order, axis=1))
+        if rows is not None:
+            rows = jnp.where(
+                drop[:, :, None], 0,
+                jnp.take_along_axis(rows, order[:, :, None], axis=1))
         sizes = jnp.where(sel, live, sizes)
         if code_leaves is not None:
             # the lane-major code cache is row-indexed on its LAST axis
@@ -540,7 +544,7 @@ def rebalance_routed(handle, index, *,
                               index.rotation, placement, index.metric,
                               index.size, code_leaves=code_leaves,
                               pq_bits=index.pq_bits,
-                              group_est=index.group_est)
+                              group_est=index.group_est, rows=rows)
     cand.canaries = index.canaries
     _mutate.next_generation(index, cand)          # the ONE global bump
 

@@ -4,7 +4,9 @@ Interpret-mode tests cannot see what Mosaic refuses (layouts, unaligned
 dynamic stores, VMEM overruns); the TPU compiler is installed here and
 compiles for a chip that is described, not attached.  Shapes are the
 flagship's (SIFT-like 1M x 128, IVF-PQ 4096 lists x cap 256, pq_dim 64
-at 8 bits, nq 5000 x 72 probes, k = kt = 10).
+at 8 bits, nq 5000 x 72 probes, k = kt = 10), and one shard of the routed
+index over the described 2x2 mesh (4,096 lists placed by owner with two
+copies: 2,049 local slots of cap 416, k 20 re-ranked to 10 on the shard).
 
 The topology is described inside a module fixture, never at import: only
 one process may load libtpu, and every xdist worker imports this file.
@@ -16,9 +18,12 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from raft_tpu.distance.types import DistanceType
+from raft_tpu.distributed import ann
 from raft_tpu.neighbors import grouped
 from raft_tpu.ops import cagra_hop_pallas as chp
 from raft_tpu.ops.fused_l2_nn_pallas import fused_l2_nn_pallas
@@ -30,6 +35,8 @@ NQ, N_PROBES, N_LISTS, CAP, ROT, K, KT = 5000, 72, 4096, 256, 128, 10, 10
 PQ_DIM, PQ_BITS, BOOK = 64, 8, 256
 N_DB, DIM, N_CLUSTERS = 1_000_000, 128, 1024
 HOP_NQ, HOP_WD, HOP_PDIM = 64, 32, 32
+# one shard of the routed r = 2 index over four chips
+R_SLOTS, R_CAP, R_KR, R_K, N_ROWS = 2 * N_LISTS // 4 + 1, 416, 20, 10, N_DB
 
 
 @pytest.fixture(scope="module")
@@ -181,3 +188,71 @@ def test_fused_hop(spec, itopk, variant):
         spec((HOP_NQ, HOP_WD), jnp.float32), spec((HOP_NQ, HOP_WD), jnp.int32),
         spec((HOP_NQ, itopk), jnp.float32), spec((HOP_NQ, itopk), jnp.int32),
         spec((HOP_NQ, itopk), jnp.bool_))
+
+
+def _routed_head(spec):
+    ng, _ = grouped.group_capacity(NQ, N_PROBES, R_SLOTS)
+    return ng, (spec((ng,), jnp.int32), spec((ng, grouped.GROUP), jnp.int32),
+                spec((NQ, ROT), jnp.float32),
+                spec((R_SLOTS, ROT), jnp.float32))
+
+
+@pytest.mark.parametrize("kernel", ["codes", "recon"])
+def test_fused_scan_at_routed_shard_shapes(spec, kernel):
+    """The kernels one shard of the routed cell runs: its 2,049 local
+    slots, the capacity sized from every pair of the batch (4,862 grid
+    steps), k = kt = 20 ahead of the re-rank."""
+    ng, head = _routed_head(spec)
+    assert ng == 4862
+    if kernel == "codes":
+        mw = pcs.fused_codes_merge_window(R_CAP, ROT, R_KR, R_KR, NQ, PQ_DIM,
+                                          PQ_BITS)
+        fn = functools.partial(pcs.grouped_code_scan_fused, kt=R_KR, k=R_KR,
+                               n_probes=N_PROBES, pq_bits=PQ_BITS,
+                               merge_window=mw)
+        data = (spec((R_SLOTS, pcs.code_lane_words(PQ_DIM, PQ_BITS), R_CAP),
+                     jnp.int32),
+                spec((PQ_DIM, BOOK, ROT // PQ_DIM), jnp.float32))
+    else:
+        mw = pgs.fused_merge_window(R_CAP, ROT, R_KR, R_KR, NQ)
+        fn = functools.partial(pgs.grouped_l2_scan_fused, kt=R_KR, k=R_KR,
+                               n_probes=N_PROBES, merge_window=mw)
+        data = (spec((R_SLOTS, R_CAP, ROT), jnp.bfloat16),)
+    assert mw > 0
+    _assert_kernel(fn, *head, *data, spec((R_SLOTS, R_CAP), jnp.float32),
+                   spec((R_SLOTS, R_CAP), jnp.int32))
+
+
+def test_routed_refined_search_program(topo, spec):
+    """The whole routed shard program over the described 2x2 mesh: the
+    coarse select, the fused code scan at k 20, the re-rank against the
+    rows the shard holds, the all_gather of candidates and the psum of
+    the exact distances (``spec`` keeps the compile cache off)."""
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices), ("data",))
+
+    def on(shape, dtype, sharded=True):
+        axes = ("data",) + (None,) * (len(shape) - 1) if sharded else ()
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(
+            mesh, PartitionSpec(*axes)))
+    n_dev = len(topo.devices)
+    ng, _ = grouped.group_capacity(NQ, N_PROBES, R_SLOTS)
+    sharded = (on((n_dev, R_SLOTS, ROT), jnp.float32),
+               on((n_dev, R_SLOTS, pcs.code_lane_words(PQ_DIM, PQ_BITS),
+                   R_CAP), jnp.int32),
+               on((n_dev, R_SLOTS, R_CAP), jnp.float32),
+               on((n_dev, R_SLOTS, R_CAP), jnp.int32),
+               on((n_dev, R_SLOTS, R_CAP, DIM), jnp.float32),
+               on((n_dev, N_ROWS), jnp.int32))
+    replicated = (on((N_LISTS, ROT), jnp.float32, False),
+                  on((DIM, ROT), jnp.float32, False),
+                  on((N_LISTS,), jnp.int32, False),
+                  on((N_LISTS,), jnp.int32, False),
+                  on((PQ_DIM, BOOK, ROT // PQ_DIM), jnp.float32, False))
+    mw = pcs.fused_codes_merge_window(R_CAP, ROT, R_KR, R_KR, NQ, PQ_DIM,
+                                      PQ_BITS)
+    text = ann._dist_search_routed_grouped.lower(
+        sharded, replicated, on((NQ, DIM), jnp.float32, False), R_KR, R_KR,
+        N_PROBES, DistanceType.L2Expanded, "data", mesh, ng, "fused_codes",
+        pq_bits=PQ_BITS, merge_window=mw, refine_to=R_K).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" in text and "all-reduce" in text
