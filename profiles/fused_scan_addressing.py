@@ -2,10 +2,14 @@
 
     python3 profiles/fused_scan_addressing.py [--nq 64,640,2560,5000]
         [--groups 6909] [--cap 416] [--reps 10] [--cell-groups 0]
+        [--live 0] [--kernel recon|codes]
 
 Runs ``pq_group_scan_pallas.grouped_l2_scan_fused`` of the checkout it
 is started from at the SIFT-1M IVF-PQ cell's kernel shape (4,096 lists
 of ``--cap`` rows, rot 128, k = kt = 20, 72 probes) on synthetic lists,
+or with ``--kernel codes`` ``pq_code_scan_pallas.grouped_code_scan_fused``
+over lane-packed codes (``pq_dim`` 64 at 8 bits), the kernel the routed
+cell's shards run (one shard: ``--lists 2049 --groups 4862``),
 and prints one JSON line per (nq, merge window): milliseconds per call,
 microseconds per grid step, and a digest of the answers (values at every
 rank, ids at every live rank, in query-major order) so two checkouts can
@@ -19,8 +23,16 @@ search dispatches instead (``grouped.group_capacity`` groups built from
 random probes).  Where the search's automatic merge window is not 1 at a
 shape, that window is timed too.
 
+``--live N`` also times each nq of at least 128 at the same ``--groups``
+grid with only its first N groups live and the rest an all-empty tail,
+laid out as ``grouped.build_groups`` lays it (the tail's slots empty,
+its list the last list), and prints the microseconds per live step (from
+the all-live call) and per empty step (what the tail adds over N live
+steps, divided by the tail's length), with the digest of that call.
+
 ``--cell-groups N`` builds the cell's index from ``benchmark/data.py``
-(seed 1), cuts N batches of 5,000 queries from the pool and prints how
+(seed 1), cuts up to N batches of 5,000 queries from the pool (10,000
+rows: two batches) and prints how
 many pair groups each needs (``grouped.num_groups``) against the static
 capacity the search dispatches at.
 
@@ -46,20 +58,52 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from raft_tpu.neighbors import grouped  # noqa: E402
+from raft_tpu.ops import pq_code_scan_pallas as pcs  # noqa: E402
 from raft_tpu.ops import pq_group_scan_pallas as pgs  # noqa: E402
 
 N_PROBES, ROT, K = 72, 128, 20
+PQ_DIM, PQ_BITS = 64, 8
 
 
-def lists(key, n_lists, cap):
+def lists(key, n_lists, cap, kernel):
+    """(centers, list data..., norms, ids): the bf16 reconstructions, or
+    the lane-packed codes and their codebooks."""
     kr, kc, ki = jax.random.split(key, 3)
-    recon = jax.random.normal(kr, (n_lists, cap, ROT), jnp.bfloat16)
-    rsq = jnp.sum(recon.astype(jnp.float32) ** 2, axis=-1)
+    if kernel == "codes":
+        codes = jax.random.randint(kr, (n_lists, cap, PQ_DIM), 0,
+                                   1 << PQ_BITS).astype(jnp.uint8)
+        books = jax.random.normal(kc, (PQ_DIM, 1 << PQ_BITS,
+                                       ROT // PQ_DIM), jnp.float32)
+        data = (pcs.pack_code_lanes(codes), books)
+        # each row's norm is the sum of its codewords' norms
+        norms = jnp.sum(books ** 2, axis=-1)           # (pq_dim, book)
+        rsq = jnp.sum(norms[jnp.arange(PQ_DIM), codes.astype(jnp.int32)],
+                      axis=-1)
+    else:
+        recon = jax.random.normal(kr, (n_lists, cap, ROT), jnp.bfloat16)
+        data = (recon,)
+        rsq = jnp.sum(recon.astype(jnp.float32) ** 2, axis=-1)
     ids = jax.random.randint(ki, (n_lists, cap), 0, 1 << 20, jnp.int32)
     # the allocator pads every list: the last eighth of each is empty
     ids = jnp.where(jnp.arange(cap)[None, :] >= cap - cap // 8, -1, ids)
     centers = jax.random.normal(kc, (n_lists, ROT), jnp.float32)
-    return centers, recon, rsq, ids
+    return (centers, *data, rsq, ids)
+
+
+def scan_fn(kernel, cap, nq, interpret):
+    """The fused scan at merge windows {1, the search's own}: a list of
+    (window, auto window, jitted call)."""
+    if kernel == "codes":
+        auto = pcs.fused_codes_merge_window(cap, ROT, K, K, nq, PQ_DIM,
+                                            PQ_BITS)
+        call = functools.partial(pcs.grouped_code_scan_fused,
+                                 pq_bits=PQ_BITS)
+    else:
+        auto = pgs.fused_merge_window(cap, ROT, K, K, nq)
+        call = pgs.grouped_l2_scan_fused
+    return [(w, auto, jax.jit(functools.partial(
+        call, kt=K, k=K, n_probes=N_PROBES, merge_window=w,
+        interpret=interpret))) for w in sorted({1, auto} - {0})]
 
 
 def full_groups(nq, n_groups, n_lists):
@@ -71,6 +115,14 @@ def full_groups(nq, n_groups, n_lists):
     slots = (q * N_PROBES + g % N_PROBES).astype(np.int32)
     return (jnp.asarray((np.arange(n_groups) % n_lists).astype(np.int32)),
             jnp.asarray(slots))
+
+
+def tail_groups(gl, sp, n_live, nq, n_lists):
+    """The first ``n_live`` groups of ``(gl, sp)``; the rest empty slots
+    of the last list, as ``grouped.build_groups`` pads its tail."""
+    live = jnp.arange(gl.shape[0]) < n_live
+    return (jnp.where(live, gl, n_lists - 1),
+            jnp.where(live[:, None], sp, nq * N_PROBES))
 
 
 def probe_groups(rng, nq, n_lists):
@@ -112,7 +164,7 @@ def cell_groups(n_batches):
     index = ivf_pq.build(res, ivf_pq.IndexParams(**conf["index"]["build"]),
                          db)
     n_probes = conf["index"]["search"]["n_probes"]
-    for b in range(n_batches):
+    for b in range(min(n_batches, pool.shape[0] // 5000)):
         q = pool[b * 5000:(b + 1) * 5000]
         probes = ivf_pq._select_clusters(index.centers, index.rotation, q,
                                          n_probes, index.metric)
@@ -133,6 +185,8 @@ def main():
     ap.add_argument("--lists", type=int, default=4096)
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--cell-groups", type=int, default=0)
+    ap.add_argument("--live", type=int, default=0)
+    ap.add_argument("--kernel", choices=("recon", "codes"), default="recon")
     ap.add_argument("--tiny", action="store_true")
     a = ap.parse_args()
     if a.tiny:
@@ -141,7 +195,7 @@ def main():
     interpret = dev.platform != "tpu"
     if interpret and not a.tiny:
         raise SystemExit("needs a TPU (or --tiny to rehearse)")
-    centers, recon, rsq, ids = lists(jax.random.PRNGKey(0), a.lists, a.cap)
+    data = lists(jax.random.PRNGKey(0), a.lists, a.cap, a.kernel)
     rng = np.random.default_rng(0)
     for nq in (int(x) for x in a.nq.split(",")):
         if nq >= grouped.GROUP:
@@ -150,20 +204,32 @@ def main():
             gl, sp = probe_groups(rng, nq, a.lists)
         qrot = jax.random.normal(jax.random.PRNGKey(nq), (nq, ROT),
                                  jnp.float32)
-        auto = pgs.fused_merge_window(a.cap, ROT, K, K, nq)
-        for w in sorted({1, auto} - {0}):
-            fn = jax.jit(functools.partial(
-                pgs.grouped_l2_scan_fused, kt=K, k=K, n_probes=N_PROBES,
-                merge_window=w, interpret=interpret))
-            args = (gl, sp, qrot, centers, recon, rsq, ids)
+        for w, auto, fn in scan_fn(a.kernel, a.cap, nq, interpret):
+            args = (gl, sp, qrot, *data)
             sec, (v, i) = time_call(fn, args, a.reps)
             ng = int(gl.shape[0])
             print(json.dumps({
-                "nq": nq, "n_groups": ng, "merge_window": w,
+                "kernel": a.kernel, "nq": nq, "n_groups": ng,
+                "merge_window": w,
                 "auto_window": auto, "ms_per_call": sec * 1e3,
                 "us_per_step": sec * 1e6 / ng,
                 "digest": digest(v, i, nq),
                 "device": dev.device_kind}), flush=True)
+            if a.live and nq >= grouped.GROUP and w == 1:
+                n_live = min(a.live, ng)
+                lsec, (v, i) = time_call(
+                    fn, (*tail_groups(gl, sp, n_live, nq, a.lists),
+                         *args[2:]), a.reps)
+                live_us = sec * 1e6 / ng
+                print(json.dumps({
+                    "kernel": a.kernel, "nq": nq, "n_groups": ng,
+                    "live": n_live,
+                    "ms_per_call": lsec * 1e3, "us_per_live_step": live_us,
+                    "us_per_empty_step": (
+                        (lsec * 1e6 - n_live * live_us)
+                        / max(ng - n_live, 1)),
+                    "digest": digest(v, i, nq),
+                    "device": dev.device_kind}), flush=True)
     if a.cell_groups:
         cell_groups(a.cell_groups)
 

@@ -196,6 +196,24 @@ def _note_fused_fallback(reason: str = "backend") -> None:
                          trace_id=rec.trace_id if rec else None)
 
 
+def _note_routed_groups(sizes, needed, failed) -> None:
+    """Tick ``distributed.routed.groups_dispatched`` / ``.groups_skipped``
+    for routed fused dispatches at the group counts ``sizes``, summed
+    over the live shards from the gathered per-shard ``needed`` groups:
+    each shard's fused scan skips the steps past its ``min(needed, n)``
+    live groups.  Reads ``needed`` only while collection is on."""
+    from raft_tpu import observability as obs
+    if not obs.enabled():
+        return
+    live = np.delete(np.asarray(needed), list(failed))
+    reg = obs.registry()
+    for n in sizes:
+        reg.counter("distributed.routed.groups_dispatched").inc(
+            n * live.size)
+        reg.counter("distributed.routed.groups_skipped").inc(
+            int(np.sum(n - np.minimum(live, n))))
+
+
 def _resolve_scan_mode(params, index, nq: int, n_probes: int,
                        k: int) -> _ScanResolution:
     """Resolve ``params.scan_mode`` to the distributed formulation that
@@ -1182,6 +1200,7 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
 
                 d, i, scanned, needed, phist, admitted = _dispatch(
                     lambda: dispatch(r.n_groups), retry_policy, deadline)
+                sizes = [r.n_groups]
                 if not r.exact:
                     # calibrated-capacity regime: the ONE deliberate host
                     # read of the routed path, AFTER the dispatch so it
@@ -1201,6 +1220,9 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
                             calibrated_groups=r.n_groups, worst=worst)
                         (d, i, scanned, needed, phist,
                          admitted) = dispatch(worst)
+                        sizes.append(worst)
+                if r.form in ("fused_codes", "fused_recon"):
+                    _note_routed_groups(sizes, needed, residual)
         elif r.form == "probe_recon":
             leaves = (index.centers, index.list_indices, index.rotation,
                       index.list_recon)

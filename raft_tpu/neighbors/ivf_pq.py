@@ -1805,9 +1805,10 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
         needed_dev = (None if exact
                       else grouped.num_groups(probes, index.n_lists))
 
-        def run_grouped(stage_label, dispatch):
+        def run_grouped(stage_label, dispatch, fused=False):
             with obs.stage(stage_label) as st:
                 out = dispatch(n_groups)
+                sizes = [n_groups]
                 overflow = False
                 if needed_dev is not None:
                     with _tracing.annotation("ivf_pq.search.group_sync"):
@@ -1822,7 +1823,12 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
                     worst, _ = grouped.group_capacity(
                         nq, n_probes, index.n_lists)
                     out = dispatch(worst)
+                    sizes.append(worst)
                 st.fence(out)
+            if fused and obs.enabled():
+                _note_skipped_groups(sizes, needed_dev if needed_dev
+                                     is not None else grouped.num_groups(
+                                         probes, index.n_lists))
             return out
 
         if mode == "codes":
@@ -1847,7 +1853,7 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
                             index.list_indices, index.rotation, queries,
                             probes, k, kt, index.metric, ng,
                             index.pq_bits, merge_window=mw,
-                            filter_words=fw))
+                            filter_words=fw), fused=True)
                 note_fused_fallback(pcs.fused_codes_reject_reason(
                     True, True, cap, rot, kt, k, nq, index.pq_dim,
                     index.pq_bits, merge_window=mw_req)
@@ -1898,7 +1904,7 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
                         index.list_recon_sq, index.list_indices,
                         index.rotation, queries, probes, k, kt,
                         index.metric, ng, merge_window=mw,
-                        filter_words=fw))
+                        filter_words=fw), fused=True)
             note_fused_fallback(
                 "backend" if not use_pallas else
                 pqp.fused_reject_reason(index.metric in _L2_METRICS, cap,
@@ -1919,6 +1925,20 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
                 filter_words=fw)
 
         return run_grouped("ivf_pq.search.scan", dispatch)
+
+
+def _note_skipped_groups(sizes, needed) -> None:
+    """Tick the fused scan's grid counters for dispatches at the group
+    counts ``sizes``, given the batch's ``needed`` groups (a device
+    scalar, read here: collection is on, so the stage fences already
+    synced).  The kernel skips every step past the live groups, of which
+    a dispatch at ``n`` holds ``min(needed, n)``."""
+    needed = int(needed)
+    reg = obs.registry()
+    for n in sizes:
+        reg.counter("ivf_pq.search.groups_dispatched").inc(n)
+        reg.counter("ivf_pq.search.groups_skipped").inc(
+            n - min(needed, n))
 
 
 def calibrate_group_capacity(res, index: Index, queries,

@@ -232,14 +232,15 @@ def _kernel_recon8(gl_ref, slot_ref, qrot_ref, cf_ref, data_ref, scale_ref,
              packed, cap_bits, adm=adm)
 
 
-def _kernel_codes_fused(gl_ref, qid_ref, q_hbm, cf_ref, codes_ref, cb_ref,
-                        rsq_ref, ids_ref, *rest, kt, k, pq_dim, pq_bits,
-                        n_groups, has_adm=False):
+def _kernel_codes_fused(gl_ref, nlive_ref, qid_ref, q_hbm, cf_ref,
+                        codes_ref, cb_ref, rsq_ref, ids_ref, *rest, kt, k,
+                        pq_dim, pq_bits, n_groups, has_adm=False):
     """Fused compact-code scan: the ``_kernel_codes`` decode + distance
     block feeding the row-addressed per-query merge of
     ``pq_group_scan_pallas._fused_merge`` instead of per-pair output
     rows — candidates never reach HBM; the final query-major answers
-    are copied out once, on the last grid step."""
+    are copied out once, on the last grid step.  Steps past the batch's
+    live groups (``nlive_ref``) scan nothing."""
     adm_ref, rest = (rest[0], rest[1:]) if has_adm else (None, rest)
     (vals_hbm, ids_hbm, qtab, acc_v, acc_i, qrows, rows_v, rows_i, mer_v,
      mer_i) = rest
@@ -250,20 +251,22 @@ def _kernel_codes_fused(gl_ref, qid_ref, q_hbm, cf_ref, codes_ref, cb_ref,
         _fused_init(q_hbm, qtab, acc_v, acc_i, qrows, rows_v, rows_i,
                     mer_v, mer_i)
 
-    _copy_slot_rows(qid_ref, ((qtab, qrows),), gather=True)
-    sub = qrows[...] - cf_ref[0, 0][None, :]             # (G, rot_pad) f32
-    sub_sq = jnp.sum(sub * sub, axis=1)                  # (G,)
-    cap = codes_ref.shape[2]
-    reconT = _decode_reconT(codes_ref, cb_ref, pq_dim, pq_bits,
-                            qrows.shape[1], cap)         # (rot_pad, cap)
-    ip = jax.lax.dot_general(sub.astype(jnp.bfloat16), reconT,
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    d = sub_sq[:, None] + rsq_ref[0, 0][None, :] - 2.0 * ip
-    d = jnp.maximum(d, 0.0)
-    adm = _unpack_admission(adm_ref, cap) if has_adm else None
-    _fused_merge(qid_ref, d, ids_ref[0, 0], kt, k, acc_v, acc_i, rows_v,
-                 rows_i, mer_v, mer_i, adm=adm)
+    @pl.when(g < nlive_ref[0])
+    def _scan():
+        _copy_slot_rows(qid_ref, ((qtab, qrows),), gather=True)
+        sub = qrows[...] - cf_ref[0, 0][None, :]         # (G, rot_pad) f32
+        sub_sq = jnp.sum(sub * sub, axis=1)              # (G,)
+        cap = codes_ref.shape[2]
+        reconT = _decode_reconT(codes_ref, cb_ref, pq_dim, pq_bits,
+                                qrows.shape[1], cap)     # (rot_pad, cap)
+        ip = jax.lax.dot_general(sub.astype(jnp.bfloat16), reconT,
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        d = sub_sq[:, None] + rsq_ref[0, 0][None, :] - 2.0 * ip
+        d = jnp.maximum(d, 0.0)
+        adm = _unpack_admission(adm_ref, cap) if has_adm else None
+        _fused_merge(qid_ref, d, ids_ref[0, 0], kt, k, acc_v, acc_i,
+                     rows_v, rows_i, mer_v, mer_i, adm=adm)
 
     @pl.when(g == n_groups - 1)
     def _flush():

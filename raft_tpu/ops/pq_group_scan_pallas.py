@@ -44,6 +44,7 @@ about one live slot each (profiles/fused_scan_addressing.py).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -242,12 +243,15 @@ def _extract_topk(d, ids_row, vals_ref, ids_out_ref, vscratch, pscratch,
 # sublane-stacked (rows, GROUP) layout, and writes the accumulator rows
 # back.  A group's real slots come first (``grouped.build_groups`` fills
 # slots in rank order), so the copies stop at the live count, rounded up
-# to the copy loop's unroll: an all-empty tail group copies nothing, and
-# the lanes past the count merge stale rows that are never written
-# back.  Every real slot of a group holds a DISTINCT query (a group is
-# one list; a query probes each list at most once), so the write-back
-# touches each real row once; empty slots inside the live range read and
-# write the padding row, which is never returned.  Row copies are exact, so neither the query table nor the
+# to the copy loop's unroll, and the lanes past the count merge stale
+# rows that are never written back.  The live groups come first too, so
+# the steps past the batch's live group count (:func:`live_groups`) do
+# no work at all and fetch nothing: the static grid's all-empty tail
+# costs a scalar compare per step.  Every real slot of a group holds a
+# DISTINCT query (a group is one list; a query probes each list at most
+# once), so the write-back touches each real row once; empty slots
+# inside the live range read and write the padding row, which is never
+# returned.  Row copies are exact, so neither the query table nor the
 # accumulator needs the three-part split the one-hot contractions of the
 # non-fused query gather take, and no per-step array has an nq_pad
 # dimension.
@@ -407,11 +411,12 @@ def _fused_merge(qid_ref, d, ids_row, kt, k, acc_v, acc_i, rows_v, rows_i,
                     gather=False)
 
 
-def _kernel_fused(gl_ref, qid_ref, q_hbm, cf_ref, data_ref, rsq_ref,
-                  ids_ref, *rest, kt, k, n_groups, has_adm=False):
+def _kernel_fused(gl_ref, nlive_ref, qid_ref, q_hbm, cf_ref, data_ref,
+                  rsq_ref, ids_ref, *rest, kt, k, n_groups, has_adm=False):
     """Fused recon scan: the non-fused ``_kernel`` distance block plus
     the in-kernel per-query merge; the final query-major answers are
-    copied to HBM once, on the last grid step."""
+    copied to HBM once, on the last grid step.  Steps past the batch's
+    live groups (``nlive_ref``) scan nothing."""
     adm_ref, rest = (rest[0], rest[1:]) if has_adm else (None, rest)
     (vals_hbm, ids_hbm, qtab, acc_v, acc_i, qrows, rows_v, rows_i, mer_v,
      mer_i) = rest
@@ -422,23 +427,43 @@ def _kernel_fused(gl_ref, qid_ref, q_hbm, cf_ref, data_ref, rsq_ref,
         _fused_init(q_hbm, qtab, acc_v, acc_i, qrows, rows_v, rows_i,
                     mer_v, mer_i)
 
-    _copy_slot_rows(qid_ref, ((qtab, qrows),), gather=True)
-    sub = qrows[...] - cf_ref[0, 0][None, :]           # (G, rot) f32
-    sub_sq = jnp.sum(sub * sub, axis=1)                # (G,)
-    data = data_ref[0]                                 # (cap, rot) bf16
-    ip = jax.lax.dot_general(sub.astype(jnp.bfloat16), data,
-                             (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    d = sub_sq[:, None] + rsq_ref[0, 0][None, :] - 2.0 * ip
-    d = jnp.maximum(d, 0.0)
-    adm = _unpack_admission(adm_ref, d.shape[1]) if has_adm else None
-    _fused_merge(qid_ref, d, ids_ref[0, 0], kt, k, acc_v, acc_i, rows_v,
-                 rows_i, mer_v, mer_i, adm=adm)
+    @pl.when(g < nlive_ref[0])
+    def _scan():
+        _copy_slot_rows(qid_ref, ((qtab, qrows),), gather=True)
+        sub = qrows[...] - cf_ref[0, 0][None, :]       # (G, rot) f32
+        sub_sq = jnp.sum(sub * sub, axis=1)            # (G,)
+        data = data_ref[0]                             # (cap, rot) bf16
+        ip = jax.lax.dot_general(sub.astype(jnp.bfloat16), data,
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        d = sub_sq[:, None] + rsq_ref[0, 0][None, :] - 2.0 * ip
+        d = jnp.maximum(d, 0.0)
+        adm = _unpack_admission(adm_ref, d.shape[1]) if has_adm else None
+        _fused_merge(qid_ref, d, ids_ref[0, 0], kt, k, acc_v, acc_i,
+                     rows_v, rows_i, mer_v, mer_i, adm=adm)
 
     @pl.when(g == n_groups - 1)
     def _flush():
         pltpu.sync_copy(acc_v, vals_hbm)
         pltpu.sync_copy(acc_i, ids_hbm)
+
+
+def live_groups(qid_rows):
+    """(1,) int32: one past the last group of :func:`slot_query_rows`'s
+    output that holds a real slot.  ``grouped.build_groups`` lays the
+    live groups first, so this is the number of live groups, and every
+    group from it on is an all-empty tail group."""
+    live = qid_rows[:, 1, 0] > 0
+    n = jnp.arange(1, live.shape[0] + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(live, n, 0), keepdims=True)
+
+
+def _at_live(index_map):
+    """An index map of (step, group_list) taken at the last live step for
+    every tail step: a tail step's blocks are the ones already in VMEM,
+    so it fetches nothing."""
+    return lambda g, gl, n_live: index_map(
+        jnp.minimum(g, jnp.maximum(n_live[0] - 1, 0)), gl)
 
 
 def fused_scan_call(kernel, *, group_list, slot_pairs, n_probes, P,
@@ -449,23 +474,31 @@ def fused_scan_call(kernel, *, group_list, slot_pairs, n_probes, P,
     (:func:`slot_query_rows`) stream into SMEM, the query table and both
     outputs stay in HBM (the kernel copies them itself), and
     ``stream_specs`` / ``stream_inputs`` are the kernel's per-list
-    blocks.  Returns the final ``(vals (nq_pad, k) f32, ids (nq_pad, k)
-    int32)``.  Call from the jitted scan function: the Pallas call's
-    instruction is named after it."""
+    blocks, their index maps taking ``(step, group_list)``.  The live
+    group count (:func:`live_groups`) is the second scalar-prefetch
+    operand: the kernel skips the steps past it, and every block index
+    stays at the last live step's, so the all-empty tail of the static
+    grid neither computes nor fetches.  Returns the final ``(vals
+    (nq_pad, k) f32, ids (nq_pad, k) int32)``.  Call from the jitted
+    scan function: the Pallas call's instruction is named after it."""
     n_groups = group_list.shape[0]
     nq_pad, width = q_table.shape
     kl = vb.acc_lanes(k)
+    qid_rows = slot_query_rows(slot_pairs, n_probes, P, nq_pad)
     in_specs = [pl.BlockSpec((1, 2, GROUP), lambda g, gl: (g, 0, 0),
                              memory_space=pltpu.SMEM),
                 pl.BlockSpec(memory_space=pl.ANY), *stream_specs]
-    inputs = [group_list, slot_query_rows(slot_pairs, n_probes, P, nq_pad),
-              q_table, *stream_inputs]
+    inputs = [group_list, live_groups(qid_rows), qid_rows, q_table,
+              *stream_inputs]
     if adm_words is not None:
         in_specs.append(pl.BlockSpec((1, GROUP, adm_words.shape[2]),
                                      lambda g, gl: (g, 0, 0)))
         inputs.append(adm_words)
+    in_specs = [s if s.index_map is None
+                else dataclasses.replace(s, index_map=_at_live(s.index_map))
+                for s in in_specs]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(n_groups,),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec(memory_space=pl.ANY),

@@ -7,9 +7,12 @@ distances match the exact distances of the returned ids within 5e-5
 relative (a bf16 re-rank errs by about 1e-2).
 """
 
+import functools
 import io
+import types
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -18,6 +21,7 @@ from raft_tpu.comms import CommsSession
 from raft_tpu.core.error import LogicError
 from raft_tpu.distributed import ann
 from raft_tpu.distributed.routing import RoutingPolicy
+from raft_tpu.neighbors import grouped
 from raft_tpu.neighbors import ivf_pq
 from raft_tpu.neighbors.refine import refine
 
@@ -236,3 +240,43 @@ def test_refined_rows_counter_and_spans(handle, data, built, monkeypatch):
         obs.disable()
     assert after - before == NQ * K * RATIO
     assert names == ["distributed.route", "distributed.dispatch"]
+
+
+def test_routed_groups_skipped_counter(handle, data, built, monkeypatch):
+    """A routed fused search ticks ``distributed.routed.groups_skipped``
+    by each shard's all-empty tail (its static grid less the groups its
+    owned probes need), summed over the shards, beside
+    ``groups_dispatched``.  The fused form is forced on the CPU, its
+    kernels in interpret mode."""
+    _, q = data
+    routed = built[1]
+    monkeypatch.setattr(ann, "_platform",
+                        types.SimpleNamespace(on_tpu=lambda: True))
+    for name in ("_search_impl_fused_recon_grouped",
+                 "_search_impl_fused_codes_grouped"):
+        monkeypatch.setattr(ivf_pq, name, functools.partial(
+            getattr(ivf_pq, name), pallas_interpret=True))
+    sp = ivf_pq.SearchParams(n_probes=N_PROBES, scan_mode="fused")
+    form = ann._resolve_scan_mode(sp, routed, NQ, N_PROBES, K * RATIO).form
+    assert form in ("fused_codes", "fused_recon")
+    names = ("distributed.routed.groups_dispatched",
+             "distributed.routed.groups_skipped")
+    obs.enable()
+    try:
+        reg = obs.registry()
+        before = [reg.counter(n).value for n in names]
+        ann.search(handle, sp, routed, q, K, refine_ratio=RATIO)
+        after = [reg.counter(n).value for n in names]
+    finally:
+        obs.disable()
+    slots = routed.local_centers.shape[1]
+    n_groups, _ = grouped.group_capacity(NQ, N_PROBES, slots)
+    probes = ivf_pq._select_clusters(routed.coarse_centers, routed.rotation,
+                                     q, N_PROBES, routed.metric)
+    owner = np.asarray(routed.owner)[np.asarray(probes)]
+    local = np.asarray(routed.local_slot)[np.asarray(probes)]
+    needed = [int(grouped.num_groups(
+        jnp.asarray(np.where(owner == s, local, slots), jnp.int32), slots))
+        for s in range(N_DEV)]
+    assert after[0] - before[0] == N_DEV * n_groups
+    assert after[1] - before[1] == sum(n_groups - n for n in needed) > 0

@@ -73,6 +73,29 @@ def _assert_kernel(fn, *args):
     assert "tpu_custom_call" in text
 
 
+def _prefetch_counts(jaxpr):
+    """Scalar-prefetch operand count of every pallas_call in ``jaxpr``
+    and the jaxprs it calls."""
+    counts = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts.append(eqn.params["grid_mapping"].num_index_operands)
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                counts += _prefetch_counts(sub)
+    return counts
+
+
+def _assert_fused_kernel(fn, *args):
+    """A fused scan compiles for the chip with its two scalar-prefetch
+    operands, the group list and the live group count that its steps
+    past the live groups skip by."""
+    traced = jax.jit(fn).trace(*args)
+    assert _prefetch_counts(traced.jaxpr.jaxpr) == [2]
+    assert "tpu_custom_call" in traced.lower().compile().as_text()
+
+
 def _groups(spec, nq=NQ):
     ng, _ = grouped.group_capacity(nq, N_PROBES, N_LISTS)
     return ng, (spec((ng,), jnp.int32), spec((ng, grouped.GROUP), jnp.int32),
@@ -99,7 +122,7 @@ def test_fused_recon_scan(spec, with_adm, nq, k, kt):
     assert mw > 0
     fn = functools.partial(pgs.grouped_l2_scan_fused, kt=kt, k=k,
                            n_probes=N_PROBES, merge_window=mw)
-    _assert_kernel(lambda *a: fn(*a[:7], adm_words=a[7]), *head,
+    _assert_fused_kernel(lambda *a: fn(*a[:7], adm_words=a[7]), *head,
                    spec((N_LISTS, CAP, ROT), jnp.bfloat16),
                    spec((N_LISTS, CAP), jnp.float32),
                    spec((N_LISTS, CAP), jnp.int32), _adm(spec, ng, with_adm))
@@ -114,7 +137,7 @@ def test_fused_code_scan(spec, with_adm, nq, k, kt):
     fn = functools.partial(pcs.grouped_code_scan_fused, kt=kt, k=k,
                            n_probes=N_PROBES, pq_bits=PQ_BITS,
                            merge_window=mw)
-    _assert_kernel(lambda *a: fn(*a[:8], adm_words=a[8]), *head,
+    _assert_fused_kernel(lambda *a: fn(*a[:8], adm_words=a[8]), *head,
                    spec((N_LISTS, pcs.code_lane_words(PQ_DIM, PQ_BITS), CAP),
                         jnp.int32),
                    spec((PQ_DIM, BOOK, ROT // PQ_DIM), jnp.float32),
@@ -219,8 +242,9 @@ def test_fused_scan_at_routed_shard_shapes(spec, kernel):
                                n_probes=N_PROBES, merge_window=mw)
         data = (spec((R_SLOTS, R_CAP, ROT), jnp.bfloat16),)
     assert mw > 0
-    _assert_kernel(fn, *head, *data, spec((R_SLOTS, R_CAP), jnp.float32),
-                   spec((R_SLOTS, R_CAP), jnp.int32))
+    _assert_fused_kernel(fn, *head, *data,
+                         spec((R_SLOTS, R_CAP), jnp.float32),
+                         spec((R_SLOTS, R_CAP), jnp.int32))
 
 
 def test_routed_refined_search_program(topo, spec):
