@@ -98,8 +98,7 @@ PQ_DIM = 64
 # per_probe_topk narrows the extraction-bound kernels' per-pair keep-set
 # (PERFORMANCE.md round 5: ~3.3 us/kept-candidate/group, flat in list
 # size — with refine_ratio>=2 the refine pass re-ranks exactly, so small
-# kt trades little recall for a near-linear scan speedup), and
-# packed_extract halves the extraction's cross-lane reduces.
+# kt trades little recall for a near-linear scan speedup).
 OPERATING_POINTS = (
     # recon-cache baseline (round-5 continuity)
     dict(n_probes=32, refine_ratio=1),
@@ -115,33 +114,18 @@ OPERATING_POINTS = (
     dict(n_probes=72, refine_ratio=2, scan_mode="codes"),
     dict(n_probes=72, refine_ratio=2, scan_mode="codes", per_probe_topk=4),
     dict(n_probes=96, refine_ratio=2, scan_mode="codes", per_probe_topk=4),
-    dict(n_probes=72, refine_ratio=2, scan_mode="codes", per_probe_topk=4,
-         packed_extract=True),
-    # int8 recon cache (1 byte/dim/row)
-    dict(n_probes=72, refine_ratio=2, scan_mode="recon8"),
-    dict(n_probes=72, refine_ratio=2, scan_mode="recon8", per_probe_topk=4),
     # round-7 fused in-kernel top-k: scan + extraction in ONE stage,
-    # candidate distance matrices never reach HBM (merge_window is
-    # accepted; the scans merge every grid step)
+    # candidate distance matrices never reach HBM
     dict(n_probes=72, refine_ratio=2, scan_mode="fused"),
     dict(n_probes=72, refine_ratio=2, scan_mode="fused", per_probe_topk=4),
     dict(n_probes=96, refine_ratio=2, scan_mode="fused", per_probe_topk=4),
-    # round-14 A/B anchor: the same point pinned to the per-step merge
-    # (W=1, the round-7 behavior) — auto minus this is the windowed gain
-    dict(n_probes=72, refine_ratio=2, scan_mode="fused", per_probe_topk=4,
-         merge_window=1),
 )
 
-# Round-14 windowed fused-scan grid: (k, merge_window) at batch 1024 and
-# matched kt=16 — large k exceeds the fused VMEM budget at the flagship
-# batch, so the large-k serving bucket's batch is the operating shape.
-# merge_window 0 = "auto" (largest W the budget admits); k=128 carries an
-# explicit W=2 beside auto to expose the window axis itself, and k=128/256
-# have NO W=1 point because the per-step merge gates at k <= 64 — exactly
-# the gate the windowed engine lifts.
-FUSED_WINDOWED_GRID = (
-    (10, 1), (10, 0), (64, 1), (64, 0), (128, 2), (128, 0), (256, 0),
-)
+# Fused-scan k grid at batch 1024 and matched kt=16 — large k exceeds the
+# fused VMEM budget at the flagship batch, so the large-k serving
+# bucket's batch is the operating shape; k past 64 takes the looped
+# per-step merge.
+FUSED_WINDOWED_GRID = (10, 64, 128, 256)
 FUSED_WINDOWED_BATCH = 1024
 FUSED_WINDOWED_KT = 16
 MIN_RECALL = 0.95
@@ -255,20 +239,17 @@ def _search_stage_probe(res, index, queries) -> dict:
 
 
 def _fused_windowed_grid(res, index, queries) -> list:
-    """Round-14 grid: the fused scans across (k, merge_window) at batch
-    :data:`FUSED_WINDOWED_BATCH` and matched kt.  Results are
-    bit-identical across W (the row-addressed kernels merge every grid
-    step whatever W is), so the grid reports QPS plus the fused_fallback
-    tick delta that proves the fused kernel actually served the point."""
+    """The fused scans across k at batch :data:`FUSED_WINDOWED_BATCH` and
+    matched kt: QPS plus the fused_fallback tick delta that proves the
+    fused kernel actually served the point."""
     from raft_tpu import observability as obs
     from raft_tpu.neighbors import ivf_pq
 
     q = queries[:FUSED_WINDOWED_BATCH]
     points = []
-    for k, mw in FUSED_WINDOWED_GRID:
+    for k in FUSED_WINDOWED_GRID:
         sp = ivf_pq.SearchParams(n_probes=72, scan_mode="fused",
-                                 per_probe_topk=FUSED_WINDOWED_KT,
-                                 merge_window=mw or "auto")
+                                 per_probe_topk=FUSED_WINDOWED_KT)
         with obs.collecting() as reg:
             before = reg.snapshot()["counters"].get(
                 "ivf_pq.search.fused_fallback", 0)
@@ -282,8 +263,7 @@ def _fused_windowed_grid(res, index, queries) -> list:
             _, i = ivf_pq.search(res, sp, index, q, k)
         np.asarray(i)
         qps = q.shape[0] / ((time.perf_counter() - t0) / RUNS)
-        point = {"k": k, "merge_window": mw or "auto",
-                 "batch": int(q.shape[0]), "kt": FUSED_WINDOWED_KT,
+        point = {"k": k, "batch": int(q.shape[0]), "kt": FUSED_WINDOWED_KT,
                  "qps": round(qps, 1),
                  "fused_fallback_ticks": after - before}
         _emit({"fused_windowed_point": point})
@@ -322,9 +302,7 @@ def bench_ivf_pq(res, db, queries, gt_i=None) -> dict:
         sp = ivf_pq.SearchParams(
             n_probes=n_probes,
             scan_mode=pt.get("scan_mode", "auto"),
-            per_probe_topk=pt.get("per_probe_topk", 0),
-            packed_extract=pt.get("packed_extract", False),
-            merge_window=pt.get("merge_window", "auto"))
+            per_probe_topk=pt.get("per_probe_topk", 0))
         kk = K * refine_ratio
 
         def query():
@@ -607,9 +585,8 @@ def bench_serving(res, db, queries, *, build_param=None, search_param=None,
     enabled* (the closed bucket-shape contract; CI fails the smoke job
     otherwise).  When the conf declares a ``large_k`` bucket, that k is
     added to the executor's closed k set and replayed inside the
-    measured window: the AOT cache key carries ``merge_window`` for
-    fused large-k plans, and the zero-recompile assertion must hold
-    across that dimension too.
+    measured window, and the zero-recompile assertion must hold across
+    that k too.
     """
     import threading
 
@@ -692,8 +669,8 @@ def bench_serving(res, db, queries, *, build_param=None, search_param=None,
             with _trace.tracing_scope():
                 traced_qps = closed_loop()
             # large-k bucket replay inside the measured window: its AOT
-            # plan (keyed on merge_window for fused scans) was warmed at
-            # start(), so these must hit the cache without a compile
+            # plan was warmed at start(), so these must hit the cache
+            # without a compile
             if large_k:
                 for _ in range(4):
                     srv.search(q[:request_rows], int(large_k))
@@ -3096,8 +3073,7 @@ def run_conf(conf_path: str) -> None:
                     p = ivf_pq.SearchParams(
                         n_probes=sp["nprobe"],
                         scan_mode=sp.get("scan_mode", "auto"),
-                        per_probe_topk=sp.get("per_probe_topk", 0),
-                        packed_extract=sp.get("packed_extract", False))
+                        per_probe_topk=sp.get("per_probe_topk", 0))
                     return dist_ann.search(mg_handle, p, index, q, k)[1]
                 if algo == "bfknn":
                     return brute_force.knn(res, db, q, k, metric=metric)[1]
@@ -3110,8 +3086,7 @@ def run_conf(conf_path: str) -> None:
                     p = ivf_pq.SearchParams(
                         n_probes=sp["nprobe"],
                         scan_mode=sp.get("scan_mode", "auto"),
-                        per_probe_topk=sp.get("per_probe_topk", 0),
-                        packed_extract=sp.get("packed_extract", False))
+                        per_probe_topk=sp.get("per_probe_topk", 0))
                     i = ivf_pq.search(res, p, index, q, k * ratio)[1]
                     if ratio > 1:
                         i = refine_fn(res, db, q, i, k, metric=metric)[1]

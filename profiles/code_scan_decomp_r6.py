@@ -7,10 +7,9 @@ Two parts:
   number for the compact-code path (codes bytes/row must be < half the
   recon path's) — plus the per-batch totals implied by the measured
   group count.
-- on-chip timing (default): kernel-only A/B of recon vs codes vs recon8
-  (and the fused kernels) at matched (n_probes, kt), isolating the scan
-  from coarse select and refine; --trace captures a profiler trace of
-  all three.
+- on-chip timing (default): kernel-only A/B of recon vs codes (and the
+  fused kernels) at matched (n_probes, kt), isolating the scan from
+  coarse select and refine; --trace captures a profiler trace of both.
 
 Run on the real chip:  python profiles/code_scan_decomp_r6.py [--trace]
 Traffic model only:    python profiles/code_scan_decomp_r6.py --model
@@ -28,7 +27,7 @@ def traffic_model(cap, rot, pq_dim, pq_bits, n_groups, group=128):
     per_row = grouped.scan_traffic(rot, pq_dim, pq_bits)
     print(f"per-candidate-row HBM bytes (rot={rot}, pq_dim={pq_dim}, "
           f"pq_bits={pq_bits}):")
-    for mode in ("recon", "recon8", "codes", "fused"):
+    for mode in ("recon", "codes", "fused"):
         b = per_row[mode]
         ratio = b / per_row["recon"]
         print(f"  {mode:>7}: {b:4d} B/row  ({ratio:.2f}x recon)")
@@ -36,7 +35,7 @@ def traffic_model(cap, rot, pq_dim, pq_bits, n_groups, group=128):
         "codes bytes/row must undercut half the recon path's")
     print(f"per-batch scan totals at n_groups={n_groups}, cap={cap} "
           f"(each group streams its list's rows once):")
-    for mode in ("recon", "recon8", "codes", "fused"):
+    for mode in ("recon", "codes", "fused"):
         total = n_groups * cap * per_row[mode]
         print(f"  {mode:>7}: {total / 1e9:6.2f} GB")
     return per_row
@@ -116,10 +115,6 @@ def main():
 
     index = ivf_pq._with_recon(res, index)
     index = ivf_pq._with_code_lanes(index)
-    index = ivf_pq._with_recon8(index)
-    rot_pad = index.list_recon_i8.shape[2]
-    block8 = grouped.block_size(n_groups, G * cap * 8, cap * rot_pad * 3,
-                                G * rot_pad * 4)
 
     def run_recon(kt_):
         return ivf_pq._search_impl_recon_grouped(
@@ -127,19 +122,11 @@ def main():
             index.list_indices, index.rotation, queries, probes, k, m,
             n_groups, block, use_pallas=True, kt=kt_)[1]
 
-    def run_codes(kt_, packed=False):
+    def run_codes(kt_):
         return ivf_pq._search_impl_codes_grouped(
             index.centers, index.codebooks, index.list_code_lanes,
             index.list_code_rsq, index.list_indices, index.rotation,
-            queries, probes, k, kt_, m, n_groups, index.pq_bits,
-            packed=packed)[1]
-
-    def run_recon8(kt_, packed=False):
-        return ivf_pq._search_impl_recon8_grouped(
-            index.centers, index.list_recon_i8, index.list_recon_scale,
-            index.list_recon_i8_sq, index.list_indices, index.rotation,
-            queries, probes, k, kt_, m, n_groups, block8, use_pallas=True,
-            packed=packed)[1]
+            queries, probes, k, kt_, m, n_groups, index.pq_bits)[1]
 
     def run_fused_codes(kt_):
         return ivf_pq._search_impl_fused_codes_grouped(
@@ -158,10 +145,6 @@ def main():
         (f"recon      kt={kt} ", lambda: run_recon(kt)),
         ("codes      kt=k ", lambda: run_codes(0)),
         (f"codes      kt={kt} ", lambda: run_codes(kt)),
-        (f"codes-pk   kt={kt} ", lambda: run_codes(kt, packed=True)),
-        ("recon8     kt=k ", lambda: run_recon8(0)),
-        (f"recon8     kt={kt} ", lambda: run_recon8(kt)),
-        (f"recon8-pk  kt={kt} ", lambda: run_recon8(kt, packed=True)),
         # round-7: scan + top-k in ONE kernel, no extraction stage
         (f"fused-cod  kt={kt} ", lambda: run_fused_codes(kt)),
         (f"fused-rec  kt={kt} ", lambda: run_fused_recon(kt)),
@@ -193,7 +176,6 @@ def main():
         with jax.profiler.trace("profiles/code_scan_trace"):
             np.asarray(run_recon(kt))
             np.asarray(run_codes(kt))
-            np.asarray(run_recon8(kt))
         print("trace written to profiles/code_scan_trace")
 
 

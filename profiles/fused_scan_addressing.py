@@ -10,7 +10,7 @@ of ``--cap`` rows, rot 128, k = kt = 20, 72 probes) on synthetic lists,
 or with ``--kernel codes`` ``pq_code_scan_pallas.grouped_code_scan_fused``
 over lane-packed codes (``pq_dim`` 64 at 8 bits), the kernel the routed
 cell's shards run (one shard: ``--lists 2049 --groups 4862``),
-and prints one JSON line per (nq, merge window): milliseconds per call,
+and prints one JSON line per nq: milliseconds per call,
 microseconds per grid step, and a digest of the answers (values at every
 rank, ids at every live rank, in query-major order) so two checkouts can
 be compared for bit-identity.
@@ -20,8 +20,7 @@ holding a real query (query ids cycle below nq, distinct in a group), so
 only the batch width changes between lines.  Widths below 128 queries
 cannot fill a group with distinct queries; they run at the shape the
 search dispatches instead (``grouped.group_capacity`` groups built from
-random probes).  Where the search's automatic merge window is not 1 at a
-shape, that window is timed too.
+random probes).
 
 ``--live N`` also times each nq of at least 128 at the same ``--groups``
 grid with only its first N groups live and the rest an all-empty tail,
@@ -90,20 +89,15 @@ def lists(key, n_lists, cap, kernel):
     return (centers, *data, rsq, ids)
 
 
-def scan_fn(kernel, cap, nq, interpret):
-    """The fused scan at merge windows {1, the search's own}: a list of
-    (window, auto window, jitted call)."""
+def scan_fn(kernel, interpret):
+    """The jitted fused scan of ``kernel``."""
     if kernel == "codes":
-        auto = pcs.fused_codes_merge_window(cap, ROT, K, K, nq, PQ_DIM,
-                                            PQ_BITS)
         call = functools.partial(pcs.grouped_code_scan_fused,
                                  pq_bits=PQ_BITS)
     else:
-        auto = pgs.fused_merge_window(cap, ROT, K, K, nq)
         call = pgs.grouped_l2_scan_fused
-    return [(w, auto, jax.jit(functools.partial(
-        call, kt=K, k=K, n_probes=N_PROBES, merge_window=w,
-        interpret=interpret))) for w in sorted({1, auto} - {0})]
+    return jax.jit(functools.partial(call, kt=K, k=K, n_probes=N_PROBES,
+                                     interpret=interpret))
 
 
 def full_groups(nq, n_groups, n_lists):
@@ -196,6 +190,7 @@ def main():
     if interpret and not a.tiny:
         raise SystemExit("needs a TPU (or --tiny to rehearse)")
     data = lists(jax.random.PRNGKey(0), a.lists, a.cap, a.kernel)
+    fn = scan_fn(a.kernel, interpret)
     rng = np.random.default_rng(0)
     for nq in (int(x) for x in a.nq.split(",")):
         if nq >= grouped.GROUP:
@@ -204,32 +199,28 @@ def main():
             gl, sp = probe_groups(rng, nq, a.lists)
         qrot = jax.random.normal(jax.random.PRNGKey(nq), (nq, ROT),
                                  jnp.float32)
-        for w, auto, fn in scan_fn(a.kernel, a.cap, nq, interpret):
-            args = (gl, sp, qrot, *data)
-            sec, (v, i) = time_call(fn, args, a.reps)
-            ng = int(gl.shape[0])
+        args = (gl, sp, qrot, *data)
+        sec, (v, i) = time_call(fn, args, a.reps)
+        ng = int(gl.shape[0])
+        print(json.dumps({
+            "kernel": a.kernel, "nq": nq, "n_groups": ng,
+            "ms_per_call": sec * 1e3, "us_per_step": sec * 1e6 / ng,
+            "digest": digest(v, i, nq),
+            "device": dev.device_kind}), flush=True)
+        if a.live and nq >= grouped.GROUP:
+            n_live = min(a.live, ng)
+            lsec, (v, i) = time_call(
+                fn, (*tail_groups(gl, sp, n_live, nq, a.lists), *args[2:]),
+                a.reps)
+            live_us = sec * 1e6 / ng
             print(json.dumps({
                 "kernel": a.kernel, "nq": nq, "n_groups": ng,
-                "merge_window": w,
-                "auto_window": auto, "ms_per_call": sec * 1e3,
-                "us_per_step": sec * 1e6 / ng,
+                "live": n_live,
+                "ms_per_call": lsec * 1e3, "us_per_live_step": live_us,
+                "us_per_empty_step": (
+                    (lsec * 1e6 - n_live * live_us) / max(ng - n_live, 1)),
                 "digest": digest(v, i, nq),
                 "device": dev.device_kind}), flush=True)
-            if a.live and nq >= grouped.GROUP and w == 1:
-                n_live = min(a.live, ng)
-                lsec, (v, i) = time_call(
-                    fn, (*tail_groups(gl, sp, n_live, nq, a.lists),
-                         *args[2:]), a.reps)
-                live_us = sec * 1e6 / ng
-                print(json.dumps({
-                    "kernel": a.kernel, "nq": nq, "n_groups": ng,
-                    "live": n_live,
-                    "ms_per_call": lsec * 1e3, "us_per_live_step": live_us,
-                    "us_per_empty_step": (
-                        (lsec * 1e6 - n_live * live_us)
-                        / max(ng - n_live, 1)),
-                    "digest": digest(v, i, nq),
-                    "device": dev.device_kind}), flush=True)
     if a.cell_groups:
         cell_groups(a.cell_groups)
 

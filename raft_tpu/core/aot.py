@@ -257,7 +257,6 @@ def executables() -> ExecutableCache:
 def export_ivf_pq_search(res, index, n_probes: int, k: int, batch: int,
                          *, scan_mode: str = "recon",
                          group_capacity: int = 0,
-                         merge_window=0,
                          n_filter_words: int = 0) -> io.BytesIO:
     """Export the flagship IVF-PQ search at fixed (batch, k, n_probes)
     into a self-contained artifact (reference analogue: serialized index
@@ -286,18 +285,11 @@ def export_ivf_pq_search(res, index, n_probes: int, k: int, batch: int,
       identically while carrying its own distinct
       :class:`ExecutableCache` key component.
 
-    ``merge_window`` ("auto" | int, see
-    :data:`raft_tpu.neighbors.ivf_pq.SearchParams.merge_window`) windows
-    the baked grouped scan's staged scatter and keys the artifact in
-    :class:`ExecutableCache` — serving pre-warms one executable per
-    (bucket, k, merge_window) point, so the live Pallas dispatch and the
-    exported twin share a cache dimension.  Ignored by the non-grouped
-    exports, where there is no staged scatter to window.
+    The export makes its own representation choice: the runtime plan
+    (:func:`raft_tpu.neighbors.ivf_pq.plan_scan`) picks among kernels
+    an artifact cannot carry.
     """
     from raft_tpu.neighbors import grouped, ivf_pq
-    from raft_tpu.ops import vmem_budget as vb
-
-    merge_window = vb.merge_window_request(merge_window)
 
     expects(scan_mode in ("recon", "codes", "lut", "fused"),
             "aot: scan_mode must be 'recon', 'codes', 'lut' or 'fused'")
@@ -334,8 +326,7 @@ def export_ivf_pq_search(res, index, n_probes: int, k: int, batch: int,
                 return ivf_pq._search_impl_recon_grouped(
                     centers, list_recon, list_recon_sq, list_indices,
                     rotation, queries, probes, k, metric, n_groups,
-                    block, merge_window=merge_window,
-                    filter_words=rt[0] if nfw else None)
+                    block, filter_words=rt[0] if nfw else None)
         else:
             def fn(centers, list_recon, list_recon_sq, list_indices,
                    rotation, queries, *rt):
@@ -380,7 +371,6 @@ def export_ivf_pq_routed_search(res, index, shard: int, n_probes: int,
                                 k: int, batch: int, *,
                                 scan_mode: str = "recon",
                                 group_capacity: int = 0,
-                                merge_window=0,
                                 replica_rank: int = 0,
                                 n_filter_words: int = 0) -> io.BytesIO:
     """Export ONE shard's routed (``placement="by_list"``) search
@@ -407,10 +397,6 @@ def export_ivf_pq_routed_search(res, index, shard: int, n_probes: int,
     leaves plus the replicated routing arrays (coarse centers, rotation,
     owner, local_slot) into a single-device program instead.
 
-    ``merge_window`` windows the fused export's staged scatter exactly
-    as in :func:`export_ivf_pq_search` (and keys the artifact the same
-    way).
-
     ``replica_rank`` (a replicated placement only) bakes replica rank
     ``j``'s routing tables instead of the primaries': the exported
     program answers for the lists this shard owns *at that rank* — the
@@ -419,9 +405,6 @@ def export_ivf_pq_routed_search(res, index, shard: int, n_probes: int,
     layout is the union), so only the two routing arrays differ; the
     rank is part of the executable-cache key."""
     from raft_tpu.neighbors import grouped, ivf_pq
-    from raft_tpu.ops import vmem_budget as vb
-
-    merge_window = vb.merge_window_request(merge_window)
 
     expects(getattr(index, "placement", None) is not None,
             "aot: export_ivf_pq_routed_search needs a RoutedIndex "
@@ -465,8 +448,7 @@ def export_ivf_pq_routed_search(res, index, shard: int, n_probes: int,
             return ivf_pq._search_impl_recon_grouped(
                 local_centers, list_recon, list_recon_sq, list_indices,
                 rotation, queries, local_probes, k, metric, n_groups,
-                block, merge_window=merge_window,
-                filter_words=rt[0] if nfw else None)
+                block, filter_words=rt[0] if nfw else None)
     else:
         def fn(coarse, rotation, owner, local_slot, local_centers,
                list_recon, list_recon_sq, list_indices, queries, *rt):

@@ -43,10 +43,11 @@ fused scans lower under ``shard_map`` for both placements —
 ``scan_mode="fused"`` runs the same formulation ladder the single-index
 search picks (fused Pallas kernels on TPU, the XLA grouped twin
 elsewhere) instead of the pre-round-10 blanket lowering to the
-probe-order recon scan.  :func:`_resolve_scan_mode` is the host-side
-resolution table; :data:`SHARD_OK_FALLBACK` now marks only the genuinely
-unsupported combinations (e.g. ``recon8`` — no stacked int8 cache — or
-code modes on an index without PQ metadata).
+probe-order recon scan.  :func:`raft_tpu.neighbors.ivf_pq.plan_scan`
+decides the formulation for both placements and the single index;
+:data:`SHARD_OK_FALLBACK` marks only the genuinely unsupported
+combinations (the code modes on a routed index, which holds no packed
+codes, or on a stacked index without PQ metadata).
 """
 
 from __future__ import annotations
@@ -75,7 +76,6 @@ from raft_tpu.neighbors import mutate as _mutate
 from raft_tpu.neighbors.refine import exact_distances as _exact_distances
 from raft_tpu.observability import flight as _flight
 from raft_tpu.observability import trace as _rtrace
-from raft_tpu.ops import vmem_budget as vb
 from raft_tpu.resilience import faults
 from raft_tpu.resilience import retry as _retry
 
@@ -138,62 +138,14 @@ def _status_vector(n_shards: int, failed: Tuple[int, ...],
     return jnp.asarray(status)
 
 
-@dataclasses.dataclass(frozen=True)
-class _ScanResolution:
-    """Host-side static resolution of the shard-local scan formulation.
-
-    ``form`` is one of ``probe_recon`` (probe-order recon scan — the
-    pre-round-10 universal formulation), ``grouped_recon`` (XLA grouped
-    scan at static capacity — the same twin the single-index fused
-    ladder lands on off-TPU), ``fused_recon`` / ``fused_codes`` (the
-    Pallas fused kernels, TPU only) or ``lut`` (the traceable LUT
-    formulation, data-parallel only).  ``lowered`` marks a genuine
-    fallback (status :data:`SHARD_OK_FALLBACK`); ``n_groups`` is the
-    static group capacity for the grouped forms; ``exact`` False arms
-    the in-graph overflow count (calibrated capacity only);
-    ``use_pallas`` gates the non-fused Pallas group kernel inside
-    ``grouped_recon``."""
-
-    form: str
-    lowered: bool
-    n_groups: int = 0
-    exact: bool = True
-    kt: int = 0
-    use_pallas: bool = False
-    # fused merge window W (ops.vmem_budget), resolved host-statically
-    # alongside the form so the jitted dispatch carries it as a static
-    # argument; 0 for the non-fused forms
-    merge_window: int = 0
-
-
 def _note_lowered(mode: str) -> None:
     from raft_tpu import observability as obs
     if obs.enabled():
         obs.registry().counter("distributed.ann.scan_mode_lowered").inc()
-        if mode == "fused":
-            obs.registry().counter("ivf_pq.search.fused_fallback").inc()
     rec = _rtrace.current()
     _flight.record_event("distributed.scan_mode_lowered",
                          trace_id=rec.trace_id if rec else None,
                          requested=mode)
-
-
-def _note_fused_fallback(reason: str = "backend") -> None:
-    """Fused requested but the Pallas kernel gates failed: the XLA
-    grouped twin runs instead (same ladder as single-index; NOT a
-    distributed lowering, so the status vector stays SHARD_OK).
-    ``reason`` carries the same codes as the single-index path
-    (ivf_pq._search_checked.note_fused_fallback): kernel reject codes
-    ("dtype" / "k-too-large" / "bucket-too-wide" / "itopk-gate") or
-    "backend" for off-TPU / non-f32-id misses."""
-    from raft_tpu import observability as obs
-    if obs.enabled():
-        obs.registry().counter("ivf_pq.search.fused_fallback").inc()
-        obs.registry().counter(
-            f"ivf_pq.search.fused_fallback.reason.{reason}").inc()
-    rec = _rtrace.current()
-    _flight.record_event("ivf_pq.fused_fallback", reason=reason,
-                         trace_id=rec.trace_id if rec else None)
 
 
 def _note_routed_groups(sizes, needed, failed) -> None:
@@ -214,126 +166,36 @@ def _note_routed_groups(sizes, needed, failed) -> None:
             int(np.sum(n - np.minimum(live, n))))
 
 
-def _resolve_scan_mode(params, index, nq: int, n_probes: int,
-                       k: int) -> _ScanResolution:
-    """Resolve ``params.scan_mode`` to the distributed formulation that
-    runs inside ``shard_map`` — the support matrix docs/api.md
-    ("Distributed search") documents.  Everything here is host-static
-    (shapes, flags, calibrated estimate), so the jitted dispatch below
-    carries the decision as static arguments and the request path does
-    no device sync."""
-    mode = getattr(params, "scan_mode", "auto")
-    expects(mode in ivf_pq._SCAN_MODES,
-            f"distributed.ann.search: unknown scan_mode {mode!r}")
+def _plan(params, index, nq: int, n_probes: int, k: int) -> ivf_pq.ScanPlan:
+    """The scan plan of one sharded search: :func:`ivf_pq.plan_scan`
+    over the facts of the index's placement, all host-static (shapes,
+    flags, the calibrated estimate, the id-exactness verdict cached at
+    shard time), so the jitted dispatch carries the plan as static
+    arguments and the request path does no device sync.  A lowered
+    plan is noted here (``distributed.ann.scan_mode_lowered``)."""
     on_tpu = _platform.on_tpu()
-    kt_req = int(getattr(params, "per_probe_topk", 0) or 0)
-    routed = isinstance(index, RoutedIndex)
-    want_fused = mode == "fused" or (mode == "auto" and on_tpu)
-
-    if routed:
-        if mode in ("lut", "codes", "recon8"):
-            # routed shards carry no raw packed codes and no int8 recon
-            # cache — the documented FALLBACK exception
-            _note_lowered(mode)
-            return _ScanResolution("probe_recon", lowered=True)
-        if not want_fused:
-            return _ScanResolution("probe_recon", lowered=False)
-        slots = index.local_centers.shape[1]
-        cap = index.capacity
-        rot = index.rotation.shape[1]
-        kt = min(kt_req or k, cap)
-        n_groups, exact = grouped.group_capacity(
-            nq, n_probes, slots, est=getattr(index, "group_est", 0.0))
-        metric_l2 = index.metric in ivf_pq._L2_METRICS
-        mw_req = vb.merge_window_request(
-            getattr(params, "merge_window", "auto"))
-        if on_tpu:
-            from raft_tpu.ops import pq_code_scan_pallas as pcs
-            from raft_tpu.ops import pq_group_scan_pallas as pqp
-            ids_ok = grouped.ids_f32_exact(index, index.list_indices)
-            if (index.list_code_lanes is not None
-                    and index.list_code_rsq is not None
-                    and index.codebooks is not None and index.pq_bits
-                    and ids_ok and metric_l2
-                    and pcs.supported_fused_codes(
-                        True, True, cap, rot, kt, k, nq,
-                        index.codebooks.shape[0], index.pq_bits,
-                        merge_window=mw_req)):
-                # the 72 B/row headline: per-shard fused code scan
-                return _ScanResolution(
-                    "fused_codes", lowered=False, n_groups=n_groups,
-                    exact=exact, kt=kt,
-                    merge_window=pcs.fused_codes_merge_window(
-                        cap, rot, kt, k, nq, index.codebooks.shape[0],
-                        index.pq_bits, requested=mw_req))
-            if ids_ok and pqp.supported_fused(metric_l2, cap, rot, kt,
-                                              k, nq,
-                                              merge_window=mw_req):
-                return _ScanResolution(
-                    "fused_recon", lowered=False, n_groups=n_groups,
-                    exact=exact, kt=kt,
-                    merge_window=pqp.fused_merge_window(
-                        cap, rot, kt, k, nq, requested=mw_req))
-            if mode == "fused":
-                _note_fused_fallback(
-                    (pqp.fused_reject_reason(metric_l2, cap, rot, kt, k,
-                                             nq, merge_window=mw_req)
-                     or "bucket-too-wide") if ids_ok else "backend")
-            return _ScanResolution("grouped_recon", lowered=False,
-                                   n_groups=n_groups, exact=exact, kt=kt,
-                                   use_pallas=ids_ok)
-        if mode == "fused":
-            _note_fused_fallback("backend")
-        return _ScanResolution("grouped_recon", lowered=False,
-                               n_groups=n_groups, exact=exact, kt=kt)
-
-    # data-parallel (by_row): per-shard local index, worst-bound
-    # capacity only (exact regime — no overflow machinery in the jit)
-    n_lists_local = index.centers.shape[1]
-    cap = index.list_recon.shape[2]
-    rot = index.rotation.shape[2]
-    kt = min(kt_req or k, cap)
-    if mode in ("lut", "codes"):
-        if getattr(index, "pq_bits", 0):
-            # the traceable LUT twin computes the same quantized
-            # distance the codes kernel streams; on TPU a codes request
-            # is still a formulation downgrade (no lane-packed leaves in
-            # the stacked pytree), so report the lowering there
-            lowered = mode == "codes" and on_tpu
-            if lowered:
-                _note_lowered(mode)
-            return _ScanResolution("lut", lowered=lowered, kt=kt)
-        _note_lowered(mode)  # legacy stacked pytree without PQ metadata
-        return _ScanResolution("probe_recon", lowered=True)
-    if mode == "recon8":
-        _note_lowered(mode)  # no stacked int8 recon cache
-        return _ScanResolution("probe_recon", lowered=True)
-    if not want_fused:
-        return _ScanResolution("probe_recon", lowered=False)
-    n_groups, _ = grouped.group_capacity(nq, n_probes, n_lists_local)
-    mw_req = vb.merge_window_request(
-        getattr(params, "merge_window", "auto"))
-    if on_tpu:
-        from raft_tpu.ops import pq_group_scan_pallas as pqp
-        metric_l2 = index.metric in ivf_pq._L2_METRICS
-        ids_ok = grouped.ids_f32_exact(index, index.list_indices)
-        if ids_ok and pqp.supported_fused(metric_l2, cap, rot, kt, k, nq,
-                                          merge_window=mw_req):
-            return _ScanResolution(
-                "fused_recon", lowered=False, n_groups=n_groups, kt=kt,
-                merge_window=pqp.fused_merge_window(cap, rot, kt, k, nq,
-                                                    requested=mw_req))
-        if mode == "fused":
-            _note_fused_fallback(
-                (pqp.fused_reject_reason(metric_l2, cap, rot, kt, k, nq,
-                                         merge_window=mw_req)
-                 or "bucket-too-wide") if ids_ok else "backend")
-        return _ScanResolution("grouped_recon", lowered=False,
-                               n_groups=n_groups, kt=kt, use_pallas=ids_ok)
-    if mode == "fused":
-        _note_fused_fallback("backend")
-    return _ScanResolution("grouped_recon", lowered=False,
-                           n_groups=n_groups, kt=kt)
+    ids_exact = on_tpu and grouped.ids_f32_exact(index, index.list_indices)
+    if isinstance(index, RoutedIndex):
+        books = index.codebooks
+        facts = dict(
+            placement="by_list", lists=index.local_centers.shape[1],
+            cap=index.capacity, rot=index.rotation.shape[1],
+            pq_dim=books.shape[0] if books is not None else 0,
+            per_subspace=books is not None,
+            code_lanes=(index.list_code_lanes is not None
+                        and index.list_code_rsq is not None),
+            group_est=index.group_est)
+    else:
+        facts = dict(placement="by_row", lists=index.centers.shape[1],
+                     cap=index.list_recon.shape[2],
+                     rot=index.rotation.shape[2])
+    plan = ivf_pq.plan_scan(params, metric=index.metric,
+                            pq_bits=int(index.pq_bits), nq=nq, k=k,
+                            n_probes=n_probes, on_tpu=on_tpu,
+                            ids_exact=ids_exact, **facts)
+    if plan.lowered:
+        _note_lowered(getattr(params, "scan_mode", "auto"))
+    return plan
 
 
 @jax.tree_util.register_pytree_node_class
@@ -721,11 +583,10 @@ def _merge_refined(rows, row_pos, q, ld, li, k, metric, axis_name):
 
 @functools.partial(jax.jit, static_argnames=(
     "k", "kt", "n_probes", "metric", "axis_name", "mesh", "n_groups",
-    "form", "use_pallas", "merge_window", "failed"))
+    "form", "use_pallas", "failed"))
 def _dist_search_grouped(index_leaves, queries, k, kt, n_probes, metric,
                          axis_name, mesh, n_groups, form,
-                         use_pallas=False, merge_window=1, failed=(),
-                         filter_words=None):
+                         use_pallas=False, failed=(), filter_words=None):
     """Data-parallel grouped/fused scan under ``shard_map`` (round 10):
     every shard runs the SAME formulation ladder the single-index search
     picks, at the worst-case static group capacity — the capacity is a
@@ -750,7 +611,7 @@ def _dist_search_grouped(index_leaves, queries, k, kt, n_probes, metric,
             ld, li = ivf_pq._search_impl_fused_recon_grouped(
                 centers[0], list_recon[0], list_recon_sq[0],
                 list_indices[0], rotation[0], q, probes, k, kt, metric,
-                n_groups, merge_window=merge_window, filter_words=fw)
+                n_groups, filter_words=fw)
         else:
             G = grouped.GROUP
             block = grouped.block_size(n_groups, G * cap * 8,
@@ -825,8 +686,7 @@ def ground_truth_params(index, params=None) -> ivf_pq.SearchParams:
     n_lists = int(index.n_lists if routed else index.centers.shape[1])
     base = params if params is not None else ivf_pq.SearchParams()
     return dataclasses.replace(base, n_probes=n_lists, scan_mode="lut",
-                               per_probe_topk=0, exact_coarse=True,
-                               use_reconstruction=None)
+                               per_probe_topk=0, exact_coarse=True)
 
 
 def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
@@ -876,11 +736,11 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
     :data:`SHARD_OK`).  Data-parallel ``codes``/``lut`` requests run the
     traceable LUT formulation (same quantized distance) when the stacked
     pytree carries PQ metadata.  Only the genuinely unsupported
-    combinations lower to the probe-order recon scan — ``recon8`` (no
-    stacked int8 cache), code modes on a routed index without the code
-    leaves or on a legacy stacked pytree — and those report
-    :data:`SHARD_OK_FALLBACK` plus the
-    ``distributed.ann.scan_mode_lowered`` counter, exactly as before.
+    combinations lower to the probe-order recon scan — the code modes on
+    a routed index (its shards hold no packed codes) or on a legacy
+    stacked pytree — and those report :data:`SHARD_OK_FALLBACK` plus the
+    ``distributed.ann.scan_mode_lowered`` counter.
+    :func:`raft_tpu.neighbors.ivf_pq.plan_scan` makes the choice.
 
     Routed fused dispatch is sync-free: an uncalibrated index runs at
     the exact-safe worst-case capacity (zero host reads); a calibrated
@@ -1124,7 +984,7 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
         faults.pause(wait)
         n_probes = min(params.n_probes,
                        index.n_lists if routed else index.centers.shape[1])
-        r = _resolve_scan_mode(params, index, nq, n_probes, k_scan)
+        r = _plan(params, index, nq, n_probes, k_scan)
         # per-request tracing: annotate the ambient recorder (pushed by
         # the serving batcher around its executor call) with the host-
         # static facts of this dispatch.  Everything attached here is
@@ -1193,8 +1053,7 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
                         n_probes, index.metric, comms.axis_name,
                         handle.mesh, ng, r.form,
                         pq_bits=int(index.pq_bits),
-                        use_pallas=r.use_pallas,
-                        merge_window=r.merge_window, failed=residual,
+                        use_pallas=r.use_pallas, failed=residual,
                         filter_words=fw, refine_to=refine_to)
                     return out if fw is not None else out + (None,)
 
@@ -1212,15 +1071,14 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
                         if obs.enabled():
                             obs.registry().counter(
                                 "ivf_pq.search.group_overflow").inc()
-                        worst, _ = grouped.group_capacity(
-                            nq, n_probes, index.local_centers.shape[1])
                         _flight.record_event(
                             "ivf_pq.group_overflow",
                             trace_id=rec.trace_id if rec else None,
-                            calibrated_groups=r.n_groups, worst=worst)
+                            calibrated_groups=r.n_groups,
+                            worst=r.worst_groups)
                         (d, i, scanned, needed, phist,
-                         admitted) = dispatch(worst)
-                        sizes.append(worst)
+                         admitted) = dispatch(r.worst_groups)
+                        sizes.append(r.worst_groups)
                 if r.form in ("fused_codes", "fused_recon"):
                     _note_routed_groups(sizes, needed, residual)
         elif r.form == "probe_recon":
@@ -1254,8 +1112,7 @@ def search(handle, params: ivf_pq.SearchParams, index, queries, k: int, *,
                 lambda: _dist_search_grouped(
                     leaves, queries, k, r.kt, n_probes, index.metric,
                     comms.axis_name, handle.mesh, r.n_groups, r.form,
-                    use_pallas=r.use_pallas,
-                    merge_window=r.merge_window, failed=residual,
+                    use_pallas=r.use_pallas, failed=residual,
                     filter_words=fw),
                 retry_policy, deadline)
             (d, i, admitted) = out if fw is not None else out + (None,)
@@ -1982,14 +1839,12 @@ def _routed_leaves(index: "RoutedIndex", form: str):
 
 @functools.partial(jax.jit, static_argnames=(
     "k", "kt", "n_probes", "metric", "axis_name", "mesh", "n_groups",
-    "form", "pq_bits", "use_pallas", "merge_window", "failed",
-    "refine_to"))
+    "form", "pq_bits", "use_pallas", "failed", "refine_to"))
 def _dist_search_routed_grouped(sharded, replicated, queries, k, kt,
                                 n_probes, metric, axis_name, mesh,
                                 n_groups, form, pq_bits=0,
-                                use_pallas=False, merge_window=1,
-                                failed=(), filter_words=None,
-                                refine_to=0):
+                                use_pallas=False, failed=(),
+                                filter_words=None, refine_to=0):
     """Routed (by_list) grouped/fused scan under ``shard_map``
     (round 10): the tentpole dispatch.  Replicated coarse routing picks
     the probe set, ownership maps it to local slots, and the shard scans
@@ -2038,13 +1893,12 @@ def _dist_search_routed_grouped(sharded, replicated, queries, k, kt,
             ld, li = ivf_pq._search_impl_fused_codes_grouped(
                 local_centers[0], rl[4], data[0], rownorm[0],
                 list_indices[0], rot, q, local_probes, k, kt, metric,
-                n_groups, pq_bits, merge_window=merge_window,
-                filter_words=fw)
+                n_groups, pq_bits, filter_words=fw)
         elif form == "fused_recon":
             ld, li = ivf_pq._search_impl_fused_recon_grouped(
                 local_centers[0], data[0], rownorm[0], list_indices[0],
                 rot, q, local_probes, k, kt, metric, n_groups,
-                merge_window=merge_window, filter_words=fw)
+                filter_words=fw)
         else:
             rot_dim = data.shape[3]
             G = grouped.GROUP

@@ -325,31 +325,6 @@ def _verify_ivf_pq(index, level: str, n_rows=None) -> None:
                   f"slot {coord[2]} does not repack from the stored "
                   f"codes", coord=coord)
 
-    if index.list_recon_i8 is not None:
-        rot_pad = -(-index.rot_dim // 128) * 128
-        qi, scale, rsq8 = ivf_pq._quantize_recon(
-            jnp.asarray(_recon_recompute(), jnp.bfloat16), rot_pad)
-        got_i8 = np.asarray(index.list_recon_i8)
-        _check(got_i8.shape == qi.shape, f"{kind}.list_recon_i8.shape",
-               f"int8 recon shape {got_i8.shape} != {qi.shape}")
-        stale = (got_i8 != np.asarray(qi)) & valid[:, :, None]
-        if stale.any():
-            coord = _first_bad(stale)
-            _fail(f"{kind}.list_recon_i8.stale",
-                  f"int8 recon at list {coord[0]} slot {coord[1]} lane "
-                  f"{coord[2]} does not re-quantize from the stored "
-                  f"codes — stale derived cache (extend without "
-                  f"re-quantization?)", coord=coord)
-        if index.list_recon_scale is not None:
-            got_s = np.asarray(index.list_recon_scale)
-            badl = ~np.isclose(got_s, np.asarray(scale), rtol=1e-5)
-            if badl.any():
-                li = int(np.argmax(badl))
-                _fail(f"{kind}.list_recon_scale.stale",
-                      f"int8 scale of list {li} is {got_s[li]:.6g}, "
-                      f"recompute gives {float(scale[li]):.6g}",
-                      coord=(li,))
-
     if level in ("statistical", "full"):
         _verify_finite(kind, "centers", centers)
         _verify_finite(kind, "codebooks", np.asarray(index.codebooks,

@@ -143,7 +143,7 @@ class SearchParams:
     entry_points: int = 4096
     rerank_topk: int = 0
     # Fused-hop merge engine ("auto" | int, parsed by
-    # ops.vmem_budget.merge_window_request like ivf_pq's knob): the hop
+    # ops.vmem_budget.merge_window_request): the hop
     # kernel cannot defer merges ACROSS hops (parent selection consumes
     # the merged buffer every hop), so >1 selects the staged WITHIN-hop
     # merge — candidates are extracted into a sorted staging block and

@@ -186,8 +186,8 @@ def probe_overlap_order(probes: jax.Array, n_lists: int) -> jax.Array:
     - adjacent groups of one list keep the same BlockSpec index, so the
       Pallas pipeline skips the re-DMA and each hot list's data streams
       from HBM once per BATCH, not once per probing query;
-    - the fused kernels' accumulator one-hots touch a narrow band of
-      query rows per group (the prerequisite for windowed merges).
+    - the fused kernels' per-step accumulator row copies touch a
+      narrow band of query rows per group.
 
     Returns ``qorder`` (nq,) int32; callers permute queries/probes by it
     before grouping and un-permute results with ``argsort(qorder)``.
@@ -299,13 +299,13 @@ def scan_traffic(rot: int, pq_dim: int = 0, pq_bits: int = 0) -> dict:
 
     Every mode reads the (int32) candidate-id row and an (f32) cached row
     norm per candidate; what differs is the data payload — bf16
-    reconstructions (2 B/dim), int8 reconstructions (1 B/dim), or
-    lane-major packed codes (int32 words covering pq_dim*pq_bits bits).
+    reconstructions (2 B/dim) or lane-major packed codes (int32 words
+    covering pq_dim*pq_bits bits).
     Query/center/codebook traffic is per GROUP (128 pairs), not per row,
     and amortizes out at scan scale; this model is what the round-6
     decomposition profile and the docs' memory-traffic table report."""
     base = 4 + 4                      # id row (int32) + row norm (f32)
-    out = {"recon": 2 * rot + base, "recon8": rot + base}
+    out = {"recon": 2 * rot + base}
     if pq_dim and pq_bits:
         w_bytes = -(-pq_dim * pq_bits // 8)
         out["codes"] = 4 * -(-w_bytes // 4) + base
@@ -341,7 +341,7 @@ def block_size(n_groups: int, *per_group_bytes: int,
 
 
 def scan_and_scatter(group_list, slot_pairs, P, cap, k, select_min, block,
-                     select_k_fn, distance_block, kt=0, merge_window=0):
+                     select_k_fn, distance_block, kt=0):
     """Shared scan driver: for each block of groups, compute distances via
     ``distance_block(gl, slot) -> ((B, GROUP, cap) masked distances,
     (B, cap) candidate ids)`` and take each pair-row's local top-kt.
@@ -354,17 +354,7 @@ def scan_and_scatter(group_list, slot_pairs, P, cap, k, select_min, block,
     ``take_along_axis`` does without materializing a (B, GROUP, cap) id
     tensor.  Sentinel slots scatter out of bounds and are dropped; the
     clamped tail block emits duplicate pairs with identical values, so the
-    final scatter stays idempotent.
-
-    ``merge_window`` windows the staged scatter: 0 stages every block's
-    outputs before the single scatter (the round-7 shape, maximal
-    staging footprint); W >= 1
-    scatters once per W-block window inside an outer scan, bounding the
-    staged (n_blocks * B * GROUP, kt) output pair to W blocks at the
-    cost of one (P, kt) carry copy per window instead of none.  Exact
-    either way — each pair-row is written with the same value no matter
-    which window carries it (overlap only at the clamped tail block,
-    which emits duplicates with identical values)."""
+    final scatter stays idempotent."""
     n_groups = group_list.shape[0]
     worst = jnp.inf if select_min else -jnp.inf
     # kt (SearchParams.per_probe_topk) narrows the per-pair keep-set below
@@ -393,27 +383,6 @@ def scan_and_scatter(group_list, slot_pairs, P, cap, k, select_min, block,
 
     outd = jnp.full((P, kt), worst, jnp.float32)
     outi = jnp.full((P, kt), -1, jnp.int32)
-
-    if 0 < merge_window < n_blocks:
-        W = merge_window
-        n_windows = -(-n_blocks // W)
-        # pad by repeating the last start: duplicate blocks re-write
-        # identical values, same idempotence as the clamped tail
-        pad = n_windows * W - n_blocks
-        starts = jnp.concatenate(
-            [block_starts, jnp.broadcast_to(block_starts[-1:], (pad,))])
-
-        def window(carry, wstarts):
-            od, oi = carry
-            _, (tds, tis, flats) = jax.lax.scan(step, None, wstarts)
-            flat = flats.reshape(-1)
-            od = od.at[flat].set(tds.reshape(-1, kt), mode="drop")
-            oi = oi.at[flat].set(tis.reshape(-1, kt), mode="drop")
-            return (od, oi), None
-
-        (outd, outi), _ = jax.lax.scan(window, (outd, outi),
-                                       starts.reshape(n_windows, W))
-        return outd, outi
 
     _, (tds, tis, flats) = jax.lax.scan(step, None, block_starts)
     flat = flats.reshape(-1)

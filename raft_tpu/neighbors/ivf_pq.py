@@ -4,7 +4,7 @@ performance flagship (BASELINE.md north-star workload).
 Reference: raft/neighbors/ivf_pq.cuh:224 ``build``, :266 ``extend``, :342
 ``search``; params/types ivf_pq_types.hpp:48 (index_params: pq_bits, pq_dim,
 codebook_kind, force_random_rotation), :110 (search_params: n_probes,
-lut_dtype, internal_distance_dtype), :264 (index).  Build internals:
+lut_dtype), :264 (index).  Build internals:
 detail/ivf_pq_build.cuh:337 ``train_per_subset``, :417 ``train_per_cluster``
 (both via kmeans_balanced), :944 ``process_and_fill_codes_kernel``; search:
 detail/ivf_pq_search.cuh:133 ``select_clusters``, :611
@@ -110,18 +110,10 @@ class SearchParams:
     exact_coarse: bool = False
     # lut_dtype applies to the LUT formulation only (fp32 | bf16, the fp8
     # analogue); the reconstruction path stores bf16 residuals and always
-    # accumulates fp32 (internal_distance_dtype's contract).
+    # accumulates fp32.
     lut_dtype: object = jnp.float32
-    internal_distance_dtype: object = jnp.float32
-    # None -> auto: scan the bf16 reconstruction cache when the index
-    # carries one (the TPU fast path; ~identical recall, see
-    # test_recon_path_matches_lut_path); False forces the LUT formulation.
-    # Indexes built with IndexParams.cache_reconstructions=False carry no
-    # cache and use the LUT path automatically.
-    # DEPRECATED in favour of scan_mode (kept for compat: when set it
-    # overrides scan_mode with "recon"/"lut").
-    use_reconstruction: Optional[bool] = None
-    # Which list-scan formulation serves the query batch:
+    # Which list-scan formulation serves the query batch (plan_scan
+    # decides the one that runs; docs/api.md has its table):
     #   "recon"  — bf16 reconstruction cache (2 B/dim/row HBM traffic);
     #   "codes"  — compact-code Pallas kernel: bit-packed codes stream
     #              from HBM (~pq_bits/8 B/subspace/row, ~4x less than
@@ -130,8 +122,6 @@ class SearchParams:
     #              analogue of the reference's shared-memory LUT scan,
     #              ivf_pq_search.cuh:611); falls back to "lut" off-TPU or
     #              for unsupported shapes (see pq_code_scan_pallas);
-    #   "recon8" — int8-quantized recon cache with per-list scale
-    #              (1 B/dim/row, in-register dequantization);
     #   "lut"    — the XLA take_along_axis LUT formulation (traceable,
     #              memory-lean; the AOT export path);
     #   "fused"  — the in-kernel top-k variants of "codes"/"recon": a
@@ -157,17 +147,6 @@ class SearchParams:
     # per-pair keep-set and ignores this knob — the fallback errs toward
     # MORE candidates, never fewer.
     per_probe_topk: int = 0
-    # Opt-in packed-key top-kt extraction inside the codes/recon8 kernels:
-    # one cross-lane reduce per kept candidate instead of three, at the
-    # cost of truncating ~log2(capacity) distance mantissa bits (~2^-13
-    # relative at bench shapes; ordering-only effect, far below PQ noise).
-    packed_extract: bool = False
-    # Merge window W ("auto" / 0, or an int >= 1).  The fused IVF-PQ
-    # scans merge every grid step whatever W is (results never depend
-    # on it); it windows the XLA grouped scan's staged scatter, keys
-    # the serving executable cache, and selects the staged CAGRA-hop
-    # merge — see cagra.SearchParams.merge_window.
-    merge_window: object = "auto"
 
 
 @jax.tree_util.register_pytree_node_class
@@ -216,13 +195,6 @@ class Index:
     # search.
     list_code_lanes: Optional[jax.Array] = None
     list_code_rsq: Optional[jax.Array] = None
-    # Derived search-time cache for scan_mode="recon8": the recon cache
-    # quantized to int8 with ONE f32 scale per list (lanes zero-padded to
-    # a 128 multiple for the kernel), plus squared norms of the
-    # DEQUANTIZED rows so kernel distances are self-consistent.
-    list_recon_i8: Optional[jax.Array] = None
-    list_recon_scale: Optional[jax.Array] = None
-    list_recon_i8_sq: Optional[jax.Array] = None
     # explicit because list_codes is bit-packed (its trailing axis is the
     # packed byte width, not pq_dim); 0 -> equal to the code width (the
     # pq_bits=8 layout where packing is the identity)
@@ -289,9 +261,7 @@ class Index:
         leaves = (self.centers, self.codebooks, self.list_codes,
                   self.list_indices, self.list_sizes, self.rotation,
                   self.list_recon, self.list_recon_sq,
-                  self.list_code_lanes, self.list_code_rsq,
-                  self.list_recon_i8, self.list_recon_scale,
-                  self.list_recon_i8_sq)
+                  self.list_code_lanes, self.list_code_rsq)
         return leaves, (self.metric, self.codebook_kind, self.pq_bits,
                         self.pq_dim_)
 
@@ -299,10 +269,8 @@ class Index:
     def tree_unflatten(cls, aux, leaves):
         return cls(*leaves[:6], list_recon=leaves[6],
                    list_recon_sq=leaves[7], list_code_lanes=leaves[8],
-                   list_code_rsq=leaves[9], list_recon_i8=leaves[10],
-                   list_recon_scale=leaves[11], list_recon_i8_sq=leaves[12],
-                   metric=aux[0], codebook_kind=aux[1], pq_bits=aux[2],
-                   pq_dim_=aux[3])
+                   list_code_rsq=leaves[9], metric=aux[0],
+                   codebook_kind=aux[1], pq_bits=aux[2], pq_dim_=aux[3])
 
 
 # ---------------------------------------------------------------------------
@@ -722,10 +690,6 @@ def extend(res, index: Index, new_vectors, new_indices=None) -> Index:
                     out.list_code_rsq = out.list_recon_sq
                 elif "code_rsq" in at:
                     out.list_code_rsq = new_bufs[at["code_rsq"]]
-            # int8 recon caches are NOT carried: their per-list symmetric
-            # scale was chosen from the pre-extend residual range, so
-            # appended rows could overflow it — the next recon8 search
-            # re-quantizes lazily (integrity.verify flags a stale copy)
             _mutate.next_generation(index, out)
             if index.canaries is not None:
                 out.canaries = index.canaries
@@ -768,8 +732,6 @@ def extend(res, index: Index, new_vectors, new_indices=None) -> Index:
         # codes rather than arriving cold at the next search
         if index.list_code_lanes is not None:
             out = _with_code_lanes(out)
-        if index.list_recon_i8 is not None:
-            out = _with_recon8(out)
         _mutate.next_generation(index, out)
         if index.canaries is not None:
             out.canaries = index.canaries
@@ -782,8 +744,8 @@ def delete(res, index: Index, ids) -> Index:
     see :mod:`raft_tpu.neighbors.mutate` for the encoding).
 
     Rewrites the matching ``list_indices`` slots to tombstones; every
-    scan formulation (recon/codes/recon8/lut and the fused Pallas
-    kernels) already masks negative ids to the worst-distance sentinel,
+    scan formulation (recon/codes/lut and the fused Pallas kernels)
+    already masks negative ids to the worst-distance sentinel,
     so deleted rows vanish from results immediately without touching
     the codes, any derived cache, or fused-path eligibility.  Storage
     is reclaimed by :func:`compact`.  Ids not present match nothing.
@@ -805,10 +767,7 @@ def delete(res, index: Index, ids) -> Index:
             list_recon=index.list_recon,
             list_recon_sq=index.list_recon_sq,
             list_code_lanes=index.list_code_lanes,
-            list_code_rsq=index.list_code_rsq,
-            list_recon_i8=index.list_recon_i8,
-            list_recon_scale=index.list_recon_scale,
-            list_recon_i8_sq=index.list_recon_i8_sq)
+            list_code_rsq=index.list_code_rsq)
         out.canaries = index.canaries
         _mutate.next_generation(index, out)
         if index.canaries is not None:
@@ -871,8 +830,6 @@ def compact(res, index: Index) -> Index:
             out = _with_recon(res, out)
         if index.list_code_lanes is not None:
             out = _with_code_lanes(out)
-        if index.list_recon_i8 is not None:
-            out = _with_recon8(out)
         out.canaries = index.canaries
         _mutate.next_generation(index, out)
         if index.canaries is not None:
@@ -1012,39 +969,6 @@ def _with_code_lanes(index: Index) -> Index:
     return index
 
 
-@functools.partial(jax.jit, static_argnames=("rot_pad",))
-def _quantize_recon(list_recon, rot_pad):
-    """bf16 recon cache -> (int8 codes, per-list f32 scale, dequantized
-    row norms).  Residual magnitudes cluster within a list, so one
-    symmetric scale per list (max|recon|/127) keeps quantization error
-    ~1/256 of the list's residual range — well under PQ noise (measured:
-    recall moves <0.3% at bench shapes, PERFORMANCE.md round 6)."""
-    r = list_recon.astype(jnp.float32)                   # (L, cap, rot)
-    L, cap, rot = r.shape
-    maxabs = jnp.max(jnp.abs(r), axis=(1, 2))            # (L,)
-    scale = jnp.where(maxabs > 0, maxabs / 127.0, 1.0)
-    q = jnp.clip(jnp.round(r / scale[:, None, None]), -127, 127)
-    rsq8 = scale[:, None] ** 2 * jnp.sum(q * q, axis=-1)  # (L, cap) f32
-    qi = jnp.pad(q.astype(jnp.int8), ((0, 0), (0, 0), (0, rot_pad - rot)))
-    return qi, scale, rsq8
-
-
-def _with_recon8(index: Index) -> Index:
-    """Attach the int8-quantized recon cache (derives the bf16 recon on
-    the fly when the index carries none — only the int8 copy is kept)."""
-    recon = index.list_recon
-    if recon is None:
-        recon = _decode_lists(index.centers, index.codebooks,
-                              index.list_codes, index.codebook_kind,
-                              index.pq_dim, index.pq_bits)
-    rot_pad = _round_up(index.rot_dim, 128)
-    qi, scale, rsq8 = _quantize_recon(recon, rot_pad)
-    index.list_recon_i8 = qi
-    index.list_recon_scale = scale
-    index.list_recon_i8_sq = rsq8
-    return index
-
-
 @functools.partial(jax.jit, static_argnames=("k", "n_probes", "metric"))
 def _search_impl_recon(centers, list_recon, list_indices, rotation, queries,
                        k, n_probes, metric, probes=None, list_recon_sq=None,
@@ -1144,13 +1068,12 @@ def _select_clusters(centers, rotation, queries, n_probes, metric,
 
 @functools.partial(jax.jit, static_argnames=("k", "metric", "n_groups",
                                              "block", "use_pallas",
-                                             "pallas_interpret", "kt",
-                                             "merge_window"))
+                                             "pallas_interpret", "kt"))
 def _search_impl_recon_grouped(centers, list_recon, list_recon_sq,
                                list_indices, rotation, queries, probes, k,
                                metric, n_groups, block, use_pallas=False,
                                pallas_interpret=False, kt=0,
-                               merge_window=0, filter_words=None):
+                               filter_words=None):
     """List-centric recon scan over fixed-size pair groups.
 
     See :mod:`raft_tpu.neighbors.grouped` for the design (and the measured
@@ -1233,7 +1156,7 @@ def _search_impl_recon_grouped(centers, list_recon, list_recon_sq,
 
     outd, outi = grouped.scan_and_scatter(
         group_list, slot_pairs, P, cap, k, not ip_metric, block,
-        select_k, distance_block, kt=kt, merge_window=merge_window)
+        select_k, distance_block, kt=kt)
     return grouped.finalize_topk(
         outd, outi, nq, k, not ip_metric,
         metric in (DistanceType.L2SqrtExpanded,
@@ -1241,13 +1164,12 @@ def _search_impl_recon_grouped(centers, list_recon, list_recon_sq,
 
 
 @functools.partial(jax.jit, static_argnames=("k", "kt", "metric", "n_groups",
-                                             "pq_bits", "packed",
-                                             "pallas_interpret"))
+                                             "pq_bits", "pallas_interpret"))
 def _search_impl_codes_grouped(centers, codebooks, list_code_lanes,
                                list_code_rsq, list_indices, rotation,
                                queries, probes, k, kt, metric, n_groups,
-                               pq_bits, packed=False,
-                               pallas_interpret=False, filter_words=None):
+                               pq_bits, pallas_interpret=False,
+                               filter_words=None):
     """Grouped COMPACT-CODE scan: the Pallas kernel streams lane-major
     packed codes (~pq_bits/8 bytes per subspace per row — the recon path
     reads 2*pq_len) and decodes them in-register against the
@@ -1275,88 +1197,9 @@ def _search_impl_codes_grouped(centers, codebooks, list_code_lanes,
     kt = min(kt or k, cap)
     vals, ti = pcs.grouped_code_scan(
         group_list, slot_pairs, qrot, cf, list_code_lanes, codebooks,
-        list_code_rsq, list_indices, kt, n_probes, pq_bits, packed=packed,
+        list_code_rsq, list_indices, kt, n_probes, pq_bits,
         interpret=pallas_interpret, adm_words=adm_words)
     outd, outi = grouped.scatter_packed(vals, ti, slot_pairs, P, True)
-    return grouped.finalize_topk(
-        outd, outi, nq, k, True,
-        metric in (DistanceType.L2SqrtExpanded,
-                   DistanceType.L2SqrtUnexpanded), select_k)
-
-
-@functools.partial(jax.jit, static_argnames=("k", "kt", "metric", "n_groups",
-                                             "block", "use_pallas", "packed",
-                                             "pallas_interpret"))
-def _search_impl_recon8_grouped(centers, list_recon_i8, list_recon_scale,
-                                list_recon_i8_sq, list_indices, rotation,
-                                queries, probes, k, kt, metric, n_groups,
-                                block, use_pallas=False, packed=False,
-                                pallas_interpret=False, filter_words=None):
-    """Grouped scan over the int8-quantized recon cache (1 byte/dim/row):
-    the Pallas kernel dequantizes in-register with the per-list scale —
-    ``d = ||sub||^2 + rsq8 - 2*scale*(sub . q8)``.  The XLA fallback
-    computes the identical quantized distance for CPU / unsupported
-    shapes.  L2-family metrics only (search() gates)."""
-    from raft_tpu.neighbors import grouped
-
-    nq, n_probes = probes.shape
-    P = nq * n_probes
-    n_lists = centers.shape[0]
-    _, cap, rot_pad = list_recon_i8.shape
-    rot = rotation.shape[1]
-
-    qrot = queries.astype(jnp.float32) @ rotation
-    cf = centers.astype(jnp.float32)
-
-    group_list, slot_pairs = grouped.build_groups(probes, n_lists, n_groups)
-    adm_words = None
-    if filter_words is not None:
-        adm_words = _fbits.group_admission_words(
-            filter_words, group_list, slot_pairs, list_indices, n_probes, P)
-    kt = min(kt or k, cap)
-    if use_pallas:
-        from raft_tpu.ops import pq_code_scan_pallas as pcs
-
-        vals, ti = pcs.grouped_recon8_scan(
-            group_list, slot_pairs, qrot, cf, list_recon_i8,
-            list_recon_scale, list_recon_i8_sq, list_indices, kt, n_probes,
-            packed=packed, interpret=pallas_interpret, adm_words=adm_words)
-        outd, outi = grouped.scatter_packed(vals, ti, slot_pairs, P, True)
-        return grouped.finalize_topk(
-            outd, outi, nq, k, True,
-            metric in (DistanceType.L2SqrtExpanded,
-                       DistanceType.L2SqrtUnexpanded), select_k)
-
-    # lane padding: the int8 cache's zero rot->rot_pad pad contributes
-    # nothing as long as the query side is zero-padded identically
-    qrot_p = jnp.pad(qrot, ((0, 0), (0, rot_pad - rot)))
-    cf_p = jnp.pad(cf, ((0, 0), (0, rot_pad - rot)))
-
-    def distance_block(gl, slot):
-        qid = jnp.where(slot < P, slot // n_probes, 0)
-        qv = qrot_p[qid]                                 # (B, G, rot_pad)
-        data = list_recon_i8[gl].astype(jnp.bfloat16)    # (B, cap, rot_pad)
-        ids = list_indices[gl]
-        sc = list_recon_scale[gl]                        # (B,)
-        rsq = list_recon_i8_sq[gl]                       # (B, cap)
-        sub = qv - cf_p[gl][:, None, :]
-        ip = jnp.einsum("bqr,bcr->bqc", sub.astype(jnp.bfloat16), data,
-                        preferred_element_type=jnp.float32)
-        d = jnp.maximum(jnp.sum(sub * sub, axis=-1)[:, :, None]
-                        + rsq[:, None, :]
-                        - 2.0 * sc[:, None, None] * ip, 0.0)
-        d = jnp.where(ids[:, None, :] >= 0, d, jnp.inf)
-        if filter_words is not None:
-            qid = jnp.where(slot < P, slot // n_probes, 0)
-            adm = _fbits.query_bits(
-                filter_words, qid,
-                jnp.broadcast_to(ids[:, None, :], d.shape))
-            d = jnp.where(adm > 0, d, jnp.inf)
-        return d, ids
-
-    outd, outi = grouped.scan_and_scatter(
-        group_list, slot_pairs, P, cap, k, True, block,
-        select_k, distance_block, kt=kt)
     return grouped.finalize_topk(
         outd, outi, nq, k, True,
         metric in (DistanceType.L2SqrtExpanded,
@@ -1384,12 +1227,11 @@ def _fused_epilogue(vals, ids, qorder, nq, k, metric):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "kt", "metric", "n_groups",
-                                             "pq_bits", "merge_window",
-                                             "pallas_interpret"))
+                                             "pq_bits", "pallas_interpret"))
 def _search_impl_fused_codes_grouped(centers, codebooks, list_code_lanes,
                                      list_code_rsq, list_indices, rotation,
                                      queries, probes, k, kt, metric,
-                                     n_groups, pq_bits, merge_window=1,
+                                     n_groups, pq_bits,
                                      pallas_interpret=False,
                                      filter_words=None):
     """Fused compact-code scan: the grouped code scan with the per-query
@@ -1422,18 +1264,15 @@ def _search_impl_fused_codes_grouped(centers, codebooks, list_code_lanes,
     vals, ids = pcs.grouped_code_scan_fused(
         group_list, slot_pairs, qrot[qorder], cf, list_code_lanes,
         codebooks, list_code_rsq, list_indices, kt, k, n_probes, pq_bits,
-        interpret=pallas_interpret, merge_window=merge_window,
-        adm_words=adm_words)
+        interpret=pallas_interpret, adm_words=adm_words)
     return _fused_epilogue(vals, ids, qorder, nq, k, metric)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "kt", "metric", "n_groups",
-                                             "merge_window",
                                              "pallas_interpret"))
 def _search_impl_fused_recon_grouped(centers, list_recon, list_recon_sq,
                                      list_indices, rotation, queries,
                                      probes, k, kt, metric, n_groups,
-                                     merge_window=1,
                                      pallas_interpret=False,
                                      filter_words=None):
     """Fused recon scan: :func:`_search_impl_recon_grouped`'s Pallas
@@ -1460,8 +1299,7 @@ def _search_impl_fused_recon_grouped(centers, list_recon, list_recon_sq,
     vals, ids = pqp.grouped_l2_scan_fused(
         group_list, slot_pairs, qrot[qorder], cf, list_recon,
         list_recon_sq, list_indices, kt, k, n_probes,
-        interpret=pallas_interpret, merge_window=merge_window,
-        adm_words=adm_words)
+        interpret=pallas_interpret, adm_words=adm_words)
     return _fused_epilogue(vals, ids, qorder, nq, k, metric)
 
 
@@ -1558,10 +1396,14 @@ def _search_impl(centers, codebooks, list_codes, list_indices, rotation,
                    DistanceType.L2SqrtUnexpanded), select_k)
 
 
-_SCAN_MODES = ("auto", "codes", "recon", "recon8", "lut", "fused")
+_SCAN_MODES = ("auto", "codes", "recon", "lut", "fused")
 
 _L2_METRICS = (DistanceType.L2Expanded, DistanceType.L2SqrtExpanded,
                DistanceType.L2Unexpanded, DistanceType.L2SqrtUnexpanded)
+
+# the plan forms that scan pair groups (grouped.build_groups) at a static
+# group capacity; the other two scan in probe order
+_GROUPED_FORMS = ("fused_codes", "fused_recon", "codes", "grouped_recon")
 
 
 def _codes_mode_eligible(index: Index) -> bool:
@@ -1574,15 +1416,198 @@ def _codes_mode_eligible(index: Index) -> bool:
             and index.pq_bits in (4, 8))
 
 
+@dataclasses.dataclass(frozen=True)
+class ScanPlan:
+    """The list-scan formulation one search runs (:func:`plan_scan`).
+
+    ``form`` is one of
+
+    - ``fused_codes`` / ``fused_recon``: the Pallas scans that keep each
+      query's top-k in the kernel (TPU only);
+    - ``codes``: the non-fused Pallas compact-code scan (TPU only);
+    - ``grouped_recon``: the grouped recon scan, through the non-fused
+      Pallas kernel where ``use_pallas`` and its gate admit the shape,
+      else through its XLA twin;
+    - ``probe_recon``: the probe-order recon scan;
+    - ``lut``: the probe-order LUT scan over the packed codes.
+
+    ``kt`` is the per-(query, probe) keep-set.  The grouped forms
+    dispatch at the static group capacity ``n_groups``; ``exact`` False
+    (a calibrated capacity) arms the in-graph overflow count, and an
+    overflowing batch re-dispatches at ``worst_groups``.  ``lowered``
+    marks a sharded search whose requested mode has no formulation on
+    its placement (shard status ``SHARD_OK_FALLBACK``).  ``reason`` is
+    the fused fallback's reason code, '' when none was noted."""
+
+    form: str
+    kt: int = 0
+    n_groups: int = 0
+    worst_groups: int = 0
+    exact: bool = True
+    use_pallas: bool = False
+    lowered: bool = False
+    reason: str = ""
+
+
+def _note_fused_fallback(reason: str) -> None:
+    """Count a search that asked for a fused scan and runs another
+    formulation.  Reason codes: "dtype", "k-too-large" and
+    "bucket-too-wide" from the kernels' reject helpers; "backend" for
+    off-TPU or non-f32-exact ids; "mode" when the resolved mode has no
+    fused variant."""
+    if obs.enabled():
+        reg = obs.registry()
+        reg.counter("ivf_pq.search.fused_fallback").inc()
+        reg.counter(f"ivf_pq.search.fused_fallback.reason.{reason}").inc()
+    from raft_tpu.observability import flight as _flight
+    from raft_tpu.observability import trace as _rtrace
+    rec = _rtrace.current()
+    _flight.record_event("ivf_pq.fused_fallback", reason=reason,
+                         trace_id=rec.trace_id if rec else None)
+
+
+def plan_scan(params, *, placement: str, metric: int, cap: int, rot: int,
+              lists: int, pq_bits: int, nq: int, k: int, n_probes: int,
+              on_tpu: bool, pq_dim: int = 0, per_subspace: bool = False,
+              has_recon: bool = True, code_lanes: bool = False,
+              ids_exact: bool = False, group_est: float = 0.0,
+              traced: bool = False) -> ScanPlan:
+    """Decide the list-scan formulation of one IVF-PQ search.
+
+    The one place that reads ``params.scan_mode`` and
+    ``params.per_probe_topk``, asks the Pallas kernels' gates, sizes the
+    group capacity and notes a fused fallback
+    (``ivf_pq.search.fused_fallback`` and its ``.reason.<code>``
+    children, flight event ``ivf_pq.fused_fallback``).  Host-static: the
+    inputs are facts the caller observes without a device read, so the
+    same facts give the same plan.
+
+    ``placement`` is ``"single"`` (one :class:`Index`), ``"by_list"``
+    (a routed ``distributed.ann.RoutedIndex``: shards hold the recon
+    cache and, when ``code_lanes``, the lane-major codes, but no packed
+    codes) or ``"by_row"`` (a stacked ``distributed.ann.DistributedIndex``:
+    recon cache and packed codes, no code lanes).  ``lists`` is the
+    list count one scan addresses (a routed shard's slots);
+    ``pq_bits`` 0 marks a stacked index without PQ metadata;
+    ``has_recon`` whether a single index carries the recon cache (one
+    is materialized when "recon" is asked of an index without it);
+    ``ids_exact`` whether every candidate id is f32-exact, which the
+    Pallas kernels need; ``traced`` a search under an outer trace,
+    which takes a probe-order form (the grouped dispatch's overflow gate
+    is a host read).
+
+    docs/api.md ("Scan plans") tabulates the outcome.
+    """
+    from raft_tpu.neighbors import grouped
+    from raft_tpu.ops import pq_code_scan_pallas as pcs
+    from raft_tpu.ops import pq_group_scan_pallas as pqp
+
+    mode = getattr(params, "scan_mode", "auto") or "auto"
+    expects(mode in _SCAN_MODES,
+            f"ivf_pq.search: unknown scan_mode {mode!r} "
+            f"(one of {_SCAN_MODES})")
+    kt = min(int(getattr(params, "per_probe_topk", 0) or 0) or k, cap)
+    l2 = metric in _L2_METRICS
+    codes_ok = l2 and per_subspace and pq_bits in (4, 8)
+    pallas = on_tpu and ids_exact
+
+    def plan(form, reason="", lowered=False):
+        if reason:
+            _note_fused_fallback(reason)
+        if form not in _GROUPED_FORMS:
+            return ScanPlan(form, kt=kt, lowered=lowered, reason=reason)
+        # a stacked index's jitted dispatch has no overflow path, so it
+        # always takes the exact-safe bound
+        est = 0.0 if placement == "by_row" else group_est
+        n_groups, exact = grouped.group_capacity(nq, n_probes, lists,
+                                                 est=est)
+        worst = (n_groups if exact
+                 else grouped.group_capacity(nq, n_probes, lists)[0])
+        return ScanPlan(form, kt=kt, n_groups=n_groups, worst_groups=worst,
+                        exact=exact, use_pallas=pallas, reason=reason)
+
+    def recon_miss():
+        if not pallas:
+            return "backend"
+        return (pqp.fused_reject_reason(l2, cap, rot, kt, k, nq)
+                or "bucket-too-wide")
+
+    def codes_miss():
+        if not pallas:
+            return "backend"
+        return (pcs.fused_codes_reject_reason(l2, per_subspace, cap, rot,
+                                              kt, k, nq, pq_dim, pq_bits)
+                or "bucket-too-wide")
+
+    fused_recon_ok = pallas and pqp.supported_fused(l2, cap, rot, kt, k, nq)
+    fused_codes_ok = (pallas and codes_ok
+                      and (placement == "single" or code_lanes)
+                      and pcs.supported_fused_codes(
+                          l2, per_subspace, cap, rot, kt, k, nq, pq_dim,
+                          pq_bits))
+
+    if placement == "single":
+        # "auto" and "fused" resolve to the backing mode that owns the
+        # caches and the fallback path: "fused" prefers the codes, "auto"
+        # the recon cache the index already carries
+        want_fused = mode in ("auto", "fused")
+        if mode == "fused":
+            mode = ("codes" if codes_ok else "recon" if has_recon
+                    else "lut")
+        elif mode == "auto":
+            mode = ("recon" if has_recon else "codes" if codes_ok
+                    else "lut")
+        elif mode == "codes" and not l2:
+            mode = "recon" if has_recon else "lut"
+        if traced:
+            # the LUT scan computes the codes kernel's distances, so a
+            # traced "codes" search answers the same
+            return plan("probe_recon" if mode == "recon" and has_recon
+                        else "lut")
+        if mode == "lut":
+            return plan("lut", "mode" if want_fused else "")
+        if mode == "codes":
+            if not codes_ok:
+                return plan("lut")
+            if not (pallas and pcs.supported_codes(
+                    l2, per_subspace, cap, rot, kt, nq, pq_dim, pq_bits)):
+                # no XLA twin of the codes kernel is worth running (it
+                # would decode every row): the LUT scan computes the
+                # same quantized distance
+                return plan("lut", codes_miss() if want_fused else "")
+            if not want_fused:
+                return plan("codes")
+            return (plan("fused_codes") if fused_codes_ok
+                    else plan("codes", codes_miss()))
+        if want_fused and fused_recon_ok:
+            return plan("fused_recon")
+        return plan("grouped_recon", recon_miss() if want_fused else "")
+
+    if mode in ("lut", "codes"):
+        # routed shards hold no packed codes; a stacked index holds them
+        # but no code lanes, so it answers both with the LUT scan (a
+        # formulation downgrade for "codes" on TPU)
+        if placement == "by_row" and pq_bits:
+            return plan("lut", lowered=mode == "codes" and on_tpu)
+        return plan("probe_recon", lowered=True)
+    if not (mode == "fused" or (mode == "auto" and on_tpu)):
+        return plan("probe_recon")
+    if fused_codes_ok:
+        return plan("fused_codes")
+    if fused_recon_ok:
+        return plan("fused_recon")
+    return plan("grouped_recon", recon_miss() if mode == "fused" else "")
+
+
 @auto_convert_output
 def search(res, params: SearchParams, index: Index, queries, k: int, *,
            filter=None) -> Tuple[jax.Array, jax.Array]:
     """Search (reference: ivf_pq.cuh:342).  Returns (distances, indices).
 
     ``params.scan_mode`` picks the list-scan formulation (see
-    :class:`SearchParams`); "codes" and "recon8" silently fall back to
-    the LUT / XLA formulations off-TPU or for unsupported shapes, so the
-    same call works on every backend.
+    :class:`SearchParams` and :func:`plan_scan`); "codes" and the fused
+    kernels silently fall back to the LUT / XLA formulations off-TPU or
+    for unsupported shapes, so the same call works on every backend.
 
     ``filter`` (a :class:`~raft_tpu.filters.SampleFilter` or an
     (nq, n_rows) bool mask — see docs/api.md, "Filtered search &
@@ -1600,10 +1625,10 @@ def search(res, params: SearchParams, index: Index, queries, k: int, *,
 
     .. note:: the first search may mutate ``index`` in place, lazily
        attaching derived caches (``list_recon``/``list_recon_sq``, the
-       codes-lane and int8 caches of their scan modes, the group count
-       and id-exactness caches); the derived caches are pytree leaves, so
-       the registered pytree structure can change after the first search
-       (one retrace for jitted closures over the index).
+       codes-lane cache of the codes scans, the id-exactness cache); the
+       derived caches are pytree leaves, so the registered pytree
+       structure can change after the first search (one retrace for
+       jitted closures over the index).
     """
     queries = ensure_array(queries, "queries")
     queries, ok_rows = _boundary.check_matrix(
@@ -1622,6 +1647,8 @@ def search(res, params: SearchParams, index: Index, queries, k: int, *,
 
 def _search_checked(res, params: SearchParams, index: Index, queries,
                     k: int, filter=None) -> Tuple[jax.Array, jax.Array]:
+    from raft_tpu.neighbors import grouped
+
     with named_range("ivf_pq::search"):
         fw = _fbits.query_filter_words(filter, queries.shape[0],
                                        "ivf_pq.search")
@@ -1630,68 +1657,24 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
         n_probes = min(params.n_probes, index.n_lists)
         coarse_rt = getattr(params, "coarse_recall_target", 0.95)
         exact_coarse = getattr(params, "exact_coarse", False)
-        mode = getattr(params, "scan_mode", "auto") or "auto"
-        if getattr(params, "use_reconstruction", None) is not None:
-            # compat override (pre-scan_mode API)
-            mode = "recon" if params.use_reconstruction else "lut"
-        expects(mode in _SCAN_MODES,
-                f"ivf_pq.search: unknown scan_mode {mode!r} "
-                f"(one of {_SCAN_MODES})")
-        kt_req = int(getattr(params, "per_probe_topk", 0) or 0)
-        packed = bool(getattr(params, "packed_extract", False))
+        nq = queries.shape[0]
+        on_tpu = _platform.on_tpu()
+        # queries or the Index pytree traced by an outer jit/vmap
+        traced = (isinstance(queries, jax.core.Tracer)
+                  or isinstance(index.centers, jax.core.Tracer))
+        plan = plan_scan(
+            params, placement="single", metric=index.metric,
+            cap=index.capacity, rot=index.rot_dim, lists=index.n_lists,
+            pq_bits=index.pq_bits, nq=nq, k=k, n_probes=n_probes,
+            on_tpu=on_tpu, pq_dim=index.pq_dim,
+            per_subspace=index.codebook_kind == CodebookKind.PER_SUBSPACE,
+            has_recon=index.list_recon is not None,
+            ids_exact=(on_tpu and not traced
+                       and grouped.ids_f32_exact(index, index.list_indices)),
+            group_est=index.group_est, traced=traced)
+        kt = plan.kt
 
-        # "fused" and "auto" both resolve to a BACKING mode (codes /
-        # recon / lut) that owns the derived caches and the fallback
-        # path; want_fused marks that the grouped dispatch should
-        # upgrade to the in-kernel top-k variant when the batch's shape
-        # supports it.  Every upgrade miss is counted
-        # (ivf_pq.search.fused_fallback) — the CI tripwire watches it.
-        want_fused = mode in ("auto", "fused")
-        if mode == "fused":
-            mode = ("codes" if _codes_mode_eligible(index)
-                    else "recon" if index.list_recon is not None
-                    else "lut")
-        if mode == "auto":
-            if index.list_recon is not None:
-                mode = "recon"
-            elif _codes_mode_eligible(index):
-                mode = "codes"
-            else:
-                mode = "lut"
-        if mode in ("codes", "recon8") and index.metric not in _L2_METRICS:
-            mode = "lut" if index.list_recon is None else "recon"
-
-        def note_fused_fallback(reason="backend"):
-            # reason codes (shared with distributed.ann): "dtype",
-            # "k-too-large", "bucket-too-wide", "itopk-gate" from the
-            # kernel reject helpers; "backend" for off-TPU / non-f32-id
-            # misses; "mode" when the backing mode has no fused variant.
-            if obs.enabled():
-                reg = obs.registry()
-                reg.counter("ivf_pq.search.fused_fallback").inc()
-                reg.counter(
-                    f"ivf_pq.search.fused_fallback.reason.{reason}").inc()
-            from raft_tpu.observability import flight as _flight
-            from raft_tpu.observability import trace as _rtrace
-            rec = _rtrace.current()
-            _flight.record_event("ivf_pq.fused_fallback", reason=reason,
-                                 trace_id=rec.trace_id if rec else None)
-
-        tracing = (isinstance(queries, jax.core.Tracer)
-                   or isinstance(index.centers, jax.core.Tracer))
-        if tracing:
-            # queries or the Index pytree traced by an outer jit/vmap:
-            # the grouped dispatch itself is shape-static since round 10,
-            # but a calibrated index's overflow re-dispatch gate is a
-            # host read that cannot run under a trace — use the fully
-            # traceable probe-order formulations instead (the LUT scan
-            # computes the same quantized distance as the codes kernel,
-            # so AOT-exported "codes" searches stay exact)
-            if mode in ("recon", "recon8") and index.list_recon is not None:
-                return _search_impl_recon(
-                    index.centers, index.list_recon, index.list_indices,
-                    index.rotation, queries, k, n_probes, index.metric,
-                    list_recon_sq=index.list_recon_sq, filter_words=fw)
+        def lut_scan():
             return _search_impl(index.centers, index.codebooks,
                                 index.list_codes, index.list_indices,
                                 index.rotation, queries, k, n_probes,
@@ -1702,30 +1685,28 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
                                 exact_coarse=exact_coarse,
                                 filter_words=fw)
 
-        def lut_scan():
+        if plan.form == "probe_recon":
+            return _search_impl_recon(
+                index.centers, index.list_recon, index.list_indices,
+                index.rotation, queries, k, n_probes, index.metric,
+                list_recon_sq=index.list_recon_sq, filter_words=fw)
+        if plan.form == "lut":
+            if traced:
+                return lut_scan()
             with obs.stage("ivf_pq.search.lut") as st:
-                out = _search_impl(index.centers, index.codebooks,
-                                   index.list_codes, index.list_indices,
-                                   index.rotation, queries, k, n_probes,
-                                   index.metric, index.codebook_kind,
-                                   jnp.dtype(params.lut_dtype).name,
-                                   pq_bits=index.pq_bits,
-                                   coarse_recall_target=coarse_rt,
-                                   exact_coarse=exact_coarse,
-                                   filter_words=fw)
+                out = lut_scan()
                 st.fence(out)
             return out
 
-        if mode == "lut":
-            if want_fused:
-                note_fused_fallback("mode")
-            return lut_scan()
-
-        from raft_tpu.neighbors import grouped
-        from raft_tpu.ops import pq_code_scan_pallas as pcs
-
         # ---- lazy derived caches (one-time per index) -------------------
-        if mode == "recon":
+        if plan.form in ("fused_codes", "codes"):
+            if index.list_code_lanes is None or index.list_code_rsq is None:
+                # the VMEM-LUT analogue of the reference's per-probe smem
+                # LUT build: here the scan tables are built once per index
+                with obs.stage("ivf_pq.search.lut_build") as st:
+                    index = _with_code_lanes(index)
+                    st.fence(index.list_code_lanes, index.list_code_rsq)
+        else:
             if index.list_recon is None:
                 # One-time materialization of the (n_lists, cap, rot_dim)
                 # bf16 cache on an index built without it; the cache stays
@@ -1740,50 +1721,6 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
                 index = _with_recon(res, index)
             if index.list_recon_sq is None:
                 index.list_recon_sq = _recon_sq(index.list_recon)
-        elif mode == "codes":
-            if not _codes_mode_eligible(index):
-                return lut_scan()
-            if index.list_code_lanes is None or index.list_code_rsq is None:
-                # the VMEM-LUT analogue of the reference's per-probe smem
-                # LUT build: here the scan tables are built once per index
-                with obs.stage("ivf_pq.search.lut_build") as st:
-                    index = _with_code_lanes(index)
-                    st.fence(index.list_code_lanes, index.list_code_rsq)
-        elif mode == "recon8":
-            if index.list_recon_i8 is None:
-                with obs.stage("ivf_pq.search.lut_build") as st:
-                    index = _with_recon8(index)
-                    st.fence(index.list_recon_i8)
-
-        cap = index.capacity
-        nq = queries.shape[0]
-        rot = index.rot_dim
-        kt = min(kt_req or k, cap)
-        from raft_tpu.ops import vmem_budget as vb
-        mw_req = vb.merge_window_request(
-            getattr(params, "merge_window", "auto"))
-        G = grouped.GROUP
-        on_tpu = _platform.on_tpu()
-        # the kernels carry candidate ids in f32 lanes — require every
-        # actual candidate id (incl. user-supplied extend ids) to be
-        # f32-exact, not just the row count
-        ids_ok = grouped.ids_f32_exact(index, index.list_indices)
-
-        if mode == "codes" and not (
-                on_tpu and ids_ok
-                and pcs.supported_codes(True, True, cap, rot, kt, nq,
-                                        index.pq_dim, index.pq_bits,
-                                        packed)):
-            # no XLA twin of the codes kernel is worth running (it would
-            # re-decode every row anyway) — the LUT formulation computes
-            # the same quantized distance
-            if want_fused:
-                note_fused_fallback(
-                    "backend" if not (on_tpu and ids_ok) else
-                    pcs.fused_codes_reject_reason(
-                        True, True, cap, rot, kt, k, nq, index.pq_dim,
-                        index.pq_bits) or "bucket-too-wide")
-            return lut_scan()
 
         with obs.stage("ivf_pq.search.coarse") as st:
             probes = _select_clusters(index.centers, index.rotation,
@@ -1800,19 +1737,18 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
         # overflow count, enqueued BEFORE the scan so the read overlaps
         # the scan's execution; only the rare batch whose probe skew
         # exceeds the calibrated bound pays a second pass.
-        n_groups, exact = grouped.group_capacity(
-            nq, n_probes, index.n_lists, est=index.group_est)
-        needed_dev = (None if exact
+        needed_dev = (None if plan.exact
                       else grouped.num_groups(probes, index.n_lists))
+        fused = plan.form in ("fused_codes", "fused_recon")
 
-        def run_grouped(stage_label, dispatch, fused=False):
+        def run_grouped(stage_label, dispatch):
             with obs.stage(stage_label) as st:
-                out = dispatch(n_groups)
-                sizes = [n_groups]
+                out = dispatch(plan.n_groups)
+                sizes = [plan.n_groups]
                 overflow = False
                 if needed_dev is not None:
                     with _tracing.annotation("ivf_pq.search.group_sync"):
-                        overflow = int(needed_dev) > n_groups
+                        overflow = int(needed_dev) > plan.n_groups
                 if overflow:
                     # calibrated capacity exceeded: tick the overflow
                     # counter and re-dispatch at the worst-case bound,
@@ -1820,10 +1756,8 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
                     if obs.enabled():
                         obs.registry().counter(
                             "ivf_pq.search.group_overflow").inc()
-                    worst, _ = grouped.group_capacity(
-                        nq, n_probes, index.n_lists)
-                    out = dispatch(worst)
-                    sizes.append(worst)
+                    out = dispatch(plan.worst_groups)
+                    sizes.append(plan.worst_groups)
                 st.fence(out)
             if fused and obs.enabled():
                 _note_skipped_groups(sizes, needed_dev if needed_dev
@@ -1831,86 +1765,33 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
                                          probes, index.n_lists))
             return out
 
-        if mode == "codes":
-            if want_fused:
-                if pcs.supported_fused_codes(True, True, cap, rot, kt, k,
-                                             nq, index.pq_dim,
-                                             index.pq_bits,
-                                             merge_window=mw_req):
-                    # one stage where code_scan + extraction used to be
-                    # two: the kernel output IS the final top-k.  The
-                    # merge window is resolved host-statically from the
-                    # same shapes the gate saw (never from n_groups), so
-                    # the overflow re-dispatch reuses the choice.
-                    mw = pcs.fused_codes_merge_window(
-                        cap, rot, kt, k, nq, index.pq_dim, index.pq_bits,
-                        requested=mw_req)
-                    return run_grouped(
-                        "ivf_pq.search.fused_scan",
-                        lambda ng: _search_impl_fused_codes_grouped(
-                            index.centers, index.codebooks,
-                            index.list_code_lanes, index.list_code_rsq,
-                            index.list_indices, index.rotation, queries,
-                            probes, k, kt, index.metric, ng,
-                            index.pq_bits, merge_window=mw,
-                            filter_words=fw), fused=True)
-                note_fused_fallback(pcs.fused_codes_reject_reason(
-                    True, True, cap, rot, kt, k, nq, index.pq_dim,
-                    index.pq_bits, merge_window=mw_req)
-                    or "bucket-too-wide")
+        if plan.form == "fused_codes":
+            # one stage where code_scan + extraction used to be two: the
+            # kernel output IS the final top-k
+            return run_grouped(
+                "ivf_pq.search.fused_scan",
+                lambda ng: _search_impl_fused_codes_grouped(
+                    index.centers, index.codebooks, index.list_code_lanes,
+                    index.list_code_rsq, index.list_indices, index.rotation,
+                    queries, probes, k, kt, index.metric, ng, index.pq_bits,
+                    filter_words=fw))
+        if plan.form == "codes":
             return run_grouped(
                 "ivf_pq.search.code_scan",
                 lambda ng: _search_impl_codes_grouped(
                     index.centers, index.codebooks, index.list_code_lanes,
                     index.list_code_rsq, index.list_indices, index.rotation,
                     queries, probes, k, kt, index.metric, ng,
-                    index.pq_bits, packed=packed, filter_words=fw))
-
-        if mode == "recon8":
-            rot_pad = index.list_recon_i8.shape[2]
-            use_pallas = (on_tpu and ids_ok
-                          and pcs.supported_recon8(True, cap, rot, kt, nq,
-                                                   packed))
-
-            def dispatch8(ng):
-                block = grouped.block_size(
-                    ng,
-                    G * cap * 8,          # fp32 distances + broadcast ids
-                    cap * rot_pad * 3,    # int8 slice + bf16 upcast
-                    G * rot_pad * 4)      # query gather
-                return _search_impl_recon8_grouped(
-                    index.centers, index.list_recon_i8,
-                    index.list_recon_scale, index.list_recon_i8_sq,
+                    index.pq_bits, filter_words=fw))
+        if plan.form == "fused_recon":
+            return run_grouped(
+                "ivf_pq.search.fused_scan",
+                lambda ng: _search_impl_fused_recon_grouped(
+                    index.centers, index.list_recon, index.list_recon_sq,
                     index.list_indices, index.rotation, queries, probes, k,
-                    kt, index.metric, ng, block, use_pallas=use_pallas,
-                    packed=packed, filter_words=fw)
+                    kt, index.metric, ng, filter_words=fw))
 
-            return run_grouped("ivf_pq.search.recon8_scan", dispatch8)
-
-        use_pallas = on_tpu and ids_ok
-
-        if want_fused:
-            from raft_tpu.ops import pq_group_scan_pallas as pqp
-
-            if use_pallas and pqp.supported_fused(
-                    index.metric in _L2_METRICS, cap, rot, kt, k, nq,
-                    merge_window=mw_req):
-                mw = pqp.fused_merge_window(cap, rot, kt, k, nq,
-                                            requested=mw_req)
-                return run_grouped(
-                    "ivf_pq.search.fused_scan",
-                    lambda ng: _search_impl_fused_recon_grouped(
-                        index.centers, index.list_recon,
-                        index.list_recon_sq, index.list_indices,
-                        index.rotation, queries, probes, k, kt,
-                        index.metric, ng, merge_window=mw,
-                        filter_words=fw), fused=True)
-            note_fused_fallback(
-                "backend" if not use_pallas else
-                pqp.fused_reject_reason(index.metric in _L2_METRICS, cap,
-                                        rot, kt, k, nq,
-                                        merge_window=mw_req)
-                or "bucket-too-wide")
+        cap, rot, G = index.capacity, index.rot_dim, grouped.GROUP
 
         def dispatch(ng):
             block = grouped.block_size(
@@ -1921,7 +1802,7 @@ def _search_checked(res, params: SearchParams, index: Index, queries,
             return _search_impl_recon_grouped(
                 index.centers, index.list_recon, index.list_recon_sq,
                 index.list_indices, index.rotation, queries, probes, k,
-                index.metric, ng, block, use_pallas=use_pallas, kt=kt,
+                index.metric, ng, block, use_pallas=plan.use_pallas, kt=kt,
                 filter_words=fw)
 
         return run_grouped("ivf_pq.search.scan", dispatch)

@@ -20,19 +20,11 @@ bytes/row.  This module is the TPU analogue, two kernels:
   MACs than contracting a per-query LUT against the one-hots
   (pq_dim·G·book·cap vs pq_dim·pq_len·book·cap + G·rot·cap).  The bf16
   codebook cast makes the decoded values bit-identical to the bf16 recon
-  cache, so distances match the recon kernel's.
-- **int8 recon scan** (:func:`grouped_recon8_scan`): the second traffic
-  lever — the recon cache quantized to int8 with a per-list scale
-  (1 byte/dim/row); the kernel dequantizes in-register
-  (``d = ||sub||² + rsq8 − 2·scale·(sub·q8)``).
-
-Both reuse the recon kernel's one-hot query gather and top-kt
-extraction; an opt-in **packed-key extraction** (:func:`_extract_topk_packed`)
-halves the cross-lane reduces per pass by packing (distance bits | column)
-into one int32 key — valid for L2 (d ≥ 0 makes the f32 bit pattern
-order-isomorphic to int order); value truncation is ≤ ceil(log2 cap)
-mantissa bits (~2⁻¹³ relative at cap 1024), far under PQ quantization
-noise, and the exact-refine pass recomputes distances anyway.
+  cache, so distances match the recon kernel's.  It reuses the recon
+  kernel's one-hot query gather and top-kt extraction.
+- **fused code scan** (:func:`grouped_code_scan_fused`): the same decode
+  and distance block feeding the recon scan's row-addressed per-query
+  top-k merge (``pq_group_scan_pallas._fused_merge``).
 
 Codes must not straddle int32 words for the in-register unpack to be one
 shift+mask: gated to ``32 % pq_bits == 0`` → pq_bits ∈ {4, 8} (the
@@ -138,62 +130,9 @@ def _decode_reconT(codes_ref, cb_ref, pq_dim, pq_bits, rot_pad, cap):
     return jnp.concatenate(parts, axis=0)                # (rot_pad, cap)
 
 
-def _extract_topk_packed(d, ids_row, vals_ref, ids_out_ref, vscratch,
-                         pscratch, kt, cap_bits, adm=None):
-    """Packed-key top-kt: ONE cross-lane reduce per selection pass.
-
-    L2 distances are >= 0, so their f32 bit patterns order like ints;
-    ``key = (bits(d) & ~col_mask) | col`` makes each pass a single int
-    min-reduce with a built-in lowest-column tie-break (vs the standard
-    extraction's max + argmin + id reduces).  Values lose the low
-    ``cap_bits`` mantissa bits; columns decode exactly, and the
-    column -> global-id mapping runs once per selected slot after the
-    selection loop.  Sentinel/exhausted slots surface as INT32_MAX keys
-    and are emitted as +inf values (the shared caller contract)."""
-    cap = d.shape[1]
-    col_mask = (1 << cap_bits) - 1
-    inf_bits = jnp.int32(0x7F800000)
-    int_max = jnp.int32(2**31 - 1)
-    col = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
-    invalid = (ids_row < 0)[None, :]
-    if adm is not None:
-        # admission folds through the same INT32_MAX key sentinel as a
-        # tombstone — rejected before any selection pass
-        invalid = invalid | (adm == 0)
-    bits = jax.lax.bitcast_convert_type(d, jnp.int32)
-    key = jnp.where(invalid, int_max, (bits & ~col_mask) | col)
-    ids_f = ids_row.astype(jnp.float32)
-
-    picked = []
-    for _ in range(kt):
-        m = jnp.min(key, axis=1)                         # (G,) int32
-        key = jnp.where(key == m[:, None], int_max, key)
-        picked.append(m)
-    for j, m in enumerate(picked):
-        vj = jax.lax.bitcast_convert_type(m & ~col_mask, jnp.float32)
-        vj = jnp.where(m >= inf_bits, jnp.inf, vj)
-        sel = col == (m & col_mask)[:, None]
-        gid = jnp.max(jnp.where(sel, ids_f[None, :], -jnp.inf), axis=1)
-        vscratch[:, j] = vj
-        pscratch[:, j] = gid.astype(jnp.int32)
-    vals_ref[0] = vscratch[:, :]
-    ids_out_ref[0] = pscratch[:, :]
-
-
-def _extract(d, ids_ref, vals_ref, ids_out_ref, vscratch, pscratch, kt,
-             packed, cap_bits, adm=None):
-    ids_row = ids_ref[0, 0]                              # (cap,) int32
-    if packed:
-        _extract_topk_packed(d, ids_row, vals_ref, ids_out_ref, vscratch,
-                             pscratch, kt, cap_bits, adm=adm)
-    else:
-        _extract_topk(d, ids_row, vals_ref, ids_out_ref, vscratch,
-                      pscratch, kt, adm=adm)
-
-
 def _kernel_codes(gl_ref, slot_ref, qrot_ref, cf_ref, codes_ref, cb_ref,
                   rsq_ref, ids_ref, *rest, kt, n_probes, P, pq_dim,
-                  pq_bits, packed, cap_bits, has_adm=False):
+                  pq_bits, has_adm=False):
     adm_ref, rest = (rest[0], rest[1:]) if has_adm else (None, rest)
     vals_ref, ids_out_ref, vscratch, pscratch = rest
     qv = _gather_queries(slot_ref, qrot_ref, n_probes, P)
@@ -208,28 +147,8 @@ def _kernel_codes(gl_ref, slot_ref, qrot_ref, cf_ref, codes_ref, cb_ref,
     d = sub_sq[:, None] + rsq_ref[0, 0][None, :] - 2.0 * ip
     d = jnp.maximum(d, 0.0)
     adm = _unpack_admission(adm_ref, cap) if has_adm else None
-    _extract(d, ids_ref, vals_ref, ids_out_ref, vscratch, pscratch, kt,
-             packed, cap_bits, adm=adm)
-
-
-def _kernel_recon8(gl_ref, slot_ref, qrot_ref, cf_ref, data_ref, scale_ref,
-                   rsq_ref, ids_ref, *rest, kt, n_probes, P, packed,
-                   cap_bits, has_adm=False):
-    adm_ref, rest = (rest[0], rest[1:]) if has_adm else (None, rest)
-    vals_ref, ids_out_ref, vscratch, pscratch = rest
-    qv = _gather_queries(slot_ref, qrot_ref, n_probes, P)
-    sub = qv - cf_ref[0, 0][None, :]                     # (G, rot_pad) f32
-    sub_sq = jnp.sum(sub * sub, axis=1)                  # (G,)
-    data = data_ref[0].astype(jnp.bfloat16)              # (cap, rot_pad)
-    scale = scale_ref[0, 0, 0]                           # f32 scalar
-    ip = jax.lax.dot_general(sub.astype(jnp.bfloat16), data,
-                             (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    d = sub_sq[:, None] + rsq_ref[0, 0][None, :] - 2.0 * scale * ip
-    d = jnp.maximum(d, 0.0)
-    adm = _unpack_admission(adm_ref, d.shape[1]) if has_adm else None
-    _extract(d, ids_ref, vals_ref, ids_out_ref, vscratch, pscratch, kt,
-             packed, cap_bits, adm=adm)
+    _extract_topk(d, ids_ref[0, 0], vals_ref, ids_out_ref, vscratch,
+                  pscratch, kt, adm=adm)
 
 
 def _kernel_codes_fused(gl_ref, nlive_ref, qid_ref, q_hbm, cf_ref,
@@ -275,23 +194,20 @@ def _kernel_codes_fused(gl_ref, nlive_ref, qid_ref, q_hbm, cf_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("kt", "k", "n_probes",
-                                             "pq_bits", "interpret",
-                                             "merge_window"))
+                                             "pq_bits", "interpret"))
 def grouped_code_scan_fused(group_list, slot_pairs, qrot, centers_f32,
                             codes_lanes, codebooks, rsq, list_indices, kt,
                             k, n_probes, pq_bits, interpret=False,
-                            merge_window=1, adm_words=None):
+                            adm_words=None):
     """Fused compact-code scan with IN-KERNEL per-query top-k.
 
     Inputs as :func:`grouped_code_scan`; output contract as
     ``pq_group_scan_pallas.grouped_l2_scan_fused`` — the batch's final
     ``(vals (nq_pad, k) f32, ids (nq_pad, k) int32)``, ascending per
-    row, exhausted ranks at the finite ``_ACC_WORST`` sentinel;
-    ``merge_window`` is accepted and unused there too.
+    row, exhausted ranks at the finite ``_ACC_WORST`` sentinel.
     ``adm_words`` (n_groups, GROUP, ceil(cap/32)) int32 streams packed
     per-(slot, candidate) admission bits (filtered search).
     """
-    del merge_window
     nq, rot = qrot.shape
     _, _, cap = codes_lanes.shape
     pq_dim, book, pq_len = codebooks.shape
@@ -331,16 +247,11 @@ def _pad_lanes(x, width):
                    ((0, 0), (0, width - x.shape[-1])))
 
 
-def _cap_bits(cap: int) -> int:
-    return max((cap - 1).bit_length(), 1)
-
-
 @functools.partial(jax.jit, static_argnames=("kt", "n_probes", "pq_bits",
-                                             "packed", "interpret"))
+                                             "interpret"))
 def grouped_code_scan(group_list, slot_pairs, qrot, centers_f32,
                       codes_lanes, codebooks, rsq, list_indices, kt,
-                      n_probes, pq_bits, packed=False, interpret=False,
-                      adm_words=None):
+                      n_probes, pq_bits, interpret=False, adm_words=None):
     """Fused grouped scan over packed PQ codes + local top-kt.
 
     Same contract as ``pq_group_scan_pallas.grouped_l2_scan`` with the
@@ -396,8 +307,7 @@ def grouped_code_scan(group_list, slot_pairs, qrot, centers_f32,
     )
     vals, gids = pl.pallas_call(
         functools.partial(_kernel_codes, kt=kt, n_probes=n_probes, P=P,
-                          pq_dim=pq_dim, pq_bits=pq_bits, packed=packed,
-                          cap_bits=_cap_bits(cap), has_adm=has_adm),
+                          pq_dim=pq_dim, pq_bits=pq_bits, has_adm=has_adm),
         out_shape=[
             jax.ShapeDtypeStruct((n_groups, GROUP, kt), jnp.float32),
             jax.ShapeDtypeStruct((n_groups, GROUP, kt), jnp.int32),
@@ -406,78 +316,6 @@ def grouped_code_scan(group_list, slot_pairs, qrot, centers_f32,
         interpret=interpret,
     )(*inputs)
     return vals, gids
-
-
-@functools.partial(jax.jit, static_argnames=("kt", "n_probes", "packed",
-                                             "interpret"))
-def grouped_recon8_scan(group_list, slot_pairs, qrot, centers_f32,
-                        recon_i8, scales, rsq8, list_indices, kt, n_probes,
-                        packed=False, interpret=False, adm_words=None):
-    """Fused grouped scan over the int8-quantized recon cache.
-
-    ``recon_i8`` (n_lists, cap, rot_pad) int8 with lanes already
-    128-padded (see ivf_pq._with_recon8), ``scales`` (n_lists,) f32
-    per-list dequant scales, ``rsq8`` (n_lists, cap) f32 row norms of
-    the DEQUANTIZED rows (so distances are consistent with the in-kernel
-    dequant).  Same output contract as ``grouped_l2_scan``.
-    """
-    n_groups = group_list.shape[0]
-    nq, rot = qrot.shape
-    _, cap, rot_pad = recon_i8.shape
-    P = nq * n_probes
-
-    nq_pad = _round_up(nq + 1, 128)
-    qrot_pad = query_table(qrot, nq_pad, rot_pad)
-    cf_pad = _pad_lanes(centers_f32, rot_pad)
-
-    has_adm = adm_words is not None
-    in_specs = [
-        pl.BlockSpec((1, 1, GROUP), lambda g, gl: (g, 0, 0)),
-        pl.BlockSpec((3, nq_pad, rot_pad), lambda g, gl: (0, 0, 0)),
-        pl.BlockSpec((1, 1, rot_pad), lambda g, gl: (gl[g], 0, 0)),
-        pl.BlockSpec((1, cap, rot_pad), lambda g, gl: (gl[g], 0, 0)),
-        pl.BlockSpec((1, 1, 1), lambda g, gl: (gl[g], 0, 0)),
-        pl.BlockSpec((1, 1, cap), lambda g, gl: (gl[g], 0, 0)),
-        pl.BlockSpec((1, 1, cap), lambda g, gl: (gl[g], 0, 0)),
-    ]
-    inputs = [group_list, slot_pairs[:, None, :], qrot_pad,
-              cf_pad[:, None, :], recon_i8,
-              scales.astype(jnp.float32)[:, None, None],
-              rsq8[:, None, :], list_indices[:, None, :]]
-    if has_adm:
-        wc = adm_words.shape[2]
-        in_specs.append(pl.BlockSpec((1, GROUP, wc),
-                                     lambda g, gl: (g, 0, 0)))
-        inputs.append(adm_words)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_groups,),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, GROUP, kt), lambda g, gl: (g, 0, 0)),
-            pl.BlockSpec((1, GROUP, kt), lambda g, gl: (g, 0, 0)),
-        ],
-        scratch_shapes=_scratch_shapes(kt),
-    )
-    vals, gids = pl.pallas_call(
-        functools.partial(_kernel_recon8, kt=kt, n_probes=n_probes, P=P,
-                          packed=packed, cap_bits=_cap_bits(cap),
-                          has_adm=has_adm),
-        out_shape=[
-            jax.ShapeDtypeStruct((n_groups, GROUP, kt), jnp.float32),
-            jax.ShapeDtypeStruct((n_groups, GROUP, kt), jnp.int32),
-        ],
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(*inputs)
-    return vals, gids
-
-
-def _extract_ok(kt: int, packed: bool) -> bool:
-    # the packed variant is unrolled-only; the generic path also serves
-    # the fori_loop regime up to _KT_MAX
-    return 0 < kt <= (_KT_UNROLL if packed else _KT_MAX)
 
 
 def _decode_bytes(cap: int, rot: int, pq_dim: int, pq_bits: int) -> int:
@@ -495,8 +333,8 @@ def _decode_bytes(cap: int, rot: int, pq_dim: int, pq_bits: int) -> int:
 
 
 def supported_codes(metric_is_l2: bool, per_subspace: bool, cap: int,
-                    rot: int, kt: int, nq: int, pq_dim: int, pq_bits: int,
-                    packed: bool = False) -> bool:
+                    rot: int, kt: int, nq: int, pq_dim: int,
+                    pq_bits: int) -> bool:
     """Shapes/configs the code-scan kernel handles.
 
     pq_bits must divide 32 (in-register unpack is one static shift+mask
@@ -515,7 +353,7 @@ def supported_codes(metric_is_l2: bool, per_subspace: bool, cap: int,
     vmem = (2 * nq_pad * rot_pad * 4            # query table + one-hot
             + _decode_bytes(cap, rot, pq_dim, pq_bits)
             + 2 * GROUP * cap * 4)              # distances + extraction
-    return (cap % 16 == 0 and GROUP % 16 == 0 and _extract_ok(kt, packed)
+    return (cap % 16 == 0 and GROUP % 16 == 0 and 0 < kt <= _KT_MAX
             and nq <= 6144 and vmem <= _VMEM_BUDGET)
 
 
@@ -528,38 +366,26 @@ def _fused_codes_bytes(cap: int, rot: int, kt: int, k: int, nq: int,
         + _decode_bytes(cap, rot, pq_dim, pq_bits))
 
 
-def fused_codes_merge_window(cap: int, rot: int, kt: int, k: int, nq: int,
-                             pq_dim: int, pq_bits: int,
-                             requested: int = 0) -> int:
-    """Host-static merge window for the fused codes scan: 1 where its
-    VMEM model (resident query table and accumulator, row blocks,
-    streamed codes, decode) fits, 0 where it does not; the kernel
-    merges every step, as the recon scan does."""
-    return vb.fused_scan_window(
-        requested, _fused_codes_bytes(cap, rot, kt, k, nq, pq_dim, pq_bits))
-
-
 def supported_fused_codes(metric_is_l2: bool, per_subspace: bool, cap: int,
                           rot: int, kt: int, k: int, nq: int, pq_dim: int,
-                          pq_bits: int, merge_window: int = 0) -> bool:
+                          pq_bits: int) -> bool:
     """Shapes the FUSED code-scan kernel handles: the static
-    :func:`supported_codes` preconditions (generic extraction — the
-    packed-key variant has no fused twin) plus the fused kernel's own
-    VMEM model (:func:`fused_codes_merge_window`); kt stays unrolled
-    while k extends to ``vmem_budget.FUSED_K_MAX`` through the looped
-    per-step merge."""
+    :func:`supported_codes` preconditions plus the fused kernel's own
+    VMEM model (resident query table and accumulator, row blocks,
+    streamed codes, decode — :func:`vmem_budget.fused_scan_fits`); kt
+    stays unrolled while k extends to ``vmem_budget.FUSED_K_MAX``
+    through the looped per-step merge."""
     if not supported_codes(metric_is_l2, per_subspace, cap, rot, kt, nq,
-                           pq_dim, pq_bits, packed=False):
+                           pq_dim, pq_bits):
         return False
     return (0 < kt <= _KT_UNROLL and 0 < k <= vb.FUSED_K_MAX
-            and fused_codes_merge_window(cap, rot, kt, k, nq, pq_dim,
-                                         pq_bits, merge_window) > 0)
+            and vb.fused_scan_fits(_fused_codes_bytes(
+                cap, rot, kt, k, nq, pq_dim, pq_bits)))
 
 
 def fused_codes_reject_reason(metric_is_l2: bool, per_subspace: bool,
                               cap: int, rot: int, kt: int, k: int, nq: int,
-                              pq_dim: int, pq_bits: int,
-                              merge_window: int = 0) -> str:
+                              pq_dim: int, pq_bits: int) -> str:
     """Reason code for a fused-codes gate miss ('' when supported):
     'dtype' (metric / codebook layout / pq_bits), 'k-too-large' (k/kt
     bounds), 'bucket-too-wide' (batch, alignment, or VMEM)."""
@@ -569,22 +395,6 @@ def fused_codes_reject_reason(metric_is_l2: bool, per_subspace: bool,
     if not (0 < kt <= _KT_UNROLL and 0 < k <= vb.FUSED_K_MAX):
         return "k-too-large"
     if supported_fused_codes(metric_is_l2, per_subspace, cap, rot, kt, k,
-                             nq, pq_dim, pq_bits, merge_window):
+                             nq, pq_dim, pq_bits):
         return ""
     return "bucket-too-wide"
-
-
-def supported_recon8(metric_is_l2: bool, cap: int, rot: int, kt: int,
-                     nq: int, packed: bool = False) -> bool:
-    """Shapes the int8 recon kernel handles: int8 tiles are (32, 128), so
-    cap must be 32-aligned (the list allocator's _LIST_ALIGN guarantees
-    it); rot is lane-padded internally."""
-    rot_pad = _round_up(rot, 128)
-    nq_pad = _round_up(nq + 1, 128)
-    vmem = (2 * nq_pad * rot_pad * 4
-            + cap * rot_pad * 1                 # int8 data block
-            + cap * rot_pad * 2                 # bf16 dequant transient
-            + 2 * GROUP * cap * 4)
-    return (metric_is_l2 and cap % 32 == 0 and GROUP % 16 == 0
-            and _extract_ok(kt, packed) and nq <= 6144
-            and vmem <= _VMEM_BUDGET)

@@ -518,10 +518,10 @@ def fused_scan_call(kernel, *, group_list, slot_pairs, n_probes, P,
 
 
 @functools.partial(jax.jit, static_argnames=("kt", "k", "n_probes",
-                                             "interpret", "merge_window"))
+                                             "interpret"))
 def grouped_l2_scan_fused(group_list, slot_pairs, qrot, centers_f32,
                           list_recon, rec_sq, list_indices, kt, k, n_probes,
-                          interpret=False, merge_window=1, adm_words=None):
+                          interpret=False, adm_words=None):
     """Fused grouped recon scan with IN-KERNEL per-query top-k.
 
     Inputs as :func:`grouped_l2_scan`; instead of per-pair winners the
@@ -534,15 +534,10 @@ def grouped_l2_scan_fused(group_list, slot_pairs, qrot, centers_f32,
     contributes at most its local top-kt per pair before the merge, so
     results match the scatter+select reference at matched kt.
 
-    ``merge_window`` is accepted for the callers' plans and cache keys;
-    the kernel merges every grid step whatever its value (see
-    :func:`fused_merge_window`).
-
     ``adm_words`` (n_groups, GROUP, ceil(cap/32)) int32 streams packed
     per-(slot, candidate) admission bits (filtered search): rejected
     candidates fold to the finite sentinel before the merge.
     """
-    del merge_window
     nq, rot = qrot.shape
     _, cap, _ = list_recon.shape
     stream_specs = [
@@ -592,32 +587,20 @@ def _fused_static_ok(metric_is_l2: bool, cap: int, rot: int, kt: int,
             and 0 < k <= vb.FUSED_K_MAX and nq <= 6144)
 
 
-def fused_merge_window(cap: int, rot: int, kt: int, k: int, nq: int,
-                       data_elem_bytes: int = 2, requested: int = 0) -> int:
-    """Host-static merge window for the fused recon scan at this shape:
-    1 where its VMEM model fits, 0 where none does (fused unsupported).
-    ``requested`` is the public knob (0 auto, n >= 1 an upper bound);
-    the kernel merges every step, so any accepted request gives 1."""
-    return vb.fused_scan_window(
-        requested, _fused_bytes(cap, rot, kt, k, nq, data_elem_bytes))
-
-
 def supported_fused(metric_is_l2: bool, cap: int, rot: int, kt: int,
-                    k: int, nq: int, data_elem_bytes: int = 2,
-                    merge_window: int = 0) -> bool:
+                    k: int, nq: int, data_elem_bytes: int = 2) -> bool:
     """Shapes the fused recon kernel handles.  Beyond :func:`supported`:
     the resident query table and (nq_pad, acc_lanes(k)) accumulator pair
     join the VMEM model (:mod:`raft_tpu.ops.vmem_budget`); kt stays in
     the unrolled regime while k extends to ``FUSED_K_MAX`` through the
     looped per-step merge."""
     return (_fused_static_ok(metric_is_l2, cap, rot, kt, k, nq)
-            and fused_merge_window(cap, rot, kt, k, nq, data_elem_bytes,
-                                   merge_window) > 0)
+            and vb.fused_scan_fits(
+                _fused_bytes(cap, rot, kt, k, nq, data_elem_bytes)))
 
 
 def fused_reject_reason(metric_is_l2: bool, cap: int, rot: int, kt: int,
-                        k: int, nq: int, data_elem_bytes: int = 2,
-                        merge_window: int = 0) -> str:
+                        k: int, nq: int, data_elem_bytes: int = 2) -> str:
     """Reason code for a fused-recon gate miss ('' when supported):
     'dtype' (metric), 'k-too-large' (k/kt bounds), 'bucket-too-wide'
     (batch, layout, or VMEM).  Drives the ``fused_fallback`` counter
@@ -629,8 +612,8 @@ def fused_reject_reason(metric_is_l2: bool, cap: int, rot: int, kt: int,
     if not (rot % 128 == 0 and cap % 16 == 0 and GROUP % 16 == 0
             and nq <= 6144):
         return "bucket-too-wide"
-    if fused_merge_window(cap, rot, kt, k, nq, data_elem_bytes,
-                          merge_window) <= 0:
+    if not vb.fused_scan_fits(
+            _fused_bytes(cap, rot, kt, k, nq, data_elem_bytes)):
         return "bucket-too-wide"
     return ""
 

@@ -27,7 +27,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-# requested merge_window sentinel: the selectors pick the window
+# requested merge_window sentinel of the fused CAGRA hop: the selector
+# picks the window
 MERGE_WINDOW_AUTO = 0
 # k ceiling of the fused scans: past the unrolled merge the per-step
 # merge runs as a fori_loop over sublane-stacked rows
@@ -44,11 +45,9 @@ def round_up(x: int, m: int) -> int:
 
 
 def merge_window_request(value) -> int:
-    """Normalize the public ``merge_window`` knob ("auto" | int) to the
-    integer request the selectors take: 0 = auto, n >= 1 = upper bound.
-    Every caller (ivf_pq / cagra SearchParams, distributed.ann, AOT
-    exports) parses the knob through here so the accepted spellings
-    cannot drift."""
+    """Normalize the public ``cagra.SearchParams.merge_window`` knob
+    ("auto" | int) to the integer request :func:`select_hop_window`
+    takes: 0 = auto, n >= 1 = upper bound."""
     if value is None or value == "auto":
         return MERGE_WINDOW_AUTO
     w = int(value)
@@ -87,14 +86,10 @@ def fused_scan_bytes(k: int, kt: int, nq_pad: int, width: int, group: int,
             + stream_bytes)
 
 
-def fused_scan_window(requested: int, total_bytes: int) -> int:
-    """Host-static merge window of a fused scan: 1 where the shape's
-    VMEM model fits :data:`FUSED_SCAN_BUDGET`, 0 where it does not
-    (callers treat 0 as "fused unsupported at this shape").  The scans
-    merge every grid step, so any accepted request resolves to 1."""
-    if requested < 0 or total_bytes > FUSED_SCAN_BUDGET:
-        return 0
-    return 1
+def fused_scan_fits(total_bytes: int) -> bool:
+    """Whether a fused scan whose VMEM model totals ``total_bytes`` fits
+    :data:`FUSED_SCAN_BUDGET` (the scans' admission test)."""
+    return total_bytes <= FUSED_SCAN_BUDGET
 
 
 def fused_scan_vmem_limit(total_bytes: int) -> int:

@@ -220,22 +220,14 @@ class Executor:
         fkw = ({"n_filter_words": self.n_filter_words}
                if self.n_filter_words else {})
         if self.kind == "ivf_pq":
-            from raft_tpu.ops import vmem_budget as vb
             n_probes = min(params.n_probes, index.n_lists)
             mode = getattr(params, "scan_mode", "auto")
             if mode not in ("recon", "codes", "lut", "fused"):
                 mode = ("recon" if index.list_recon is not None
                         else "lut")
-            # the merge window is an export specialization like the
-            # bucket shape: it rides the ExecutableCache key (via the
-            # sorted export kwargs) so warmup compiles one executable
-            # per (bucket, k, merge_window) point and a steady-state
-            # window change can never alias onto a warm entry
-            mw = vb.merge_window_request(
-                getattr(params, "merge_window", "auto"))
             return cache.get("ivf_pq", self.res, index, batch=bucket,
                              k=k, n_probes=n_probes, scan_mode=mode,
-                             rung=rung, merge_window=mw, **fkw)
+                             rung=rung, **fkw)
         if self.kind == "ivf_flat":
             n_probes = min(params.n_probes, index.n_lists)
             return cache.get("ivf_flat", self.res, index, batch=bucket,

@@ -126,7 +126,7 @@ def ground_truth_search_params(kind: str, index, params=None):
                 else "lut")
         return dataclasses.replace(
             base, n_probes=int(index.n_lists), scan_mode=mode,
-            per_probe_topk=0, exact_coarse=True, use_reconstruction=None)
+            per_probe_topk=0, exact_coarse=True)
     if kind == "ivf_flat":
         from raft_tpu.neighbors import ivf_flat as _flat
         base = params if params is not None else _flat.SearchParams()

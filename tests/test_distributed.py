@@ -463,9 +463,10 @@ class TestRoutedAnn:
         np.testing.assert_array_equal(
             np.asarray(status), np.full(8, ann.SHARD_OK, np.int8))
         assert np.asarray(i).min() >= 0
-        # recon8 stays a genuine lowering under the routed path and
-        # keeps the FALLBACK status visible to callers
-        sp = ivf_pq.SearchParams(n_probes=8, scan_mode="recon8")
+        # lut stays a genuine lowering under the routed path (its
+        # shards hold no packed codes) and keeps the FALLBACK status
+        # visible to callers
+        sp = ivf_pq.SearchParams(n_probes=8, scan_mode="lut")
         _, i, status = ann.search(rhandle, sp, ridx, q, self.K,
                                   return_status=True)
         np.testing.assert_array_equal(

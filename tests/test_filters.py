@@ -3,7 +3,7 @@
 The filtered-parity contract is the backbone: a filtered search at full
 probe must be BIT-IDENTICAL to taking the unfiltered result at a huge k
 and dropping inadmissible rows post-hoc — on every scan formulation
-(lut / recon / codes / recon8 / fused), on brute force, on ivf_flat,
+(lut / recon / codes / fused), on brute force, on ivf_flat,
 and across the routed distributed dispatch.  Everything else (tenancy,
 hybrid dense+sparse, serving integration, zero-recompile) layers on
 that seam.
@@ -123,7 +123,7 @@ class TestBitset:
 
 class TestFilteredParity:
     @pytest.mark.parametrize(
-        "mode", ["lut", "recon", "codes", "recon8", "fused"])
+        "mode", ["lut", "recon", "codes", "fused"])
     def test_scan_mode_bit_identical_to_posthoc(self, mres, pq_index,
                                                 dataset, mode):
         db, q, mask = dataset
@@ -225,7 +225,7 @@ class TestPallasAdmissionParity:
         d_f, i_f = _search_impl_fused_recon_grouped(
             idx.centers, idx.list_recon, idx.list_recon_sq,
             idx.list_indices, idx.rotation, q, probes, k, k, idx.metric,
-            ng, merge_window=2, pallas_interpret=True, filter_words=fw)
+            ng, pallas_interpret=True, filter_words=fw)
         np.testing.assert_array_equal(np.asarray(i_f), i_ref)
         np.testing.assert_allclose(np.asarray(d_f), d_ref,
                                    rtol=1e-5, atol=1e-5)
@@ -240,8 +240,8 @@ class TestPallasAdmissionParity:
         d_fc, i_fc = _search_impl_fused_codes_grouped(
             idx.centers, idx.codebooks, idx.list_code_lanes,
             idx.list_code_rsq, idx.list_indices, idx.rotation, q, probes,
-            k, k, idx.metric, ng, idx.pq_bits, merge_window=2,
-            pallas_interpret=True, filter_words=fw)
+            k, k, idx.metric, ng, idx.pq_bits, pallas_interpret=True,
+            filter_words=fw)
         np.testing.assert_array_equal(np.asarray(i_fc), i_ref)
 
 

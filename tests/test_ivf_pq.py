@@ -246,7 +246,7 @@ class TestIvfPq:
         d_r, i_r = ivf_pq.search(
             res, ivf_pq.SearchParams(n_probes=8), index, q, k)
         d_l, i_l = ivf_pq.search(
-            res, ivf_pq.SearchParams(n_probes=8, use_reconstruction=False),
+            res, ivf_pq.SearchParams(n_probes=8, scan_mode="lut"),
             index, q, k)
         i_r, i_l = np.asarray(i_r), np.asarray(i_l)
         overlap = sum(len(set(a) & set(b)) for a, b in zip(i_r, i_l))
@@ -273,7 +273,7 @@ class TestIvfPq:
         assert recall(np.asarray(i), ti) > 0.5
         # both search formulations agree on the packed codes
         _, i_lut = ivf_pq.search(res, ivf_pq.SearchParams(
-            n_probes=16, use_reconstruction=False), index, q, 10)
+            n_probes=16, scan_mode="lut"), index, q, 10)
         overlap = np.mean([len(set(a) & set(b)) / len(a)
                            for a, b in zip(np.asarray(i),
                                            np.asarray(i_lut))])
@@ -310,7 +310,6 @@ def scan_index(dataset):
         ng = grouped.round_groups(
             int(grouped.num_groups(probes, index.n_lists)))
         index = ivf_pq._with_code_lanes(index)
-        index = ivf_pq._with_recon8(index)
         rd, ri = ivf_pq._search_impl_recon_grouped(
             index.centers, index.list_recon, index.list_recon_sq,
             index.list_indices, index.rotation, jnp.asarray(q), probes,
@@ -329,47 +328,17 @@ class TestCodeScan:
     reproduce the bf16 recon cache's distances bit-for-bit-close."""
 
     @pytest.mark.parametrize("pq_bits", [8, 4])
-    @pytest.mark.parametrize("packed", [False, True])
-    def test_codes_matches_recon(self, scan_index, pq_bits, packed):
+    def test_codes_matches_recon(self, scan_index, pq_bits):
         q, built = scan_index
         index, probes, ng, rd, ri = built[pq_bits]
         cd, ci = ivf_pq._search_impl_codes_grouped(
             index.centers, index.codebooks, index.list_code_lanes,
             index.list_code_rsq, index.list_indices, index.rotation,
             q, probes, 10, 0, index.metric, ng, index.pq_bits,
-            packed=packed, pallas_interpret=True)
+            pallas_interpret=True)
         cd, ci = np.asarray(cd), np.asarray(ci)
         assert _overlap(ci, ri) > 0.95
-        if not packed:
-            np.testing.assert_allclose(cd, rd, rtol=1e-2, atol=1e-2)
-
-    @pytest.mark.parametrize("use_pallas,packed",
-                             [(False, False), (True, False), (True, True)])
-    def test_recon8_matches_recon(self, scan_index, use_pallas, packed):
-        q, built = scan_index
-        index, probes, ng, rd, ri = built[8]
-        d8, i8 = ivf_pq._search_impl_recon8_grouped(
-            index.centers, index.list_recon_i8, index.list_recon_scale,
-            index.list_recon_i8_sq, index.list_indices, index.rotation,
-            q, probes, 10, 0, index.metric, ng, 64,
-            use_pallas=use_pallas, packed=packed, pallas_interpret=True)
-        # int8 quantization shifts distances; top-k is nearly preserved
-        assert _overlap(np.asarray(i8), ri) > 0.9
-
-    def test_recon8_pallas_matches_xla(self, scan_index):
-        """The Pallas dequant kernel and the XLA fallback compute the
-        identical quantized distance."""
-        q, built = scan_index
-        index, probes, ng, _, _ = built[8]
-        args = (index.centers, index.list_recon_i8, index.list_recon_scale,
-                index.list_recon_i8_sq, index.list_indices, index.rotation,
-                q, probes, 10, 0, index.metric, ng, 64)
-        dx, ix = ivf_pq._search_impl_recon8_grouped(*args)
-        dp, ip = ivf_pq._search_impl_recon8_grouped(
-            *args, use_pallas=True, pallas_interpret=True)
-        np.testing.assert_allclose(np.asarray(dx), np.asarray(dp),
-                                   rtol=1e-2, atol=1e-2)
-        assert _overlap(np.asarray(ip), np.asarray(ix)) > 0.95
+        np.testing.assert_allclose(cd, rd, rtol=1e-2, atol=1e-2)
 
     def test_per_probe_topk_matches_recon_at_same_kt(self, scan_index):
         """kt parity must compare same-kt paths: the codes kernel's
@@ -413,13 +382,12 @@ class TestCodeScan:
         index = ivf_pq.build(res, params, db)
         _, ti = naive_knn(db, q, 10)
         recalls = {}
-        for mode in ("recon", "codes", "recon8"):
+        for mode in ("recon", "codes"):
             sp = ivf_pq.SearchParams(n_probes=16, scan_mode=mode)
             _, i = ivf_pq.search(res, sp, index, q, 10)
             recalls[mode] = recall(np.asarray(i), ti)
         assert recalls["recon"] >= 0.9
         assert abs(recalls["codes"] - recalls["recon"]) < 0.05, recalls
-        assert abs(recalls["recon8"] - recalls["recon"]) < 0.05, recalls
 
 
 class TestFusedScan:
@@ -560,24 +528,29 @@ class TestFusedScan:
         bench geometry (1M x 128, 4096 lists, pq_dim 64, kt 16, batch
         5000).  If a VMEM-budget or gate edit regresses this,
         scan_mode=auto would silently fall off the fused kernel at the
-        headline operating point — fail HERE, not in the QPS number."""
-        from raft_tpu.ops import pq_code_scan_pallas as pcs
-        from raft_tpu.ops import pq_group_scan_pallas as pqp
-
+        headline operating point — fail HERE, not in the QPS number.
+        The planner is host-static, so it is asked as a TPU would ask
+        it: "auto" takes the recon cache the index carries, "fused" the
+        codes."""
         cap = -(-int(1_000_000 / 4096 * 1.35) // 32) * 32
-        assert pcs.supported_fused_codes(True, True, cap, 128, 16, 10,
-                                         5000, 64, 8)
-        assert pqp.supported_fused(True, cap, 128, 16, 10, 5000)
+        facts = dict(placement="single", metric=DistanceType.L2Expanded,
+                     cap=cap, rot=128, lists=4096, pq_bits=8, nq=5000,
+                     k=10, n_probes=72, on_tpu=True, pq_dim=64,
+                     per_subspace=True, has_recon=True, ids_exact=True)
+        for mode, form in (("auto", "fused_recon"),
+                           ("fused", "fused_codes")):
+            sp = ivf_pq.SearchParams(n_probes=72, scan_mode=mode,
+                                     per_probe_topk=16)
+            plan = ivf_pq.plan_scan(sp, **facts)
+            assert (plan.form, plan.reason) == (form, ""), mode
 
 
 @pytest.fixture(scope="module")
 def ring_case():
-    """Tiny synthetic geometry for staging-ring semantics: 7 lists of
-    capacity 32 with the last 5 rows of every list tombstoned (id -1),
-    9 queries x 3 probes.  n_groups is deliberately coprime with every
-    tested W, so each sweep crosses a partial final window, and k=256
-    exceeds the live candidate pool, so the accumulator tail stays
-    all-sentinel through entire windows."""
+    """Tiny synthetic geometry for the fused merge's accumulator: 7 lists
+    of capacity 32 with the last 5 rows of every list tombstoned (id -1),
+    9 queries x 3 probes.  k=256 exceeds the live candidate pool, so the
+    accumulator tail stays all-sentinel through the whole scan."""
     from raft_tpu.neighbors import grouped
 
     rng = np.random.default_rng(0)
@@ -601,47 +574,31 @@ def ring_case():
 
 
 class TestWindowedMerge:
-    """``merge_window`` on the fused scans: still accepted (it is the
-    public knob and a cache-key dimension), and the row-addressed
-    kernels merge every grid step whatever its value.  The contract is
-    bit-identity with the per-step merge (W=1): VALUES bit-equal
-    at every rank, IDS bit-equal at every live rank (exhausted ranks
-    all carry the sentinel value, so their relative id order is
-    unspecified; the epilogue maps every such rank to +inf / -1)."""
+    """The fused recon scan's per-step merge at large k and with
+    tombstoned rows: the row-addressed kernels merge every grid step,
+    and their answers must match the non-fused kernel + a host-side sort
+    at matched kt.  Exhausted ranks all carry the sentinel value, so
+    their relative id order is unspecified; the epilogue maps every such
+    rank to +inf / -1."""
 
-    def _run(self, c, k, W):
+    def _run(self, c, k):
         from raft_tpu.ops import pq_group_scan_pallas as pqp
 
         v, i = pqp.grouped_l2_scan_fused(
             c["gl"], c["sp"], c["qrot"], c["centers"], c["recon"],
-            c["rsq"], c["ids"], c["kt"], k, c["n_probes"],
-            interpret=True, merge_window=W)
+            c["rsq"], c["ids"], c["kt"], k, c["n_probes"], interpret=True)
         return np.asarray(v), np.asarray(i)
-
-    def test_bit_identity_across_windows(self, ring_case):
-        from raft_tpu.ops import pq_group_scan_pallas as pqp
-
-        base_v, base_i = self._run(ring_case, 10, 1)
-        live = base_v < pqp._ACC_WORST / 2
-        for W in (2, 3, 8):            # none divide n_groups
-            v, i = self._run(ring_case, 10, W)
-            np.testing.assert_array_equal(base_v, v)
-            np.testing.assert_array_equal(base_i[live], i[live])
 
     @pytest.mark.parametrize("k", [128, 256])
     def test_large_k_windowed_matches_reference(self, ring_case, k):
-        """k past the unrolled-merge ceiling takes the fori-loop merge.
-        Windowed runs must agree with each other bit-for-bit and with
-        the non-fused kernel + host-side sort at matched kt."""
+        """k past the unrolled-merge ceiling takes the fori-loop merge,
+        which must agree with the non-fused kernel + host-side sort at
+        matched kt."""
         from raft_tpu.neighbors import grouped
         from raft_tpu.ops import pq_group_scan_pallas as pqp
 
         c = ring_case
-        av, ai = self._run(c, k, 2)
-        bv, bi = self._run(c, k, 5)
-        live = av < pqp._ACC_WORST / 2
-        np.testing.assert_array_equal(av, bv)
-        np.testing.assert_array_equal(ai[live], bi[live])
+        av, ai = self._run(c, k)
         nv, ni = pqp.grouped_l2_scan(
             c["gl"], c["sp"], c["qrot"], c["centers"], c["recon"],
             c["rsq"], c["ids"], c["kt"], c["n_probes"], interpret=True)
@@ -665,67 +622,44 @@ class TestWindowedMerge:
     def test_tombstones_never_surface_through_staging_ring(self,
                                                            ring_case):
         """The last 5 rows of every list carry id -1 (the tombstone /
-        integrity-mask contract): the ring's sentinel fill must never
-        resurrect them at any W, and exhausted ranks must come back as
-        sentinel-value / -1 pairs — never a live value with a stale
-        id left over from a previous window."""
+        integrity-mask contract): the accumulator's sentinel fill must
+        never resurrect them, and every live rank must carry a real
+        (non-tombstoned) id."""
         from raft_tpu.ops import pq_group_scan_pallas as pqp
 
         ids_np = ring_case["ids_np"]
         alive = set(ids_np[ids_np >= 0].tolist())
-        for W in (1, 4):
-            v, i = self._run(ring_case, 64, W)
-            live = v < pqp._ACC_WORST / 2
-            # the raw kernel output predates the epilogue, so only live
-            # ranks carry a contract: a real (non-tombstoned) id, never
-            # the -1 ring fill
-            assert (i[live] >= 0).all()
-            assert all(int(x) in alive for x in i[live])
+        v, i = self._run(ring_case, 64)
+        live = v < pqp._ACC_WORST / 2
+        # the raw kernel output predates the epilogue, so only live
+        # ranks carry a contract: a real (non-tombstoned) id, never the
+        # -1 fill
+        assert live.any()
+        assert (i[live] >= 0).all()
+        assert all(int(x) in alive for x in i[live])
 
     def test_fused_codes_windowed_large_k(self, scan_index):
-        """Codes kernel at k=128: any merge_window is
-        bit-identical to the per-step merge and lands the same
+        """Codes kernel at k=128 (the looped merge) lands the same
         candidates as the non-fused codes path at matched kt."""
         q, built = scan_index
         index, probes, ng, _, _ = built[8]
         args = (index.centers, index.codebooks, index.list_code_lanes,
                 index.list_code_rsq, index.list_indices, index.rotation,
                 q, probes, 128, 4, index.metric, ng, index.pq_bits)
-        f1d, f1i = ivf_pq._search_impl_fused_codes_grouped(
-            *args, pallas_interpret=True, merge_window=1)
-        f4d, f4i = ivf_pq._search_impl_fused_codes_grouped(
-            *args, pallas_interpret=True, merge_window=4)
-        f1d, f1i = np.asarray(f1d), np.asarray(f1i)
-        f4d, f4i = np.asarray(f4d), np.asarray(f4i)
-        np.testing.assert_array_equal(f1d, f4d)
-        fin = np.isfinite(f1d)
-        np.testing.assert_array_equal(f1i[fin], f4i[fin])
+        fd, fi = ivf_pq._search_impl_fused_codes_grouped(
+            *args, pallas_interpret=True)
+        fd, fi = np.asarray(fd), np.asarray(fi)
         rd, ri = ivf_pq._search_impl_codes_grouped(
             *args, pallas_interpret=True)
         rd, ri = np.asarray(rd), np.asarray(ri)
-        both = fin & np.isfinite(rd)
-        np.testing.assert_allclose(f4d[both], rd[both], rtol=1e-4,
+        both = np.isfinite(fd) & np.isfinite(rd)
+        np.testing.assert_allclose(fd[both], rd[both], rtol=1e-4,
                                    atol=1e-4)
         # k=128 exceeds the kt=4 candidate pool (8 probes x 4), so both
         # paths keep EVERY candidate: the finite id sets match exactly
-        for r in range(f4i.shape[0]):
-            assert (set(f4i[r][f4i[r] >= 0].tolist())
+        for r in range(fi.shape[0]):
+            assert (set(fi[r][fi[r] >= 0].tolist())
                     == set(ri[r][ri[r] >= 0].tolist()))
-
-    def test_xla_twin_windowed_scatter_matches(self, scan_index):
-        """grouped.scan_and_scatter's merge_window (the AOT export's
-        XLA grouped scan) defers the scatter to one pass
-        per W blocks; the scatter is idempotent over disjoint slots, so
-        every W must reproduce the unwindowed result exactly."""
-        q, built = scan_index
-        index, probes, ng, rd, ri = built[8]
-        for W in (1, 3):
-            wd_, wi_ = ivf_pq._search_impl_recon_grouped(
-                index.centers, index.list_recon, index.list_recon_sq,
-                index.list_indices, index.rotation, q, probes, 10,
-                index.metric, ng, 64, merge_window=W)
-            np.testing.assert_array_equal(np.asarray(wd_), rd)
-            np.testing.assert_array_equal(np.asarray(wi_), ri)
 
 
 class TestListDataHelpers:

@@ -4,7 +4,7 @@ shim, and mutation x scan-mode parity.
 
 The contract under test: ``delete``/``compact``/``extend`` return a NEW
 index generation sharing unchanged arrays with the parent; deleted ids
-vanish from every scan formulation (recon / codes / recon8 / fused) via
+vanish from every scan formulation (recon / codes / fused) via
 the existing ``id < 0`` mask with zero kernel changes; ``integrity.verify``
 accepts tombstones inside the occupied prefix and rejects them outside it;
 the recall canary excludes deleted rows from its ground truth.
@@ -310,7 +310,7 @@ class TestPqMutationParity:
     mode's top-k (fused included — on CPU its Pallas kernels run the
     portable path, same contract)."""
 
-    MODES = ("recon", "codes", "recon8", "fused")
+    MODES = ("recon", "codes", "fused")
 
     @pytest.fixture(scope="class")
     def built(self, res, pq_dataset):
@@ -353,8 +353,8 @@ class TestPqMutationParity:
                 if kt:
                     continue
                 # at the exact merge the quantized modes keep essentially
-                # the recon reference's candidates (int8/LUT noise only)
-                for mode in ("codes", "recon8", "fused"):
+                # the recon reference's candidates (LUT noise only)
+                for mode in ("codes", "fused"):
                     ov = np.mean([len(set(a) & set(b)) / 10 for a, b in
                                   zip(by_mode[mode], by_mode["recon"])])
                     assert ov > 0.9, (rnd, mode, ov)

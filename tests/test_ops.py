@@ -233,13 +233,12 @@ class TestFusedScanMatchesXlaTwin:
             d, i = ivf_pq._search_impl_fused_recon_grouped(
                 idx.centers, idx.list_recon, idx.list_recon_sq,
                 idx.list_indices, idx.rotation, q, probes, k, k,
-                idx.metric, ng, merge_window=3, pallas_interpret=True,
-                filter_words=fw)
+                idx.metric, ng, pallas_interpret=True, filter_words=fw)
         else:
             d, i = ivf_pq._search_impl_fused_codes_grouped(
                 idx.centers, idx.codebooks, idx.list_code_lanes,
                 idx.list_code_rsq, idx.list_indices, idx.rotation, q,
-                probes, k, k, idx.metric, ng, idx.pq_bits, merge_window=3,
+                probes, k, k, idx.metric, ng, idx.pq_bits,
                 pallas_interpret=True, filter_words=fw)
         np.testing.assert_array_equal(np.asarray(i), np.asarray(ref_i))
         np.testing.assert_allclose(np.asarray(d), np.asarray(ref_d),

@@ -347,7 +347,6 @@ class TestGroundTruthParams:
         assert sp.n_probes == pq_index.n_lists
         assert sp.exact_coarse is True
         assert sp.per_probe_topk == 0
-        assert sp.use_reconstruction is None
         assert sp.scan_mode in ("lut", "recon")
 
     def test_ivf_flat_full_probe(self, res, dataset):

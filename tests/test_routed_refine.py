@@ -257,7 +257,7 @@ def test_routed_groups_skipped_counter(handle, data, built, monkeypatch):
         monkeypatch.setattr(ivf_pq, name, functools.partial(
             getattr(ivf_pq, name), pallas_interpret=True))
     sp = ivf_pq.SearchParams(n_probes=N_PROBES, scan_mode="fused")
-    form = ann._resolve_scan_mode(sp, routed, NQ, N_PROBES, K * RATIO).form
+    form = ann._plan(sp, routed, NQ, N_PROBES, K * RATIO).form
     assert form in ("fused_codes", "fused_recon")
     names = ("distributed.routed.groups_dispatched",
              "distributed.routed.groups_skipped")

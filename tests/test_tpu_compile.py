@@ -30,6 +30,7 @@ from raft_tpu.ops.fused_l2_nn_pallas import fused_l2_nn_pallas
 from raft_tpu.ops import kmeans_update_pallas as kup
 from raft_tpu.ops import pq_code_scan_pallas as pcs
 from raft_tpu.ops import pq_group_scan_pallas as pgs
+from raft_tpu.ops import vmem_budget as vb
 
 NQ, N_PROBES, N_LISTS, CAP, ROT, K, KT = 5000, 72, 4096, 256, 128, 10, 10
 PQ_DIM, PQ_BITS, BOOK = 64, 8, 256
@@ -118,10 +119,9 @@ FUSED_SHAPES = [(NQ, K, KT), (5000, 20, 20), (64, 20, 20)]
 @pytest.mark.parametrize("with_adm", [False, True])
 def test_fused_recon_scan(spec, with_adm, nq, k, kt):
     ng, head = _groups(spec, nq)
-    mw = pgs.fused_merge_window(CAP, ROT, kt, k, nq)
-    assert mw > 0
+    assert vb.fused_scan_fits(pgs._fused_bytes(CAP, ROT, kt, k, nq, 2))
     fn = functools.partial(pgs.grouped_l2_scan_fused, kt=kt, k=k,
-                           n_probes=N_PROBES, merge_window=mw)
+                           n_probes=N_PROBES)
     _assert_fused_kernel(lambda *a: fn(*a[:7], adm_words=a[7]), *head,
                    spec((N_LISTS, CAP, ROT), jnp.bfloat16),
                    spec((N_LISTS, CAP), jnp.float32),
@@ -132,11 +132,10 @@ def test_fused_recon_scan(spec, with_adm, nq, k, kt):
 @pytest.mark.parametrize("with_adm", [False, True])
 def test_fused_code_scan(spec, with_adm, nq, k, kt):
     ng, head = _groups(spec, nq)
-    mw = pcs.fused_codes_merge_window(CAP, ROT, kt, k, nq, PQ_DIM, PQ_BITS)
-    assert mw > 0
+    assert vb.fused_scan_fits(
+        pcs._fused_codes_bytes(CAP, ROT, kt, k, nq, PQ_DIM, PQ_BITS))
     fn = functools.partial(pcs.grouped_code_scan_fused, kt=kt, k=k,
-                           n_probes=N_PROBES, pq_bits=PQ_BITS,
-                           merge_window=mw)
+                           n_probes=N_PROBES, pq_bits=PQ_BITS)
     _assert_fused_kernel(lambda *a: fn(*a[:8], adm_words=a[8]), *head,
                    spec((N_LISTS, pcs.code_lane_words(PQ_DIM, PQ_BITS), CAP),
                         jnp.int32),
@@ -171,16 +170,6 @@ def test_code_scan(spec):
                    spec((N_LISTS, pcs.code_lane_words(PQ_DIM, PQ_BITS), CAP),
                         jnp.int32),
                    spec((PQ_DIM, BOOK, ROT // PQ_DIM), jnp.float32),
-                   spec((N_LISTS, CAP), jnp.float32),
-                   spec((N_LISTS, CAP), jnp.int32))
-
-
-def test_recon8_scan(spec):
-    _, head = _groups(spec)
-    _assert_kernel(functools.partial(pcs.grouped_recon8_scan, kt=KT,
-                                     n_probes=N_PROBES), *head,
-                   spec((N_LISTS, CAP, ROT), jnp.int8),
-                   spec((N_LISTS,), jnp.float32),
                    spec((N_LISTS, CAP), jnp.float32),
                    spec((N_LISTS, CAP), jnp.int32))
 
@@ -228,20 +217,20 @@ def test_fused_scan_at_routed_shard_shapes(spec, kernel):
     ng, head = _routed_head(spec)
     assert ng == 4862
     if kernel == "codes":
-        mw = pcs.fused_codes_merge_window(R_CAP, ROT, R_KR, R_KR, NQ, PQ_DIM,
-                                          PQ_BITS)
+        fits = vb.fused_scan_fits(pcs._fused_codes_bytes(
+            R_CAP, ROT, R_KR, R_KR, NQ, PQ_DIM, PQ_BITS))
         fn = functools.partial(pcs.grouped_code_scan_fused, kt=R_KR, k=R_KR,
-                               n_probes=N_PROBES, pq_bits=PQ_BITS,
-                               merge_window=mw)
+                               n_probes=N_PROBES, pq_bits=PQ_BITS)
         data = (spec((R_SLOTS, pcs.code_lane_words(PQ_DIM, PQ_BITS), R_CAP),
                      jnp.int32),
                 spec((PQ_DIM, BOOK, ROT // PQ_DIM), jnp.float32))
     else:
-        mw = pgs.fused_merge_window(R_CAP, ROT, R_KR, R_KR, NQ)
+        fits = vb.fused_scan_fits(
+            pgs._fused_bytes(R_CAP, ROT, R_KR, R_KR, NQ, 2))
         fn = functools.partial(pgs.grouped_l2_scan_fused, kt=R_KR, k=R_KR,
-                               n_probes=N_PROBES, merge_window=mw)
+                               n_probes=N_PROBES)
         data = (spec((R_SLOTS, R_CAP, ROT), jnp.bfloat16),)
-    assert mw > 0
+    assert fits
     _assert_fused_kernel(fn, *head, *data,
                          spec((R_SLOTS, R_CAP), jnp.float32),
                          spec((R_SLOTS, R_CAP), jnp.int32))
@@ -272,11 +261,9 @@ def test_routed_refined_search_program(topo, spec):
                   on((N_LISTS,), jnp.int32, False),
                   on((N_LISTS,), jnp.int32, False),
                   on((PQ_DIM, BOOK, ROT // PQ_DIM), jnp.float32, False))
-    mw = pcs.fused_codes_merge_window(R_CAP, ROT, R_KR, R_KR, NQ, PQ_DIM,
-                                      PQ_BITS)
     text = ann._dist_search_routed_grouped.lower(
         sharded, replicated, on((NQ, DIM), jnp.float32, False), R_KR, R_KR,
         N_PROBES, DistanceType.L2Expanded, "data", mesh, ng, "fused_codes",
-        pq_bits=PQ_BITS, merge_window=mw, refine_to=R_K).compile().as_text()
+        pq_bits=PQ_BITS, refine_to=R_K).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text and "all-reduce" in text
