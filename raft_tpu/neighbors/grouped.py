@@ -45,17 +45,58 @@ GROUP = 128          # pair-group size: one full MXU tile of queries
 _GROUP_ROUND = 256   # n_groups rounding quantum (compile-cache stability)
 
 
+def _sort_pairs(probes: jax.Array, n_lists: int
+                ) -> Tuple[jax.Array, jax.Array]:
+    """The batch's pairs sorted by list, ascending pair index within a
+    list: ``(pl_s, order)``, each (P,) int32.  Sentinel probes
+    (``>= n_lists``) share the key ``n_lists`` and sort last."""
+    key = jnp.minimum(probes.reshape(-1).astype(jnp.int32), n_lists)
+    return jax.lax.sort((key, jax.lax.iota(jnp.int32, key.shape[0])),
+                        num_keys=1, is_stable=True)
+
+
+def _list_starts(pl_s: jax.Array, n_lists: int) -> jax.Array:
+    """(n_lists + 1,) int32: the first sorted position of each list; the
+    last entry is the number of in-range pairs.
+
+    A two-level search over the sorted keys in ``GROUP``-wide blocks:
+    every block whose last key lies below ``l`` lies wholly below it,
+    and the one block after them holds the boundary.  Both levels are
+    dense compares; the only data move is one block row per list —
+    ``jnp.searchsorted`` would lower to serial per-key gathers."""
+    P = pl_s.shape[0]
+    nb = -(-P // GROUP)
+    blocks = jnp.pad(pl_s, (0, nb * GROUP - P),
+                     constant_values=n_lists).reshape(nb, GROUP)
+    v = jax.lax.iota(jnp.int32, n_lists + 1)[:, None]
+    last = blocks[:, GROUP - 1:].reshape(1, nb)
+    below = jnp.sum(last < v, axis=1, dtype=jnp.int32)
+    row = blocks[jnp.minimum(below, nb - 1)]
+    within = jnp.sum(row < v, axis=1, dtype=jnp.int32)
+    return below * GROUP + jnp.where(below < nb, within, 0)
+
+
+def _group_bounds(lstart: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """``(group_start, group_end)``, each (n_lists,) int32: the first
+    group of each list and one past its last, ``ceil(count/G)`` groups
+    a list."""
+    per_list = -(-(lstart[1:] - lstart[:-1]) // GROUP)
+    gend = jnp.cumsum(per_list, dtype=jnp.int32)
+    return gend - per_list, gend
+
+
 def num_groups(probes: jax.Array, n_lists: int) -> jax.Array:
     """Total fixed-size groups this batch needs: sum over lists of
-    ceil(count/G).  Probes ``>= n_lists`` (sentinels) are excluded by the
-    segment reduction, matching what :func:`build_groups` lays out.  The
-    dispatch path no longer syncs this (see :func:`group_capacity`); it
-    remains the calibrated regime's overflow count and the measurement
+    ceil(count/G), counted from the same sort :func:`build_groups`
+    lays its groups out from, so sentinel probes (``>= n_lists``) are
+    excluded exactly as there.  The dispatch path no longer syncs this
+    (see :func:`group_capacity`); it remains the calibrated regime's
+    overflow count and the measurement
     :func:`raft_tpu.neighbors.ivf_pq.calibrate_group_capacity` reads."""
-    counts = jax.ops.segment_sum(
-        jnp.ones(probes.size, jnp.int32), probes.reshape(-1),
-        num_segments=n_lists)
-    return jnp.sum(-(-counts // GROUP))
+    if probes.size == 0:
+        return jnp.int32(0)
+    pl_s, _ = _sort_pairs(probes, n_lists)
+    return _group_bounds(_list_starts(pl_s, n_lists))[1][-1]
 
 
 num_groups = jax.jit(num_groups, static_argnames=("n_lists",))
@@ -140,37 +181,69 @@ def build_groups(probes: jax.Array, n_lists: int, n_groups: int
 
     Returns ``(group_list, slot_pairs)``:
 
-    - ``group_list`` (n_groups,) int32 — the list each group scans (tail
-      groups beyond the real count alias the last list; their slots are
-      empty);
+    - ``group_list`` (n_groups,) int32 — the list each group scans, the
+      live groups first in list order (tail groups beyond the real count
+      alias the last list; their slots are empty);
     - ``slot_pairs`` (n_groups, GROUP) int32 — flattened pair index
-      (q * n_probes + probe_rank) per slot, with ``P = probes.size`` as
-      the empty-slot sentinel (scatters through it are dropped).
+      (q * n_probes + probe_rank) per slot, ascending within a list,
+      with ``P = probes.size`` as the empty-slot sentinel (scatters
+      through it are dropped).  A group's real slots come first.
 
     Pair → (group, slot): sort pairs by list; pair with in-list rank r of
     list l lands in group ``group_start[l] + r // G``, slot ``r % G``.
+    Sentinel probes (``>= n_lists``) occupy no slot.  Group ``g`` is
+    therefore the window of ``min(G, list end - start)`` sorted pairs
+    from ``start = list_start[l] + G * (g - group_start[l])``: past the
+    one sort, every step works on per-list or per-group tables, and the
+    only full-size data move is that window per group.  No gather or
+    scatter is indexed per pair: over the batch cell's 360,000 pairs
+    each such op costs ≈ 2.5 ms on a TPU v5e, against 0.46 ms for the
+    sort and 0.67 ms for this whole layout (``profiles/group_build.py``).
     """
     P = probes.size
-    pl = probes.reshape(-1)
-    order = jnp.argsort(pl)
-    pl_s = pl[order]
-    counts = jax.ops.segment_sum(jnp.ones(P, jnp.int32), pl,
-                                 num_segments=n_lists)
-    groups_per_list = -(-counts // GROUP)
-    gstart = jnp.cumsum(groups_per_list) - groups_per_list
-    group_list = jnp.repeat(jnp.arange(n_lists, dtype=jnp.int32),
-                            groups_per_list, total_repeat_length=n_groups)
-    starts = jnp.cumsum(counts) - counts
-    rank = jnp.arange(P) - starts[pl_s]
-    g = gstart[jnp.minimum(pl_s, n_lists - 1)] + rank // GROUP
-    s = rank % GROUP
-    slot_pairs = jnp.full((n_groups, GROUP), P, jnp.int32)
-    # probes >= n_lists are sentinels (the super-tile dedupe marks
-    # duplicate pairs that way): their pairs write the empty-slot
-    # sentinel wherever they land, so they can never surface results
-    vals = jnp.where(pl_s < n_lists, order, P)
-    slot_pairs = slot_pairs.at[g, s].set(vals, mode="drop")
+    if P == 0:
+        return (jnp.full((n_groups,), n_lists - 1, jnp.int32),
+                jnp.zeros((n_groups, GROUP), jnp.int32))
+    pl_s, order = _sort_pairs(probes, n_lists)
+    lstart = _list_starts(pl_s, n_lists)
+    gstart, gend = _group_bounds(lstart)
+    g = jax.lax.iota(jnp.int32, n_groups)
+    # the list of each group: how many lists end at or before it
+    # (n_lists past the live groups, which clamps to the tail's alias)
+    gl = jnp.sum(gend[None, :] <= g[:, None], axis=1, dtype=jnp.int32)
+    group_list = jnp.minimum(gl, n_lists - 1)
+    offset = lstart[:-1] - GROUP * gstart
+    start = jnp.where(gl < n_lists, offset[group_list] + GROUP * g, P)
+    length = jnp.minimum(lstart[1:][group_list] - start, GROUP)
+    slot_pairs = jnp.where(
+        jax.lax.iota(jnp.int32, GROUP)[None, :] < length[:, None],
+        _windows(order, start), P)
     return group_list, slot_pairs
+
+
+def _windows(order: jax.Array, start: jax.Array) -> jax.Array:
+    """(n, GROUP) int32: ``order[s : s + GROUP]`` for each ``s`` of
+    ``start`` (``0 <= s <= P``; ``P`` past the end).
+
+    Each window is cut from the two aligned ``GROUP``-wide rows of
+    ``order`` that hold it — two row gathers — and shifted into place
+    by a seven-stage barrel shift.  A gather of unaligned 128-wide
+    windows lowers to a loop of one dynamic slice per window on the
+    TPU: with it the layout took 6.0 ms at the batch cell's 6,909
+    groups, with this 0.67 ms (``profiles/group_build.py``)."""
+    P = order.shape[0]
+    rows = -(-P // GROUP) + 1
+    src = jnp.pad(order, (0, rows * GROUP - P),
+                  constant_values=P).reshape(rows, GROUP)
+    r = start // GROUP
+    win = jnp.concatenate([src[r], src[jnp.minimum(r + 1, rows - 1)]],
+                          axis=1)
+    shift = start % GROUP
+    for b in range(GROUP.bit_length() - 1):
+        step = 1 << b
+        rolled = jnp.concatenate([win[:, step:], win[:, :step]], axis=1)
+        win = jnp.where(((shift & step) != 0)[:, None], rolled, win)
+    return win[:, :GROUP]
 
 
 @functools.partial(jax.jit, static_argnames=("n_lists",))
